@@ -2,15 +2,14 @@
 
 Reads experiments/dryrun/*.json (produced by ``python -m repro.launch.dryrun
 --all``) and emits one CSV row per combination — the §Roofline table's data.
-If no artifacts exist yet, runs one small combination inline (whisper-small
-decode) so ``python -m benchmarks.run`` is self-contained.
+It starts no dry-run itself: the dry-run needs 512 fake CPU devices fixed
+before JAX starts, and this process has started JAX already (and may hold a
+chip).  Run the dry-run first, in its own process.
 """
 from __future__ import annotations
 
 import glob
 import os
-import subprocess
-import sys
 import time
 
 from benchmarks.common import emit
@@ -21,13 +20,9 @@ def main(fast: bool = False) -> None:
     t0 = time.time()
     d = "experiments/dryrun"
     if not glob.glob(os.path.join(d, "*.json")):
-        os.makedirs(d, exist_ok=True)
-        subprocess.run(
-            [sys.executable, "-m", "repro.launch.dryrun", "--arch", "whisper-small",
-             "--shape", "decode_32k", "--out-dir", d],
-            check=False,
-            env={**os.environ, "PYTHONPATH": "src"},
-        )
+        raise FileNotFoundError(
+            f"no dry-run artifacts under {d}/ — run `PYTHONPATH=src python -m "
+            "repro.launch.dryrun --arch whisper-small --shape decode_32k` first")
     for rec in load(d):
         name = f"roofline_{rec['arch']}_{rec['shape']}_{rec['mesh']}"
         if rec.get("skipped"):

@@ -8,13 +8,20 @@
   bench_gengap       Tables 2 & 4 (generalization gap)
   bench_dlrm_proxy   Table 5 (CTR AUC vs batch, SGD vs VR-SGD)
   bench_overhead     VRGD systems cost (step overhead + fused kernel)
-  bench_roofline     §Roofline terms from the dry-run artifacts
   bench_serve        continuous-batching serving (mixed prefill/decode)
+  bench_roofline     §Roofline terms from the dry-run artifacts (only on
+                     request: it reads what a dry-run wrote)
 
 ``python -m benchmarks.run``            full pass (CPU, ~15 min)
 ``python -m benchmarks.run --fast``     reduced sweeps (~4 min)
 ``python -m benchmarks.run --only linreg,gengap``
 ``python -m benchmarks.run --check-regression``  structural cost gate only
+
+The roofline table takes two steps, each its own process: the dry-run
+first (it fixes 512 fake CPU devices before JAX starts), then the table::
+
+  PYTHONPATH=src python -m repro.launch.dryrun --arch whisper-small --shape decode_32k
+  python -m benchmarks.run --only roofline
 """
 from __future__ import annotations
 
@@ -33,9 +40,9 @@ MODULES = [
     "gengap",
     "dlrm_proxy",
     "overhead",
-    "roofline",
     "serve",
 ]
+ON_REQUEST = ["roofline"]  # run only when named in --only
 
 _HERE = os.path.dirname(__file__)
 BENCH_JSONS = [
@@ -126,13 +133,17 @@ def main() -> None:
     args = ap.parse_args()
     if args.check_regression:
         sys.exit(check_regression())
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     only = [s.strip() for s in args.only.split(",") if s.strip()]
+    unknown = set(only) - set(MODULES + ON_REQUEST)
+    if unknown:
+        ap.error(f"unknown benchmark modules: {sorted(unknown)}")
     print("name,us_per_call,derived")
     t0 = time.time()
     failures = []
-    for mod in MODULES:
-        if only and mod not in only:
-            continue
+    for mod in only or MODULES:
         try:
             m = __import__(f"benchmarks.bench_{mod}", fromlist=["main"])
             m.main(fast=args.fast)

@@ -11,15 +11,14 @@ heavy runs at registration; geometries materialize only inside
 
 Declared contracts ride on the operands:
 
-  * ``role``        what layout rule applies: "tile" (rank/sublane),
-                    "row" ((1, block) int32 pos/seg), "lse" ((1, 1, block_q)
-                    f32 residual), "meta" (leaf ids / scalars: rank only)
-  * ``window``      inclusive (lo, hi) phase window on ``Geometry.phase_axis``
-                    — outside it the index map must PARK (constant index)
-  * ``accumulate``  output declared accumulate-through-window: its block
-                    index MAY recur non-consecutively (Mosaic re-fetches the
-                    output window on revisit; dq in the fused backward, the
-                    stashed ``upd`` in the 3-phase flat kernels)
+  * ``shape``       the full array's shape: the layout rules compare the
+                    block's last two dims against it (a block dim equal to
+                    the array dim is always legal)
+  * ``window``      inclusive (lo, hi) window on ``Geometry.phase_axis`` of
+                    the grid steps in which the kernel reads / writes the
+                    operand — outside it the index map must PARK (inputs:
+                    constant index; outputs: the index of an adjacent live
+                    step, so the parked steps join a written run)
 
 ``Geometry.fetch_maps`` carries concrete scalar-prefetch fetch arrays for
 the FETCH-* soundness rules.  ``oracle`` names the pure-jnp reference the
@@ -40,10 +39,9 @@ class Operand:
     """One kernel operand: its BlockSpec plus the declared contracts."""
 
     spec: Any  # pl.BlockSpec
+    shape: Tuple[int, ...]  # the full array's shape
     dtype: str = "float32"
-    role: str = "tile"  # tile | row | lse | meta
     window: Optional[Tuple[int, int]] = None  # inclusive live phase window
-    accumulate: bool = False  # declared accumulate-through-window output
 
 
 @dataclasses.dataclass(frozen=True)

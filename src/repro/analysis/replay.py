@@ -9,10 +9,10 @@ sequence:
   * benchmarks.cost_model counts a DMA exactly when the returned index
     changes vs the previous step (the Mosaic copy-in/copy-out elision rule)
     and turns visits into HBM bytes;
-  * repro.analysis.rules detects revisit races (an output block whose index
-    recurs NON-consecutively), verifies PHASE_WINDOWS parking (constant
-    index outside the declared live window), and checks the live->parked
-    write-back boundary.
+  * repro.analysis.rules detects output revisits (an output block whose
+    index recurs in two separate runs), verifies PHASE_WINDOWS parking
+    (inputs: constant index outside the declared live window; outputs:
+    every run of an index holds a live, written step).
 
 Keeping the walker here (and importing it from cost_model) is an acceptance
 criterion of the contract checker: the race detector and the cost model
@@ -47,7 +47,8 @@ def count_visits(seq: List[tuple]) -> int:
 
 
 def _blk_bytes(spec, elem_bytes: int) -> int:
-    return int(math.prod(spec.block_shape)) * elem_bytes
+    """Bytes of one block window (a None — squeezed — dim counts as 1)."""
+    return int(math.prod(1 if d is None else d for d in spec.block_shape)) * elem_bytes
 
 
 def replay_dma(grid: Tuple[int, ...],
@@ -55,14 +56,14 @@ def replay_dma(grid: Tuple[int, ...],
                extra: Tuple = ()) -> Dict[str, dict]:
     """Per-operand {visits, bytes} over the grid walk.
 
-    operands: (name, BlockSpec, elem_bytes, is_output).  Outputs cost a
-    fetch AND a write-back per visit (2x bytes).
+    operands: (name, BlockSpec, elem_bytes, is_output).  An output costs
+    one copy-back per visit: the TPU pipeline never fetches output blocks.
     """
     out = {}
     for name, spec, eb, is_out in operands:
         visits = count_visits(replay_indices(grid, spec, extra))
         out[name] = {
             "visits": visits,
-            "bytes": visits * _blk_bytes(spec, eb) * (2 if is_out else 1),
+            "bytes": visits * _blk_bytes(spec, eb),
         }
     return out

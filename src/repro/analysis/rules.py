@@ -3,33 +3,32 @@ mutation tests in tests/test_analysis.py and referenced from the Mosaic
 checklists in docs/) and produces ``Finding`` records, never exceptions —
 the checker reports everything it can see in one pass.
 
-Revisit rules (replaying the real index maps via analysis.replay):
+Revisit rules (replaying the real index maps via analysis.replay).  The TPU
+pipeline copies an output block back to HBM whenever its block index
+changes (and after the last step), and never reads an output block from
+HBM — so an output block must be written in exactly one run of consecutive
+steps:
 
-  REVISIT-RACE   an OUTPUT block whose index recurs non-consecutively
-                 within its live phases must be declared
-                 ``accumulate=True`` (Mosaic re-fetches the output window
-                 on revisit; without the declaration the earlier write is
-                 presumed lost — dq in the fused backward, the stashed
-                 ``upd`` of the 3-phase flat kernels)
+  REVISIT-RACE   an OUTPUT block index recurs in two separate runs (the
+                 revisit starts from a buffer that does not hold the first
+                 run's writes: they are lost or overwritten)
   REVISIT-PARK   an INPUT with a declared phase window must hold a CONSTANT
                  block index through every out-of-window segment (parked =
                  zero DMA; a drifting index means the kernel re-fetches
                  blocks in phases it never reads them)
-  REVISIT-WRITE  parked-output safety: constant index while parked (a
-                 parked window is never written, so its departure write-back
-                 must restore the exact bytes it fetched — impossible if the
-                 window moved) and an index CHANGE at every live->parked
-                 transition (the change forces the final write-back; an
-                 elided one strands the last written block in VMEM)
+  REVISIT-WRITE  every run of an OUTPUT's block index contains a live step
+                 of its window (a run with no live step is copied back from
+                 a buffer the kernel never wrote — parked outputs must park
+                 on the index of an adjacent live step)
 
-Layout rules (static, from BlockSpec shapes + declared dtypes):
+Layout rules (static, from BlockSpec shapes, array shapes and dtypes) — the
+TPU compiler's block rule: the last two block dims (None counts as 1) are
+each divisible by the tile, or equal to the array's own dims:
 
-  LAYOUT-RANK     every operand block keeps >= MIN_TILE_RANK dims (and a
-                  "tile" role must survive squeezing its 1-dims)
-  LAYOUT-SUBLANE  a tile's squeezed sublane dim is a multiple of
-                  layout_contracts.sublane(dtype) — no hard-coded 8
-  LAYOUT-ROW      pos/seg operands are (1, block) int32 rows
-  LAYOUT-LSE      LSE/delta residuals are (1, 1, block_q) f32
+  LAYOUT-RANK     every operand block keeps >= MIN_TILE_RANK dims
+  LAYOUT-SUBLANE  second-minor block dim % sublane(dtype) == 0, or == the
+                  array's second-minor dim (dtype-derived, not 8)
+  LAYOUT-LANE     minor block dim % LANE == 0, or == the array's minor dim
 
 Fetch-map rules (concrete scalar-prefetch arrays):
 
@@ -56,6 +55,7 @@ import numpy as np
 
 from repro.analysis.layout_contracts import (
     DOUBLE_BUFFER,
+    LANE,
     MIN_TILE_RANK,
     VMEM_BUDGET_BYTES,
     itemsize,
@@ -65,13 +65,12 @@ from repro.analysis.registry import Geometry, KernelSpec, Operand
 from repro.analysis.replay import _blk_bytes, grid_steps, replay_indices
 
 RULES = {
-    "REVISIT-RACE": "non-consecutive output revisit must be declared accumulate-through-window",
+    "REVISIT-RACE": "an output block is written in one run of consecutive steps, never revisited",
     "REVISIT-PARK": "input parked outside its phase window must hold a constant block index",
-    "REVISIT-WRITE": "parked output never written: constant index while parked, index change at live->parked",
-    "LAYOUT-RANK": f"operand tiles keep >= {MIN_TILE_RANK} dims",
-    "LAYOUT-SUBLANE": "tile sublane dim is a multiple of sublane(dtype) — dtype-derived, not 8",
-    "LAYOUT-ROW": "pos/seg operands are (1, block) int32",
-    "LAYOUT-LSE": "LSE/delta residuals are (1, 1, block_q) f32",
+    "REVISIT-WRITE": "every run of an output block index contains a live (written) step",
+    "LAYOUT-RANK": f"operand blocks keep >= {MIN_TILE_RANK} dims",
+    "LAYOUT-SUBLANE": "second-minor block dim divisible by sublane(dtype) or equal to the array dim",
+    "LAYOUT-LANE": "minor block dim divisible by LANE or equal to the array dim",
     "FETCH-BOUNDS": "scalar-prefetch fetch indices in [0, n_blocks)",
     "FETCH-FILL": "fetch map is a monotone forward-fill; self-fetch exactly on live tiles",
     "FETCH-IDENTITY": "dense non-causal static fetch map is the identity",
@@ -99,27 +98,24 @@ class Finding:
 
 
 def _layout_findings(kernel: str, config: str, name: str, op: Operand) -> List[Finding]:
-    bs = tuple(int(d) for d in op.spec.block_shape)
+    bs = tuple(1 if d is None else int(d) for d in op.spec.block_shape)
     mk = lambda rule, detail: Finding(rule, kernel, config, f"operand {name!r}: {detail}")
     if len(bs) < MIN_TILE_RANK:
         return [mk("LAYOUT-RANK", f"block shape {bs} has rank {len(bs)} < {MIN_TILE_RANK} "
-                   "— Mosaic iota/tiling needs >= 2 dims")]
-    if op.role == "tile":
-        sq = [d for d in bs if d != 1]
-        if len(sq) < 2:
-            return [mk("LAYOUT-RANK", f"tile block {bs} squeezes to rank {len(sq)} < 2")]
-        sub = sublane(op.dtype)
-        if sq[-2] % sub:
-            return [mk("LAYOUT-SUBLANE",
-                       f"sublane dim {sq[-2]} of block {bs} is not a multiple of "
-                       f"{sub} (= sublane({op.dtype})) — a half-height tile for this dtype")]
-    elif op.role == "row":
-        if len(bs) != 2 or bs[0] != 1 or op.dtype != "int32":
-            return [mk("LAYOUT-ROW", f"expected a (1, block) int32 row, got block {bs} {op.dtype}")]
-    elif op.role == "lse":
-        if len(bs) != 3 or bs[:2] != (1, 1) or op.dtype != "float32":
-            return [mk("LAYOUT-LSE", f"expected a (1, 1, block_q) f32 residual, got block {bs} {op.dtype}")]
-    return []
+                   "— Mosaic tiling needs >= 2 dims")]
+    shape = tuple(op.shape)
+    findings = []
+    sub = sublane(op.dtype)
+    if bs[-2] % sub and bs[-2] != shape[-2]:
+        findings.append(mk("LAYOUT-SUBLANE",
+                           f"second-minor dim {bs[-2]} of block {bs} is neither a multiple of "
+                           f"{sub} (= sublane({op.dtype})) nor the array's {shape[-2]} "
+                           f"(array {shape})"))
+    if bs[-1] % LANE and bs[-1] != shape[-1]:
+        findings.append(mk("LAYOUT-LANE",
+                           f"minor dim {bs[-1]} of block {bs} is neither a multiple of "
+                           f"{LANE} nor the array's {shape[-1]} (array {shape})"))
+    return findings
 
 
 # ---------------------------------------------------------------------------
@@ -127,59 +123,57 @@ def _layout_findings(kernel: str, config: str, name: str, op: Operand) -> List[F
 # ---------------------------------------------------------------------------
 
 
+def _runs(seq: List[tuple]) -> List[tuple]:
+    """Maximal runs of equal consecutive indices: (index, first, last)."""
+    runs, start = [], 0
+    for i in range(1, len(seq) + 1):
+        if i == len(seq) or seq[i] != seq[start]:
+            runs.append((seq[start], start, i - 1))
+            start = i
+    return runs
+
+
 def _revisit_findings(kernel: str, config: str, name: str, op: Operand,
                       is_out: bool, seq: List[tuple], live: List[bool]) -> List[Finding]:
     findings: List[Finding] = []
     mk = lambda rule, detail: Finding(rule, kernel, config, f"operand {name!r}: {detail}")
-    n = len(seq)
+    runs = _runs(seq)
 
-    # parked segments hold a constant index (no DMA outside the window)
-    park_rule = "REVISIT-WRITE" if is_out else "REVISIT-PARK"
-    i = 0
-    while i < n:
-        if live[i]:
-            i += 1
-            continue
-        j = i
-        while j < n and not live[j]:
-            j += 1
-        if len(set(seq[i:j])) > 1:
-            findings.append(mk(park_rule,
-                               f"block index changes inside the parked segment (steps {i}..{j - 1}: "
-                               f"{sorted(set(seq[i:j]))[:4]}...) — outside its phase window the "
-                               "index map must park (constant index, zero DMA)"))
-            break
-        i = j
-
-    # a live->parked transition must change the index: the change forces the
-    # output's departure write-back at the phase boundary
-    if is_out and op.window is not None:
-        for i in range(n - 1):
-            if live[i] and not live[i + 1] and seq[i] == seq[i + 1]:
-                findings.append(mk("REVISIT-WRITE",
-                                   f"live->parked transition at step {i} keeps block index "
-                                   f"{seq[i]} — the elided write-back strands the last written "
-                                   "block in VMEM"))
-                break
-
-    # output revisit race: a block index recurring NON-consecutively within
-    # the live steps needs the accumulate-through-window declaration
-    if is_out and not op.accumulate:
-        runs: dict = {}
-        prev = None
-        for i in range(n):
-            if not live[i]:
-                prev = None
+    if not is_out:
+        # parked segments hold a constant index (no DMA outside the window)
+        i, n = 0, len(seq)
+        while i < n:
+            if live[i]:
+                i += 1
                 continue
-            if seq[i] != prev:
-                runs[seq[i]] = runs.get(seq[i], 0) + 1
-                prev = seq[i]
-        revisited = sorted(b for b, c in runs.items() if c > 1)
-        if revisited:
-            findings.append(mk("REVISIT-RACE",
-                               f"block(s) {revisited[:4]} revisited non-consecutively without an "
-                               "accumulate-through-window declaration — Mosaic must re-fetch the "
-                               "output window on revisit or the earlier write is lost"))
+            j = i
+            while j < n and not live[j]:
+                j += 1
+            if len(set(seq[i:j])) > 1:
+                findings.append(mk("REVISIT-PARK",
+                                   f"block index changes inside the parked segment (steps "
+                                   f"{i}..{j - 1}: {sorted(set(seq[i:j]))[:4]}...) — outside its "
+                                   "phase window the index map must park (constant index, zero DMA)"))
+                break
+            i = j
+        return findings
+
+    unwritten = [(b, lo, hi) for b, lo, hi in runs if not any(live[lo:hi + 1])]
+    if unwritten:
+        b, lo, hi = unwritten[0]
+        findings.append(mk("REVISIT-WRITE",
+                           f"block {b} is held over steps {lo}..{hi} with no live step — its "
+                           "copy-back writes a buffer the kernel never wrote; park on the "
+                           "index of an adjacent live step"))
+    seen: dict = {}
+    for b, _, _ in runs:
+        seen[b] = seen.get(b, 0) + 1
+    revisited = sorted(b for b, c in seen.items() if c > 1)
+    if revisited:
+        findings.append(mk("REVISIT-RACE",
+                           f"block(s) {revisited[:4]} recur in separate runs — an output "
+                           "block is never re-read from HBM, so the earlier run's writes "
+                           "are lost or overwritten"))
     return findings
 
 

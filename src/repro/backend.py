@@ -52,6 +52,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 FUSED = "fused"
 REFERENCE = "reference"
@@ -70,6 +71,13 @@ def default_interpret() -> bool:
     run in interpret mode (same kernel bodies, evaluated by jax).  The single
     platform probe every consumer delegates to."""
     return platform() != "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A kernel wrapper's ``interpret`` argument: None follows the platform
+    (default_interpret), so a caller that omits it runs Mosaic on a TPU and
+    the interpreter elsewhere — never the interpreter on the chip."""
+    return default_interpret() if interpret is None else interpret
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,24 +226,6 @@ def resolve_backend(spec: Any = None, use_pallas: Optional[bool] = None,
 # SPMD plan: shard_map wrappers for the flat-buffer pallas_calls
 # ---------------------------------------------------------------------------
 
-# shard_map moved out of experimental (and check_rep was renamed check_vma)
-# across the supported jax range; probe both independently.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHMAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
-
-from jax.sharding import PartitionSpec as P  # noqa: E402
-
-
 class FlatSpmd:
     """Per-shard execution of the flat-buffer kernels under shard_map.
 
@@ -306,22 +296,18 @@ class FlatSpmd:
         return P(self._axes(layout), None)
 
     def _smap(self, fn, in_specs, out_specs):
-        return _shard_map(
-            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs, **_SHMAP_KW
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
         )
 
     def _meta(self, layout, pad: int = 0):
-        import numpy as np
-
         lids = jnp.asarray(layout.block_leaf_ids())
         invsz = jnp.asarray(layout.leaf_inv_sizes())
-        rl = jnp.asarray(np.asarray(layout.row_leaf_ids()))
         if pad:
             # pad blocks carry leaf id 0: their zero rows contribute exact
             # zeros to leaf 0's partial sums (additive no-ops)
-            lids = jnp.pad(lids, ((0, pad // layout.block_rows), (0, 0)))
-            rl = jnp.pad(rl, (0, pad))
-        return lids, invsz, rl
+            lids = jnp.pad(lids, (0, pad // layout.block_rows))
+        return lids, invsz
 
     # -- flat-stats sweeps (element-wise: shard with no collective) ---------
 
@@ -370,7 +356,7 @@ class FlatSpmd:
         interp = self._interp()
         axes = self._axes(layout)
         pad = self._pad_rows(layout)
-        lids, invsz, _ = self._meta(layout, pad)
+        lids, invsz = self._meta(layout, pad)
         row = self._row_spec(layout)
 
         def body(lids, invsz, g, ga, g2):
@@ -382,7 +368,7 @@ class FlatSpmd:
             )
 
         out = self._smap(
-            body, (row, P(None, None), row, row, row), (row, row)
+            body, (P(axes), P(None, None), row, row, row), (row, row)
         )(lids, invsz, self._padded(g, pad), self._padded(ga, pad), self._padded(g2, pad))
         return tuple(o[: layout.n_rows] for o in out)
 
@@ -393,7 +379,7 @@ class FlatSpmd:
         interp = self._interp()
         axes = self._axes(layout)
         pad = self._pad_rows(layout)
-        lids, invsz, _ = self._meta(layout, pad)
+        lids, invsz = self._meta(layout, pad)
         row = self._row_spec(layout)
         rep = P(None, None)
 
@@ -407,22 +393,23 @@ class FlatSpmd:
             )
 
         out = self._smap(
-            body, (row, rep, rep) + (row,) * 7, (row,) * 4
+            body, (P(axes), rep, rep) + (row,) * 7, (row,) * 4
         )(lids, invsz, scal, *(self._padded(x, pad) for x in (g, ga, g2, m, v, p, w)))
         return tuple(o[: layout.n_rows] for o in out)
 
     def vr_lamb(self, g, ga, g2, m, v, p, w, scal, layout, *,
                 b1, b2, b3, eps, wd, gamma, gsnr_eps, state_dtype):
         from repro.kernels import flat_spmd as fsp
+        from repro.kernels import flat_update as fu
 
         interp = self._interp()
         axes = self._axes(layout)
         pad = self._pad_rows(layout)
-        lids, invsz, rl = self._meta(layout, pad)
+        lids, invsz = self._meta(layout, pad)
         row = self._row_spec(layout)
         rep = P(None, None)
 
-        def body(lids, invsz, rl, scal, g, ga, g2, m, v, p, w):
+        def body(lids, invsz, scal, g, ga, g2, m, v, p, w):
             racc = fsp.leaf_r_partials(g, g2, lids, layout, gsnr_eps=gsnr_eps, interpret=interp)
             racc = jax.lax.psum(racc, axes)
             u, m2, v2, p2, uacc, wacc = fsp.vr_lamb_compute(
@@ -433,27 +420,27 @@ class FlatSpmd:
             uacc = jax.lax.psum(uacc, axes)
             wacc = jax.lax.psum(wacc, axes)
             # per-leaf trust-ratio apply: a tiny element-wise epilogue XLA
-            # fuses into the surrounding step — not worth a third launch
-            ratio = fsp.trust_from_partials(uacc, wacc, numer_is_phi=True, trust=0.0)
-            upd = -scal[0, 0] * ratio[rl][:, None] * u
-            return upd, m2, v2, p2
+            # fuses into the surrounding step — the same one the gathered
+            # kernel's wrapper runs
+            return fu.lamb_epilogue(u, uacc, wacc, scal[0, 0], lids), m2, v2, p2
 
         out = self._smap(
-            body, (row, rep, P(axes), rep) + (row,) * 7, (row,) * 4
-        )(lids, invsz, rl, scal, *(self._padded(x, pad) for x in (g, ga, g2, m, v, p, w)))
+            body, (P(axes), rep, rep) + (row,) * 7, (row,) * 4
+        )(lids, invsz, scal, *(self._padded(x, pad) for x in (g, ga, g2, m, v, p, w)))
         return tuple(o[: layout.n_rows] for o in out)
 
     def vr_lars(self, g, ga, g2, m, w, scal, layout, *, mu, wd, trust, eps):
         from repro.kernels import flat_spmd as fsp
+        from repro.kernels import flat_update as fu
 
         interp = self._interp()
         axes = self._axes(layout)
         pad = self._pad_rows(layout)
-        lids, invsz, rl = self._meta(layout, pad)
+        lids, invsz = self._meta(layout, pad)
         row = self._row_spec(layout)
         rep = P(None, None)
 
-        def body(lids, invsz, rl, scal, g, ga, g2, m, w):
+        def body(lids, invsz, scal, g, ga, g2, m, w):
             racc = fsp.leaf_r_partials(g, g2, lids, layout, gsnr_eps=eps, interpret=interp)
             racc = jax.lax.psum(racc, axes)
             u, uacc, wacc = fsp.vr_lars_compute(
@@ -462,11 +449,9 @@ class FlatSpmd:
             )
             uacc = jax.lax.psum(uacc, axes)
             wacc = jax.lax.psum(wacc, axes)
-            ratio = fsp.trust_from_partials(uacc, wacc, numer_is_phi=False, trust=trust)
-            m_new = mu * m.astype(jnp.float32) + ratio[rl][:, None] * u
-            return -scal[0, 0] * m_new, m_new
+            return fu.lars_epilogue(u, m, uacc, wacc, scal[0, 0], lids, mu=mu, trust=trust)
 
         out = self._smap(
-            body, (row, rep, P(axes), rep) + (row,) * 5, (row, row)
-        )(lids, invsz, rl, scal, *(self._padded(x, pad) for x in (g, ga, g2, m, w)))
+            body, (P(axes), rep, rep) + (row,) * 5, (row, row)
+        )(lids, invsz, scal, *(self._padded(x, pad) for x in (g, ga, g2, m, w)))
         return tuple(o[: layout.n_rows] for o in out)

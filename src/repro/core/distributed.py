@@ -25,21 +25,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.gsnr import GradStats
 
-# Top-level export landed before the check_rep -> check_vma rename, so probe
-# the module location and the kwarg name independently.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHMAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
-
 PyTree = Any
 _tm = jax.tree_util.tree_map
 
@@ -141,12 +126,12 @@ def device_grad_stats_fn(
         aux_out = aux if has_aux else jnp.zeros(())
         return loss, aux_out, stats.mean, stats.sq_mean, terms
 
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         inner2,
         mesh=mesh,
         in_specs=(P(), P(data_axis)),
         out_specs=(P(), P(), P(), P(), P()),
-        **_SHMAP_KW,
+        check_vma=False,
     )
 
     @functools.wraps(loss_fn)

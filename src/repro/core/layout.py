@@ -125,12 +125,12 @@ class ParamLayout:
         return -(-self.n_leaves // SUBLANE) * SUBLANE
 
     def block_leaf_ids(self) -> np.ndarray:
-        """(n_blocks, 1) int32: which leaf each grid block belongs to."""
-        ids = np.repeat(
+        """(n_blocks,) int32: which leaf each grid block belongs to (the
+        kernels' scalar-prefetched SMEM map)."""
+        return np.repeat(
             np.arange(self.n_leaves, dtype=np.int32),
             np.asarray(self.leaf_rows, np.int64) // self.block_rows,
         )
-        return ids.reshape(-1, 1)
 
     def row_leaf_ids(self) -> np.ndarray:
         """(n_rows,) int32 leaf id per row (jnp segment reductions)."""

@@ -1,11 +1,12 @@
-# Pallas TPU kernels (interpret-mode validated on CPU by tests/oracle.py):
+# Pallas TPU kernels (interpret-mode validated on CPU by tests/oracle.py;
+# main-path kernels compile-tested for TPU v5e by tests/test_tpu_compile.py):
 #
 # Flat-buffer path (the dispatch target — ONE pallas_call per optimizer step
 # over the ParamLayout flat buffer, core/layout.py):
 #   flat_update.flat_vr_scale   — 2-phase fused GSNR pipeline (VR-SGD/Momentum)
 #   flat_update.flat_vr_adam    — 2-phase full VR-Adam step (r-mean in-grid)
-#   flat_update.flat_vr_lamb    — 3-phase VR-LAMB + in-grid trust-ratio norms
-#   flat_update.flat_vr_lars    — 3-phase VR-LARS + in-grid trust-ratio norms
+#   flat_update.flat_vr_lamb    — 2-phase VR-LAMB + in-grid trust-ratio norms
+#   flat_update.flat_vr_lars    — 2-phase VR-LARS + in-grid trust-ratio norms
 #   flat_stats.flat_moments_*   — flat k-group moment accumulation/finalize
 #   flat_stats.flat_vmap_moments— batched (k, param) stack -> moments
 #

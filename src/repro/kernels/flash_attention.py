@@ -70,6 +70,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.analysis.layout_contracts import LANE
+from repro.backend import resolve_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -99,8 +102,9 @@ def tile_mask(qp, kp, qs, ks, causal: bool, window: int):
     backward kernels so the backward's softmax recompute p = exp(s - lse) can
     never drift from the mask the forward's lse was built under.
 
-    qp/qs: (1, block_q) or (block_q,) int32, kp/ks likewise for block_k
-    (rank-normalized here: q-side to columns, k-side to rows); out-of-range
+    qp/qs: (block_q, 1) or (block_q,) int32, kp/ks (1, block_k) or
+    (block_k,) (rank-normalized here: q-side to columns, k-side to rows —
+    a no-op on the kernels' own column/row tiles); out-of-range
     rows of partial edge tiles arrive as pos -1 / seg < 0 (see
     _load_pos_seg), so the ``pos >= 0`` terms subsume the old seq-bound
     checks.
@@ -142,8 +146,9 @@ def tile_reachable(qp, kp, qs, ks, causal: bool, window: int):
     positions were implicit (no bound reductions on a layout whose dead
     tiles are known from grid indices alone).
     """
-    qp, qs = qp.reshape(1, -1), qs.reshape(1, -1)  # rank-2 for the VPU
-    kp, ks = kp.reshape(1, -1), ks.reshape(1, -1)
+    # rank-2 for the VPU; in-kernel operands already arrive rank-2 (q column,
+    # k row) and are reduced as they are, with no relayout
+    qp, qs, kp, ks = (x.reshape(1, -1) if x.ndim == 1 else x for x in (qp, qs, kp, ks))
     qv, kv = qp >= 0, kp >= 0
     qp_max = jnp.max(jnp.where(qv, qp, -_BIG))
     kp_min = jnp.min(jnp.where(kv, kp, _BIG))
@@ -172,13 +177,18 @@ def zero_oob_rows(x, i, block: int, seq: int):
 
 
 def _load_pos_seg(pos_ref, seg_ref, i, block: int, seq: int, seg_fill: int):
-    """Sanitized (1, block) pos/seg tiles: entries beyond ``seq`` (the
-    NaN/garbage padding of partial edge blocks) become pos -1 and a negative
-    seg sentinel.  seg_fill differs between the q (-1) and k (-2) sides so
+    """Sanitized pos/seg tiles: entries beyond ``seq`` (the NaN/garbage
+    padding of partial edge blocks) become pos -1 and a negative seg
+    sentinel.  seg_fill differs between the q (-1) and k (-2) sides so
     out-of-range q rows can never segment-match out-of-range k rows.
-    Everything stays rank-2 (Mosaic rejects iota of rank < 2 — same reason
-    zero_oob_rows shapes its iota (block, 1))."""
-    idx = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, pos_ref.shape[-1]), 1)
+
+    The q side arrives as a (block, 1) column and the k side as a (1, block)
+    row, so ``tile_mask`` broadcasts them into the (block_q, block_k) tile
+    with no in-kernel transpose.  Everything stays rank-2 (Mosaic rejects
+    iota of rank < 2)."""
+    shape = pos_ref.shape
+    axis = 0 if shape[0] == block else 1
+    idx = i * block + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
     valid = idx < seq
     pos = jnp.where(valid, pos_ref[...], -1)
     seg = jnp.where(valid, seg_ref[...], seg_fill)
@@ -285,9 +295,9 @@ def _kernel(
     kp, ks = _load_pos_seg(kp_ref, ks_ref, ik, block_k, seq_kv, seg_fill=-2)
 
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (BQ, D)
-        k, _ = zero_oob_rows(k_ref[0, :, 0, :].astype(jnp.float32), ik, block_k, seq_kv)
-        v, _ = zero_oob_rows(v_ref[0, :, 0, :].astype(jnp.float32), ik, block_k, seq_kv)
+        q = q_ref[...].astype(jnp.float32)  # (BQ, D)
+        k, _ = zero_oob_rows(k_ref[...].astype(jnp.float32), ik, block_k, seq_kv)
+        v, _ = zero_oob_rows(v_ref[...].astype(jnp.float32), ik, block_k, seq_kv)
         s = jax.lax.dot_general(
             q * scale, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (BQ, BK)
@@ -295,17 +305,17 @@ def _kernel(
         mask = tile_mask(qp, kp, qs, ks, causal, window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
+        m_prev = m_scr[...]  # (BQ, 1)
         l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # exact zeros for masked entries: a fully-masked row has s == m ==
         # NEG_INF everywhere, where exp(s - m) would be 1 and the row would
         # silently turn into a uniform average over kv — the l stays 0 so
         # _finalize can emit 0.
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_scr[...] = m_new
@@ -331,57 +341,82 @@ def _kernel(
     def _finalize():
         l = l_scr[...]
         valid = l > 0.0  # rows with at least one unmasked kv position
-        o_ref[0, :, 0, :] = jnp.where(
-            valid[:, None], acc_scr[...] / jnp.maximum(l, 1e-30)[:, None], 0.0
+        o_ref[...] = jnp.where(
+            valid, acc_scr[...] / jnp.maximum(l, 1e-30), 0.0
         ).astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0, 0, :] = jnp.where(
+            lse_ref[...] = jnp.where(
                 valid, m_scr[...] + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF
             )
 
 
-def fwd_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int, with_lse: bool):
-    """Grid + named BlockSpecs of the forward pallas_call.
+def bhsd(x):
+    """(B, S, H, D) <-> (B, H, S, D).  The kernels run on the head-major
+    layout, so every block's last two dims are (rows, head_dim) — the only
+    tiling Mosaic accepts for D < 128 (a (block, 1, D) block of a
+    (B, S, H, D) array has a sublane dim of 1)."""
+    return jnp.swapaxes(x, 1, 2)
 
-    Single source of truth shared between _fwd_call and
-    benchmarks.cost_model.  Every index map takes the flattened
-    (B*nq*nk,) int32 fetch array as its trailing scalar-prefetch argument;
-    the kv-side maps (k, v, k_pos, k_seg) read the fetch-mapped block so
-    dead grid steps repeat the previous index and Mosaic elides their
-    copy-in.
+
+def fwd_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int, with_lse: bool):
+    """Grid, named BlockSpecs and array shapes of the forward pallas_call.
+
+    Single source of truth shared between _fwd_call, the contract checker
+    and benchmarks.cost_model.  q/out are (B, H, Sq, D), k/v (B, KV, Skv, D);
+    the q-side pos/seg operands are (B, Sq, 1) columns and the k-side ones
+    (B, 1, Skv) rows; the LSE residual is (B, H, Sq, 1).  Every index map
+    takes the flattened (B*nq*nk,) int32 fetch array as its trailing
+    scalar-prefetch argument; the kv-side maps (k, v, k_pos, k_seg) read the
+    fetch-mapped block so dead grid steps repeat the previous index and
+    Mosaic elides their copy-in.
     """
     g = h // kvh
     nq = -(-sq // block_q)
     nk = -(-skv // block_k)
     grid = (b, h, nq, nk)
 
-    def kv_block(b_, h_, iq, ik, f):
-        return (b_, f[(b_ * nq + iq) * nk + ik], h_ // g, 0)
+    def fetched(b_, iq, ik, f):
+        return f[(b_ * nq + iq) * nk + ik]
 
-    def krow(b_, h_, iq, ik, f):
-        return (b_, f[(b_ * nq + iq) * nk + ik])
-
-    q_spec = pl.BlockSpec((1, block_q, 1, d), lambda b_, h_, iq, ik, f: (b_, iq, h_, 0))
-    kv_spec = pl.BlockSpec((1, block_k, 1, d), kv_block)
-    qrow_spec = pl.BlockSpec((1, block_q), lambda b_, h_, iq, ik, f: (b_, iq))
-    krow_spec = pl.BlockSpec((1, block_k), krow)
+    q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h_, iq, ik, f: (b_, h_, iq, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, d),
+        lambda b_, h_, iq, ik, f: (b_, h_ // g, fetched(b_, iq, ik, f), 0))
+    qcol_spec = pl.BlockSpec((None, block_q, 1), lambda b_, h_, iq, ik, f: (b_, iq, 0))
+    krow_spec = pl.BlockSpec(
+        (None, 1, block_k), lambda b_, h_, iq, ik, f: (b_, 0, fetched(b_, iq, ik, f)))
     ins = {
-        "q": q_spec, "k": kv_spec, "v": kv_spec, "q_pos": qrow_spec,
-        "k_pos": krow_spec, "q_seg": qrow_spec, "k_seg": krow_spec,
+        "q": q_spec, "k": kv_spec, "v": kv_spec, "q_pos": qcol_spec,
+        "k_pos": krow_spec, "q_seg": qcol_spec, "k_seg": krow_spec,
     }
     outs = {"out": q_spec}
+    shapes = {
+        "q": (b, h, sq, d), "k": (b, kvh, skv, d), "v": (b, kvh, skv, d),
+        "q_pos": (b, sq, 1), "k_pos": (b, 1, skv), "q_seg": (b, sq, 1),
+        "k_seg": (b, 1, skv), "out": (b, h, sq, d),
+    }
     if with_lse:
-        outs["lse"] = pl.BlockSpec((1, 1, block_q), lambda b_, h_, iq, ik, f: (b_, h_, iq))
-    return grid, nq, nk, g, ins, outs
+        outs["lse"] = pl.BlockSpec((None, None, block_q, 1),
+                                   lambda b_, h_, iq, ik, f: (b_, h_, iq, 0))
+        shapes["lse"] = (b, h, sq, 1)
+    return grid, nq, nk, g, ins, outs, shapes
+
+
+def pos_operands(q_pos, k_pos, q_seg, k_seg):
+    """(B, S) int32 pos/seg -> the kernels' operands: q side as (B, Sq, 1)
+    columns, k side as (B, 1, Skv) rows."""
+    return q_pos[:, :, None], k_pos[:, None, :], q_seg[:, :, None], k_seg[:, None, :]
 
 
 def _fwd_call(q, k, v, q_pos, k_pos, q_seg, k_seg,
               *, causal, window, block_q, block_k, interpret, with_lse, implicit):
-    """One pallas_call: out (B,S,H,D) [+ lse (B,H,S) f32 when with_lse]."""
-    b, sq, h, d = q.shape
-    skv, kvh = k.shape[1], k.shape[2]
+    """One pallas_call on the head-major layout: q (B,H,Sq,D), k/v
+    (B,KV,Skv,D), pos/seg (B,S) int32 -> out (B,H,Sq,D) [+ lse (B,H,Sq,1)
+    f32 when with_lse]."""
+    b, h, sq, d = q.shape
+    kvh, skv = k.shape[1], k.shape[2]
     scale = d**-0.5
-    grid, nq, nk, g, ins, out_spec_map = fwd_geometry(
+    grid, nq, nk, g, ins, out_spec_map, _ = fwd_geometry(
         b, sq, h, d, skv, kvh, block_q=block_q, block_k=block_k, with_lse=with_lse
     )
     if implicit:
@@ -397,17 +432,17 @@ def _fwd_call(q, k, v, q_pos, k_pos, q_seg, k_seg,
             causal=causal, window=window, block_q=block_q, block_k=block_k,
         )
         fetch = fetch.reshape(-1)
-    out_shape = [jax.ShapeDtypeStruct((b, sq, h, d), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)]
     if with_lse:
-        out_shape.append(jax.ShapeDtypeStruct((b, h, sq), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=list(ins.values()),
         out_specs=list(out_spec_map.values()),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
     )
@@ -420,7 +455,7 @@ def _fwd_call(q, k, v, q_pos, k_pos, q_seg, k_seg,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(fetch, q, k, v, q_pos, k_pos, q_seg, k_seg)
+    )(fetch, q, k, v, *pos_operands(q_pos, k_pos, q_seg, k_seg))
     return tuple(outs) if with_lse else (outs[0],)
 
 
@@ -430,7 +465,8 @@ _NO_POS_GRADS = (None, None, None, None)  # int operands: symbolic-zero cotangen
 @functools.lru_cache(maxsize=None)
 def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
               interpret: bool, implicit: bool):
-    """custom_vjp'd flash attention for one static config.
+    """custom_vjp'd flash attention on the head-major (B,H,S,D) layout for
+    one static config.
 
     Three nested custom_vjp layers keep every pallas_call out of autodiff's
     reach while staying differentiable to arbitrary order:
@@ -438,7 +474,7 @@ def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
       flash     primal: fused fwd (no LSE).  vjp: fused bwd via _bwd_p.
       _fwd_p    primal: fused fwd emitting LSE (the residual producer).
                 vjp (2nd order+): jnp replica attention_fwd_ref.
-      _bwd_p    primal: fused dq + dk/dv kernels.
+      _bwd_p    primal: fused dq + dk/dv kernel.
                 vjp (2nd order+): jnp replica attention_bwd_ref.
 
     All three take the (q_pos, k_pos, q_seg, k_seg) int operands positionally
@@ -459,12 +495,14 @@ def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
 
     def _fwd_p_bwd(res, ct):
         q, k, v, qp, kp, qs, ks = res
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: fab.attention_fwd_ref(
-                q_, k_, v_, causal=causal, window=window, **pos_kw(qp, kp, qs, ks)
-            ),
-            q, k, v,
-        )
+
+        def ref(q_, k_, v_):  # the BSHD oracle, on the kernels' layout
+            out, lse = fab.attention_fwd_ref(
+                bhsd(q_), bhsd(k_), bhsd(v_), causal=causal, window=window,
+                **pos_kw(qp, kp, qs, ks))
+            return bhsd(out), lse[..., None]
+
+        _, vjp = jax.vjp(ref, q, k, v)
         return vjp(ct) + _NO_POS_GRADS
 
     _fwd_p.defvjp(_fwd_p_fwd, _fwd_p_bwd)
@@ -502,8 +540,8 @@ def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
         q, k, v, out, lse, qp, kp, qs, ks = res
         # FlashAttention-2 preprocess: delta_i = <dO_i, O_i> — one cheap
         # element-wise jnp pass (XLA fuses it), not a kernel launch.
-        delta = jnp.einsum(
-            "bshd,bshd->bhs", do.astype(jnp.float32), out.astype(jnp.float32)
+        delta = jnp.sum(
+            do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
         )
         return _bwd_p(q, k, v, lse, delta, do, qp, kp, qs, ks) + _NO_POS_GRADS
 
@@ -578,9 +616,13 @@ def flash_attention(
     window: int = 0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """q: (B,S,H,D); k,v: (B,Skv,KV,D) -> (B,S,H,D).  Differentiable.
+
+    The kernels run on the head-major (B,H,S,D) layout (``bhsd``); the
+    transposes in and out are XLA ops around the custom VJP.  ``interpret``
+    None follows the platform (repro.backend.default_interpret).
 
     q_pos/k_pos: optional (B, S)/(B, Skv) int32 absolute positions (pos < 0
     = padding); omitted -> the implicit training arange, which REQUIRES
@@ -601,9 +643,8 @@ def flash_attention(
     k_seg = jnp.broadcast_to(k_seg, (b, skv))
     block_q = min(block_q, sq)
     block_k = min(block_k, skv)
-    return _flash_fn(causal, window, block_q, block_k, interpret, implicit)(
-        q, k, v, q_pos, k_pos, q_seg, k_seg
-    )
+    fn = _flash_fn(causal, window, block_q, block_k, resolve_interpret(interpret), implicit)
+    return bhsd(fn(bhsd(q), bhsd(k), bhsd(v), q_pos, k_pos, q_seg, k_seg))
 
 
 # ---------------------------------------------------------------------------
@@ -629,7 +670,7 @@ def _analysis_geometry(B, S, H, KV, D, *, causal=True, window=0, docs=None,
     from repro.analysis.registry import FetchMap, Geometry, Operand
 
     bq, bk = min(block_q, S), min(block_k, S)
-    grid, nq, nk, _, ins, outs = fwd_geometry(
+    grid, nq, nk, _, ins, outs, shapes = fwd_geometry(
         B, S, H, D, S, KV, block_q=bq, block_k=bk, with_lse=True)
     if docs is not None:
         qp, kp, qs, ks = resolve_positions(
@@ -646,17 +687,15 @@ def _analysis_geometry(B, S, H, KV, D, *, causal=True, window=0, docs=None,
                       dense_identity=not causal and window == 0)
 
     def op(name, spec):
-        if name in ("q_pos", "k_pos", "q_seg", "k_seg"):
-            return Operand(spec, dtype="int32", role="row")
-        if name == "lse":
-            return Operand(spec, dtype="float32", role="lse")
-        return Operand(spec, dtype=dtype)
+        dt = {"lse": "float32"}.get(name, "int32" if name.endswith(("_pos", "_seg")) else dtype)
+        return Operand(spec, shapes[name], dtype=dt)
 
     return Geometry(
         grid=grid,
         ins={n: op(n, s) for n, s in ins.items()},
         outs={n: op(n, s) for n, s in outs.items()},
-        scratch_bytes=4 * (bq + bq + bq * D),
+        # m/l are (bq, 1) columns, lane-padded in VMEM
+        scratch_bytes=4 * (2 * bq * LANE + bq * D),
         extra=(fetch.reshape(-1),),
         fetch_maps={"kv": fm},
     )

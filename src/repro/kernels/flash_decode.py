@@ -15,7 +15,11 @@ a decode query stream is a different position stream than the cache —
 derived per-stream ordinals cannot align (resolve_positions docstring), so
 there is no safe default to fall back to.
 
-Mosaic checklist (pallas_guide):
+Mosaic checklist (compile-tested for v5e in tests/test_tpu_compile.py):
+  * the kernels run head-major: q and the paged cache are transposed to
+    (B, H, L, D) / (B, KV, C, D) here, so every tile's last two dims are
+    (rows, D).  The cache transpose is a full copy per call — the price
+    until the cache itself is stored head-major.
   * the min tile is DTYPE-DEPENDENT — (8, 128) for f32 but (16, 128) for
     bf16: the lane axis is the kernel's sublane axis, so L is padded up to
     the query dtype's sublane multiple (_sublane) with pos = -1 / seg = -1
@@ -23,8 +27,7 @@ Mosaic checklist (pallas_guide):
     8 would hand Mosaic a half-height bf16 q tile.
   * block_q covers the whole padded lane axis (one q tile per row); block_k
     tiles the cache, so dead cache tiles (kpos still -1 past the fill
-    cursor) are skipped by tile_reachable's pos/seg bounds.
-  * iota inside the kernel is rank-2 (handled by _load_pos_seg already).
+    cursor) are skipped through the prefetched fetch map.
 """
 from __future__ import annotations
 
@@ -33,8 +36,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.layout_contracts import LANE
 from repro.analysis.layout_contracts import sublane as _sublane
-from repro.kernels.flash_attention import DEFAULT_BLOCK_K, _fwd_call
+from repro.backend import resolve_interpret
+from repro.kernels.flash_attention import DEFAULT_BLOCK_K, _fwd_call, bhsd
 
 
 @functools.partial(
@@ -52,7 +57,7 @@ def flash_decode(
     causal: bool = True,
     window: int = 0,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """q: (B,L,H,D) decode lanes; k,v: (B,C,KV,D) paged cache -> (B,L,H,D).
 
@@ -81,12 +86,12 @@ def flash_decode(
     k_pos = jnp.broadcast_to(jnp.asarray(k_pos, jnp.int32), (b, skv))
     k_seg = jnp.broadcast_to(jnp.asarray(k_seg, jnp.int32), (b, skv))
     out = _fwd_call(
-        q, k, v, q_pos, k_pos, q_seg, k_seg,
+        bhsd(q), bhsd(k), bhsd(v), q_pos, k_pos, q_seg, k_seg,
         causal=causal, window=window,
         block_q=lp, block_k=min(block_k, skv),
-        interpret=interpret, with_lse=False, implicit=False,
+        interpret=resolve_interpret(interpret), with_lse=False, implicit=False,
     )[0]
-    return out[:, :l]
+    return bhsd(out)[:, :l]
 
 # ---------------------------------------------------------------------------
 # contract registration (repro.analysis): decode replayed over a synthetic
@@ -105,7 +110,7 @@ def _analysis_geometry(B, L, C, H, KV, D, *, dtype="float32",
 
     lp = -(-L // _sublane(dtype)) * _sublane(dtype)
     bk = min(block_k, C)
-    grid, _, nk, _, ins, outs = fwd_geometry(
+    grid, _, nk, _, ins, outs, shapes = fwd_geometry(
         B, lp, H, D, C, KV, block_q=lp, block_k=bk, with_lse=False)
 
     # arrival-ordered cache: slots alternate between two segments up to the
@@ -127,15 +132,14 @@ def _analysis_geometry(B, L, C, H, KV, D, *, dtype="float32",
     fetch, live = np.asarray(fetch), np.asarray(live)
 
     def op(name, spec):
-        if name in ("q_pos", "k_pos", "q_seg", "k_seg"):
-            return Operand(spec, dtype="int32", role="row")
-        return Operand(spec, dtype=dtype)
+        dt = "int32" if name.endswith(("_pos", "_seg")) else dtype
+        return Operand(spec, shapes[name], dtype=dt)
 
     return Geometry(
         grid=grid,
         ins={n: op(n, s) for n, s in ins.items()},
         outs={n: op(n, s) for n, s in outs.items()},
-        scratch_bytes=4 * (lp + lp + lp * D),
+        scratch_bytes=4 * (2 * lp * LANE + lp * D),
         extra=(fetch.reshape(-1),),
         fetch_maps={"kv": FetchMap(fetch, live=live, n_blocks=nk)},
     )

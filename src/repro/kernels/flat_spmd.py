@@ -16,9 +16,10 @@ the reduction must split:
   3. a per-shard APPLY / COMPUTE kernel taking the combined accumulator as
      an ordinary operand where the fused kernel read its scratch.
 
-The element-wise math is IMPORTED from flat_update (``_raw_r``,
-``_inv_mean_r``, ``_adam_math``), so per-shard and single-launch paths
-cannot drift.  Numerics: a shard accumulates its blocks in the same order
+The block bodies are IMPORTED from flat_update (``_accumulate_r``,
+``_scale_apply``, ``_adam_apply``, ``_lamb_compute``, ``_lars_compute``),
+so per-shard and single-launch paths cannot drift.  Numerics: a shard
+accumulates its blocks in the same order
 the fused kernel's phase-0 sweep does, and shards that hold none of a
 leaf's rows contribute exact zero partials — so whenever no leaf straddles
 a shard boundary the combined scalars (and therefore the whole update) are
@@ -27,10 +28,10 @@ one addition per boundary (~1 ulp on the leaf scalar).
 
 Grids derive from the LOCAL operand shapes (``g.shape[0] // block_rows``) —
 the same wrappers serve any shard count, including 1 (the differential
-tests run them unsharded against the fused kernels).  The (n_blocks, 1)
-leaf-id map rides as a SHARDED operand: its row split under the same
-PartitionSpec is exactly the buffer's block split, so each shard reads its
-own leaf ids with no index arithmetic.
+tests run them unsharded against the fused kernels).  The (n_blocks,)
+leaf-id map is SHARDED under the same PartitionSpec — its split is exactly
+the buffer's block split — and each shard scalar-prefetches its own slice
+into SMEM, so it reads its leaf ids with no index arithmetic.
 
 PHASE-AWARE maps don't apply here: these per-shard kernels run SINGLE-PHASE
 1-D grids (the multi-phase structure lives in the gathered flat_update
@@ -49,32 +50,31 @@ from jax.experimental import pallas as pl
 
 from repro.core.layout import LANE, ParamLayout
 from repro.kernels.flat_stats import _local_blocks
-from repro.kernels.flat_update import _adam_math, _inv_mean_r, _raw_r
+from repro.kernels.flat_update import (
+    _accumulate_r,
+    _adam_apply,
+    _call,
+    _lamb_compute,
+    _lars_compute,
+    _scale_apply,
+)
 
 _f32 = jnp.float32
 
 
 def _specs(layout: ParamLayout):
-    """(row-block, leaf-id, accumulator, inv-size, scalar) BlockSpecs for a
-    1-D local grid over row blocks."""
-    blk = pl.BlockSpec((layout.block_rows, LANE), lambda b: (b, 0))
-    lid = pl.BlockSpec((1, 1), lambda b: (b, 0))
-    acc = pl.BlockSpec((layout.leaf_slots, LANE), lambda b: (0, 0))
-    inv = pl.BlockSpec((layout.leaf_slots, 1), lambda b: (0, 0))
-    scal = pl.BlockSpec((1, 8), lambda b: (0, 0))
-    return blk, lid, acc, inv, scal
+    """(row-block, accumulator, inv-size, scalar) BlockSpecs for a 1-D local
+    grid over row blocks; every index map also receives the SMEM leaf ids."""
+    blk = pl.BlockSpec((layout.block_rows, LANE), lambda b, lids: (b, 0))
+    acc = pl.BlockSpec((layout.leaf_slots, LANE), lambda b, lids: (0, 0))
+    inv = pl.BlockSpec((layout.leaf_slots, 1), lambda b, lids: (0, 0))
+    scal = pl.BlockSpec((1, 8), lambda b, lids: (0, 0))
+    return blk, acc, inv, scal
 
 
-def trust_from_partials(uacc, wacc, *, numer_is_phi: bool, trust: float):
-    """Per-leaf LAMB/LARS trust ratio from the psum-combined norm partials.
-
-    Mirrors flat_update._trust_ratio term for term (jnp.sum over the LANE
-    row, sqrt, phi clamp) so the sharded epilogue matches the in-kernel
-    phase-2 math exactly."""
-    un = jnp.sqrt(jnp.sum(uacc, axis=1))
-    pn = jnp.sqrt(jnp.sum(wacc, axis=1))
-    numer = jnp.clip(pn, 0.0, 10.0) if numer_is_phi else trust * pn
-    return jnp.where((pn > 0) & (un > 0), numer / (un + 1e-12), 1.0)
+def _launch(kernel, layout, g, lids, in_specs, out_specs, out_shape, interpret, *args):
+    return _call(kernel, lids, _local_blocks(g, layout), in_specs, out_specs,
+                 out_shape, [], interpret, *args, phases=None)
 
 
 # ---------------------------------------------------------------------------
@@ -82,31 +82,26 @@ def trust_from_partials(uacc, wacc, *, numer_is_phi: bool, trust: float):
 # ---------------------------------------------------------------------------
 
 
-def _r_partials_kernel(lid_ref, g_ref, g2_ref, racc_ref, *, gsnr_eps):
+def _r_partials_kernel(lids_ref, g_ref, g2_ref, racc_ref, *, gsnr_eps):
     b = pl.program_id(0)
 
     @pl.when(b == 0)
     def _init():
         racc_ref[...] = jnp.zeros_like(racc_ref)
 
-    leaf = lid_ref[0, 0]
-    racc_ref[pl.ds(leaf, 1), :] += jnp.sum(
-        _raw_r(g_ref, g2_ref, gsnr_eps), axis=0, keepdims=True
-    )
+    _accumulate_r(racc_ref, g_ref, g2_ref, lids_ref[b], gsnr_eps)
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "gsnr_eps", "interpret"))
-def leaf_r_partials(g, g2, lids, layout: ParamLayout, *, gsnr_eps, interpret: bool = True):
+def leaf_r_partials(g, g2, lids, layout: ParamLayout, *, gsnr_eps,
+                    interpret: bool | None = None):
     """Shard-local per-leaf Σ r_raw partials: one launch over the local rows."""
-    blk, lid, acc, _, _ = _specs(layout)
-    return pl.pallas_call(
-        functools.partial(_r_partials_kernel, gsnr_eps=gsnr_eps),
-        grid=(_local_blocks(g, layout),),
-        in_specs=[lid, blk, blk],
-        out_specs=acc,
-        out_shape=jax.ShapeDtypeStruct((layout.leaf_slots, LANE), _f32),
-        interpret=interpret,
-    )(lids, g, g2)
+    blk, acc, _, _ = _specs(layout)
+    return _launch(
+        functools.partial(_r_partials_kernel, gsnr_eps=gsnr_eps), layout, g, lids,
+        [blk, blk], acc, jax.ShapeDtypeStruct((layout.leaf_slots, LANE), _f32),
+        interpret, g, g2,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -114,47 +109,37 @@ def leaf_r_partials(g, g2, lids, layout: ParamLayout, *, gsnr_eps, interpret: bo
 # ---------------------------------------------------------------------------
 
 
-def _scale_apply_kernel(lid_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref,
-                        sg_ref, r_ref, *, gamma, eps):
-    leaf = lid_ref[0, 0]
-    r_raw = _raw_r(g_ref, g2_ref, eps)
-    r = jnp.clip(r_raw * _inv_mean_r(racc_ref, invsz_ref, leaf), gamma, 1.0)
-    sg_ref[...] = r * ga_ref[...].astype(_f32)
-    r_ref[...] = r
+def _apply_kernel(lids_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref, *rest, body):
+    """One block of a flat_update phase-1 body, with the psum-combined r
+    accumulator as an ordinary operand where the fused kernel read scratch."""
+    body(lids_ref[pl.program_id(0)], racc_ref, invsz_ref, g_ref, ga_ref, g2_ref, *rest)
+
+
+def _trust_kernel(lids_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref, *rest, body):
+    """_apply_kernel for the LAMB/LARS computes: also zero the norm-partial
+    accumulators (the last two outputs) on the first block."""
+    uacc_ref, wacc_ref = rest[-2:]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        uacc_ref[...] = jnp.zeros_like(uacc_ref)
+        wacc_ref[...] = jnp.zeros_like(wacc_ref)
+
+    _apply_kernel(lids_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref, *rest, body=body)
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "gamma", "eps", "interpret"))
 def vr_scale_apply(g, ga, g2, racc, lids, invsz, layout: ParamLayout, *,
-                   gamma, eps, interpret: bool = True):
+                   gamma, eps, interpret: bool | None = None):
     """Shard-local (scaled_grad, r) given the combined r accumulator."""
-    blk, lid, acc, inv, _ = _specs(layout)
+    blk, acc, inv, _ = _specs(layout)
     sds = jax.ShapeDtypeStruct(g.shape, _f32)
-    return pl.pallas_call(
-        functools.partial(_scale_apply_kernel, gamma=gamma, eps=eps),
-        grid=(_local_blocks(g, layout),),
-        in_specs=[lid, inv, acc, blk, blk, blk],
-        out_specs=(blk, blk),
-        out_shape=(sds, sds),
-        interpret=interpret,
-    )(lids, invsz, racc, g, ga, g2)
-
-
-def _adam_apply_kernel(lid_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref,
-                       m_ref, v_ref, p_ref, w_ref, scal_ref,
-                       upd_ref, m_out, v_out, p_out,
-                       *, b1, b2, b3, eps, wd, gamma, gsnr_eps):
-    leaf = lid_ref[0, 0]
-    lr = scal_ref[0, 0]
-    direction, m_new, v_new, p_new = _adam_math(
-        _raw_r(g_ref, g2_ref, gsnr_eps),
-        _inv_mean_r(racc_ref, invsz_ref, leaf),
-        ga_ref, m_ref, v_ref, p_ref, scal_ref,
-        b1=b1, b2=b2, b3=b3, gamma=gamma, eps=eps,
+    return _launch(
+        functools.partial(_apply_kernel, body=functools.partial(
+            _scale_apply, gamma=gamma, eps=eps)),
+        layout, g, lids, [inv, acc, blk, blk, blk], (blk, blk), (sds, sds), interpret,
+        invsz, racc, g, ga, g2,
     )
-    upd_ref[...] = -lr * (direction + wd * w_ref[...].astype(_f32))
-    m_out[...] = m_new.astype(m_out.dtype)
-    v_out[...] = v_new.astype(v_out.dtype)
-    p_out[...] = p_new.astype(p_out.dtype)
 
 
 @functools.partial(
@@ -165,51 +150,19 @@ def _adam_apply_kernel(lid_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref,
 )
 def vr_adam_apply(g, ga, g2, m, v, p, w, scal, racc, lids, invsz,
                   layout: ParamLayout, *, b1, b2, b3, eps, wd, gamma, gsnr_eps,
-                  state_dtype="float32", interpret: bool = True):
+                  state_dtype="float32", interpret: bool | None = None):
     """Shard-local full VR-Adam apply given the combined r accumulator."""
-    blk, lid, acc, inv, scal_spec = _specs(layout)
-    sd = jnp.dtype(state_dtype)
+    blk, acc, inv, scal_spec = _specs(layout)
     f32_sds = jax.ShapeDtypeStruct(g.shape, _f32)
-    sd_sds = jax.ShapeDtypeStruct(g.shape, sd)
-    return pl.pallas_call(
-        functools.partial(
-            _adam_apply_kernel,
-            b1=b1, b2=b2, b3=b3, eps=eps, wd=wd, gamma=gamma, gsnr_eps=gsnr_eps,
-        ),
-        grid=(_local_blocks(g, layout),),
-        in_specs=[lid, inv, acc] + [blk] * 7 + [scal_spec],
-        out_specs=(blk,) * 4,
-        out_shape=(f32_sds, sd_sds, sd_sds, sd_sds),
-        interpret=interpret,
-    )(lids, invsz, racc, g, ga, g2, m, v, p, w, scal)
-
-
-def _lamb_compute_kernel(lid_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref,
-                         m_ref, v_ref, p_ref, w_ref, scal_ref,
-                         u_ref, m_out, v_out, p_out, uacc_ref, wacc_ref,
-                         *, b1, b2, b3, eps, wd, gamma, gsnr_eps):
-    b = pl.program_id(0)
-
-    @pl.when(b == 0)
-    def _init():
-        uacc_ref[...] = jnp.zeros_like(uacc_ref)
-        wacc_ref[...] = jnp.zeros_like(wacc_ref)
-
-    leaf = lid_ref[0, 0]
-    w = w_ref[...].astype(_f32)
-    direction, m_new, v_new, p_new = _adam_math(
-        _raw_r(g_ref, g2_ref, gsnr_eps),
-        _inv_mean_r(racc_ref, invsz_ref, leaf),
-        ga_ref, m_ref, v_ref, p_ref, scal_ref,
-        b1=b1, b2=b2, b3=b3, gamma=gamma, eps=eps,
+    sd_sds = jax.ShapeDtypeStruct(g.shape, jnp.dtype(state_dtype))
+    body = functools.partial(_adam_apply, b1=b1, b2=b2, b3=b3, eps=eps, wd=wd,
+                             gamma=gamma, gsnr_eps=gsnr_eps)
+    return _launch(
+        functools.partial(_apply_kernel, body=body), layout, g, lids,
+        [inv, acc] + [blk] * 7 + [scal_spec], (blk,) * 4,
+        (f32_sds, sd_sds, sd_sds, sd_sds), interpret,
+        invsz, racc, g, ga, g2, m, v, p, w, scal,
     )
-    u = direction + wd * w  # padded tail: g = ga = w = 0 -> u = 0 (exact norms)
-    u_ref[...] = u
-    m_out[...] = m_new.astype(m_out.dtype)
-    v_out[...] = v_new.astype(v_out.dtype)
-    p_out[...] = p_new.astype(p_out.dtype)
-    uacc_ref[pl.ds(leaf, 1), :] += jnp.sum(u * u, axis=0, keepdims=True)
-    wacc_ref[pl.ds(leaf, 1), :] += jnp.sum(w * w, axis=0, keepdims=True)
 
 
 @functools.partial(
@@ -220,73 +173,44 @@ def _lamb_compute_kernel(lid_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref,
 )
 def vr_lamb_compute(g, ga, g2, m, v, p, w, scal, racc, lids, invsz,
                     layout: ParamLayout, *, b1, b2, b3, eps, wd, gamma, gsnr_eps,
-                    state_dtype="float32", interpret: bool = True):
+                    state_dtype="float32", interpret: bool | None = None):
     """Shard-local VR-LAMB compute: (u, m', v', p', uacc, wacc) — the
     pre-trust-ratio update plus the shard's norm partials; the cross-shard
-    psum and the -lr * ratio * u epilogue live in backend.FlatSpmd."""
-    blk, lid, acc, inv, scal_spec = _specs(layout)
-    sd = jnp.dtype(state_dtype)
+    psum and the lamb_epilogue live in backend.FlatSpmd."""
+    blk, acc, inv, scal_spec = _specs(layout)
     f32_sds = jax.ShapeDtypeStruct(g.shape, _f32)
-    sd_sds = jax.ShapeDtypeStruct(g.shape, sd)
+    sd_sds = jax.ShapeDtypeStruct(g.shape, jnp.dtype(state_dtype))
     acc_sds = jax.ShapeDtypeStruct((layout.leaf_slots, LANE), _f32)
-    return pl.pallas_call(
-        functools.partial(
-            _lamb_compute_kernel,
-            b1=b1, b2=b2, b3=b3, eps=eps, wd=wd, gamma=gamma, gsnr_eps=gsnr_eps,
-        ),
-        grid=(_local_blocks(g, layout),),
-        in_specs=[lid, inv, acc] + [blk] * 7 + [scal_spec],
-        out_specs=(blk, blk, blk, blk, acc, acc),
-        out_shape=(f32_sds, sd_sds, sd_sds, sd_sds, acc_sds, acc_sds),
-        interpret=interpret,
-    )(lids, invsz, racc, g, ga, g2, m, v, p, w, scal)
-
-
-def _lars_compute_kernel(lid_ref, invsz_ref, racc_ref, g_ref, ga_ref, g2_ref,
-                         w_ref, scal_ref, u_ref, uacc_ref, wacc_ref, *, wd, eps):
-    b = pl.program_id(0)
-
-    @pl.when(b == 0)
-    def _init():
-        uacc_ref[...] = jnp.zeros_like(uacc_ref)
-        wacc_ref[...] = jnp.zeros_like(wacc_ref)
-
-    leaf = lid_ref[0, 0]
-    gamma = scal_ref[0, 1]
-    w = w_ref[...].astype(_f32)
-    r = jnp.clip(
-        _raw_r(g_ref, g2_ref, eps) * _inv_mean_r(racc_ref, invsz_ref, leaf),
-        gamma, 1.0,
+    body = functools.partial(_lamb_compute, b1=b1, b2=b2, b3=b3, eps=eps, wd=wd,
+                             gamma=gamma, gsnr_eps=gsnr_eps)
+    return _launch(
+        functools.partial(_trust_kernel, body=body), layout, g, lids,
+        [inv, acc] + [blk] * 7 + [scal_spec], (blk, blk, blk, blk, acc, acc),
+        (f32_sds, sd_sds, sd_sds, sd_sds, acc_sds, acc_sds), interpret,
+        invsz, racc, g, ga, g2, m, v, p, w, scal,
     )
-    u = r * ga_ref[...].astype(_f32) + wd * w
-    u_ref[...] = u
-    uacc_ref[pl.ds(leaf, 1), :] += jnp.sum(u * u, axis=0, keepdims=True)
-    wacc_ref[pl.ds(leaf, 1), :] += jnp.sum(w * w, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "wd", "eps", "interpret"))
 def vr_lars_compute(g, ga, g2, w, scal, racc, lids, invsz, layout: ParamLayout,
-                    *, wd, eps, interpret: bool = True):
+                    *, wd, eps, interpret: bool | None = None):
     """Shard-local VR-LARS compute: (u, uacc, wacc); the momentum fold and
-    trust-ratio epilogue live in backend.FlatSpmd."""
-    blk, lid, acc, inv, scal_spec = _specs(layout)
+    trust-ratio epilogue (lars_epilogue) live in backend.FlatSpmd."""
+    blk, acc, inv, scal_spec = _specs(layout)
     sds = jax.ShapeDtypeStruct(g.shape, _f32)
     acc_sds = jax.ShapeDtypeStruct((layout.leaf_slots, LANE), _f32)
-    return pl.pallas_call(
-        functools.partial(_lars_compute_kernel, wd=wd, eps=eps),
-        grid=(_local_blocks(g, layout),),
-        in_specs=[lid, inv, acc] + [blk] * 4 + [scal_spec],
-        out_specs=(blk, acc, acc),
-        out_shape=(sds, acc_sds, acc_sds),
-        interpret=interpret,
-    )(lids, invsz, racc, g, ga, g2, w, scal)
+    return _launch(
+        functools.partial(_trust_kernel, body=functools.partial(_lars_compute, wd=wd, eps=eps)),
+        layout, g, lids, [inv, acc] + [blk] * 4 + [scal_spec], (blk, acc, acc),
+        (sds, acc_sds, acc_sds), interpret,
+        invsz, racc, g, ga, g2, w, scal,
+    )
 
 
 # ---------------------------------------------------------------------------
 # contract registration (repro.analysis): single-phase per-shard launches —
-# the replay PROVES the accumulator outputs' constant index maps give
-# consecutive revisits (the safe accumulate-in-VMEM pattern), so none of
-# them needs an accumulate-through-window declaration
+# the replay PROVES the accumulator outputs' constant index maps give one
+# run per block (the safe accumulate-in-VMEM pattern)
 # ---------------------------------------------------------------------------
 
 
@@ -295,45 +219,44 @@ def _analysis_geometry(kname: str, *, layout_kind: str = "hostile",
     from repro.analysis.registry import Geometry, Operand, demo_layout
 
     layout = demo_layout(layout_kind)
-    blk, lid, acc, inv, scal = _specs(layout)
-    f32 = lambda spec: Operand(spec, dtype="float32")
-    sd = lambda spec: Operand(spec, dtype=state_dtype)
-    meta = {
-        "lid": Operand(lid, dtype="int32", role="meta"),
-        "inv": Operand(inv, dtype="float32", role="meta"),
-    }
+    blk, acc, inv, scal = _specs(layout)
+    rows, accs = (layout.n_rows, LANE), (layout.leaf_slots, LANE)
+    f32 = lambda spec: Operand(spec, rows)
+    sd = lambda spec: Operand(spec, rows, dtype=state_dtype)
+    a32 = Operand(acc, accs)
+    meta = {"inv": Operand(inv, (layout.leaf_slots, 1))}
     grid = (layout.n_blocks,)
+    extra = (layout.block_leaf_ids(),)
     if kname == "spmd_leaf_r_partials":
-        return Geometry(grid=grid,
-                        ins={"lid": meta["lid"], "g": f32(blk), "g2": f32(blk)},
-                        outs={"racc": f32(acc)})
-    racc = {"racc": f32(acc)}
+        return Geometry(grid=grid, ins={"g": f32(blk), "g2": f32(blk)},
+                        outs={"racc": a32}, extra=extra)
+    racc = {"racc": a32}
     if kname == "spmd_vr_scale_apply":
         return Geometry(grid=grid,
                         ins={**meta, **racc, "g": f32(blk), "ga": f32(blk),
                              "g2": f32(blk)},
-                        outs={"sg": f32(blk), "r": f32(blk)})
-    scal_op = {"scal": Operand(scal, dtype="float32", role="meta")}
+                        outs={"sg": f32(blk), "r": f32(blk)}, extra=extra)
+    scal_op = {"scal": Operand(scal, (1, 8))}
     if kname == "spmd_vr_adam_apply":
         return Geometry(grid=grid,
                         ins={**meta, **racc, "g": f32(blk), "ga": f32(blk),
                              "g2": f32(blk), "m": sd(blk), "v": sd(blk),
                              "p": sd(blk), "w": sd(blk), **scal_op},
                         outs={"upd": f32(blk), "m_out": sd(blk),
-                              "v_out": sd(blk), "p_out": sd(blk)})
+                              "v_out": sd(blk), "p_out": sd(blk)}, extra=extra)
     if kname == "spmd_vr_lamb_compute":
         return Geometry(grid=grid,
                         ins={**meta, **racc, "g": f32(blk), "ga": f32(blk),
                              "g2": f32(blk), "m": sd(blk), "v": sd(blk),
                              "p": sd(blk), "w": sd(blk), **scal_op},
                         outs={"u": f32(blk), "m_out": sd(blk), "v_out": sd(blk),
-                              "p_out": sd(blk), "uacc": f32(acc),
-                              "wacc": f32(acc)})
+                              "p_out": sd(blk), "uacc": a32, "wacc": a32},
+                        extra=extra)
     # spmd_vr_lars_compute
     return Geometry(grid=grid,
                     ins={**meta, **racc, "g": f32(blk), "ga": f32(blk),
                          "g2": f32(blk), "w": sd(blk), **scal_op},
-                    outs={"u": f32(blk), "uacc": f32(acc), "wacc": f32(acc)})
+                    outs={"u": f32(blk), "uacc": a32, "wacc": a32}, extra=extra)
 
 
 def _register():

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.backend import resolve_interpret
 from repro.core.layout import LANE, ParamLayout
 from repro.kernels.grad_stats import _accum_kernel, _finalize_kernel
 
@@ -60,7 +61,7 @@ def _local_blocks(x, layout: ParamLayout) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "interpret"))
-def flat_moments_accum(gs, g2s, g, layout: ParamLayout, interpret: bool = True):
+def flat_moments_accum(gs, g2s, g, layout: ParamLayout, interpret: bool | None = None):
     """One scan-body update of both flat moment carries: a single launch."""
     blk = _blk(layout)
     sds = jax.ShapeDtypeStruct(gs.shape, jnp.float32)
@@ -70,7 +71,8 @@ def flat_moments_accum(gs, g2s, g, layout: ParamLayout, interpret: bool = True):
         in_specs=[blk, blk, blk],
         out_specs=(blk, blk),
         out_shape=(sds, sds),
-        interpret=interpret,
+        input_output_aliases={0: 0, 1: 1},  # the carries update in place
+        interpret=resolve_interpret(interpret),
     )(gs, g2s, g)
 
 
@@ -79,7 +81,7 @@ def _g_accum_kernel(gs_ref, g_ref, gs_out):
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "interpret"))
-def flat_g_accum(gs, g, layout: ParamLayout, interpret: bool = True):
+def flat_g_accum(gs, g, layout: ParamLayout, interpret: bool | None = None):
     """One scan-body update of the g-only flat carry (stale-GSNR steps):
     a single launch, no Σg² stream."""
     blk = _blk(layout)
@@ -90,12 +92,13 @@ def flat_g_accum(gs, g, layout: ParamLayout, interpret: bool = True):
         in_specs=[blk, blk],
         out_specs=blk,
         out_shape=sds,
-        interpret=interpret,
+        input_output_aliases={0: 0},
+        interpret=resolve_interpret(interpret),
     )(gs, g)
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "interpret"))
-def flat_moments_finalize(gs, g2s, k, layout: ParamLayout, interpret: bool = True):
+def flat_moments_finalize(gs, g2s, k, layout: ParamLayout, interpret: bool | None = None):
     """Terminal /k normalize of the flat carries: a single launch.
 
     k may be traced.  Returns flat (mean, sq_mean) f32 buffers.
@@ -109,7 +112,8 @@ def flat_moments_finalize(gs, g2s, k, layout: ParamLayout, interpret: bool = Tru
         in_specs=[blk, blk, pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=(blk, blk),
         out_shape=(sds, sds),
-        interpret=interpret,
+        input_output_aliases={0: 0, 1: 1},  # the sums become the moments
+        interpret=resolve_interpret(interpret),
     )(gs, g2s, inv)
 
 
@@ -120,7 +124,7 @@ def _pack_square_kernel(g_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "interpret"))
-def flat_pack_square(gf, layout: ParamLayout, interpret: bool = True):
+def flat_pack_square(gf, layout: ParamLayout, interpret: bool | None = None):
     """(rows, LANE) flat gradient -> (2, rows, LANE) [g; g²] payload: one
     launch, ONE read of gf per block.
 
@@ -137,7 +141,7 @@ def flat_pack_square(gf, layout: ParamLayout, interpret: bool = True):
         in_specs=[blk],
         out_specs=pl.BlockSpec((2, layout.block_rows, LANE), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((2,) + tuple(gf.shape), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(gf)
 
 
@@ -160,7 +164,7 @@ def _vmap_kernel(g_ref, mean_ref, sq_ref, *, nk: int, inv: float):
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "k", "interpret"))
-def flat_vmap_moments(gstack, layout: ParamLayout, k: int, interpret: bool = True):
+def flat_vmap_moments(gstack, layout: ParamLayout, k: int, interpret: bool | None = None):
     """(k, n_rows, LANE) gradient stack -> flat (mean, sq_mean): one launch.
 
     The k axis is the minor grid dimension, so each output block stays
@@ -176,7 +180,7 @@ def flat_vmap_moments(gstack, layout: ParamLayout, k: int, interpret: bool = Tru
         in_specs=[pl.BlockSpec((1, br, LANE), lambda b, j: (j, b, 0))],
         out_specs=(out_blk, out_blk),
         out_shape=(sds, sds),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(gstack)
 
 
@@ -190,8 +194,9 @@ def _analysis_geometry(kname: str, *, layout_kind: str = "hostile", k: int = 4):
 
     layout = demo_layout(layout_kind)
     blk = _blk(layout)
-    f32 = lambda spec: Operand(spec, dtype="float32")
-    inv = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), role="meta")
+    rows = (layout.n_rows, LANE)
+    f32 = lambda spec: Operand(spec, rows)
+    inv = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), (1, 1))
     if kname == "flat_moments_accum":
         return Geometry(grid=(layout.n_blocks,),
                         ins={"gs": f32(blk), "g2s": f32(blk), "g": f32(blk)},
@@ -207,14 +212,15 @@ def _analysis_geometry(kname: str, *, layout_kind: str = "hostile", k: int = 4):
     if kname == "flat_pack_square":
         out = pl.BlockSpec((2, layout.block_rows, LANE), lambda i: (0, i, 0))
         return Geometry(grid=(layout.n_blocks,),
-                        ins={"gf": f32(blk)}, outs={"payload": f32(out)})
-    # flat_vmap_moments: k-minor grid keeps output revisits consecutive —
-    # the registry replay PROVES that, no accumulate declaration needed
+                        ins={"gf": f32(blk)},
+                        outs={"payload": Operand(out, (2,) + rows)})
+    # flat_vmap_moments: k-minor grid keeps each output block in one run —
+    # the registry replay PROVES that
     br = layout.block_rows
     out_blk = pl.BlockSpec((br, LANE), lambda b, j: (b, 0))
+    stack = pl.BlockSpec((1, br, LANE), lambda b, j: (j, b, 0))
     return Geometry(grid=(layout.n_blocks, k),
-                    ins={"gstack": f32(pl.BlockSpec((1, br, LANE),
-                                                    lambda b, j: (j, b, 0)))},
+                    ins={"gstack": Operand(stack, (k,) + rows)},
                     outs={"mean": f32(out_blk), "sq": f32(out_blk)})
 
 

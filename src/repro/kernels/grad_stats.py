@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.backend import resolve_interpret
 from repro.kernels.vr_update import BLOCK_ROWS, LANE, _pad2d, padded_rows
 
 
@@ -55,7 +56,7 @@ def moments_init(leaf: jnp.ndarray) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def moments_accum(gs2d, g2s2d, g, interpret: bool = True):
+def moments_accum(gs2d, g2s2d, g, interpret: bool | None = None):
     """One fused scan-body update: (g_sum+g, g2_sum+g²) on one leaf.
 
     gs2d/g2s2d are padded (rows x 128) carries; g is the raw param-shaped
@@ -71,12 +72,12 @@ def moments_accum(gs2d, g2s2d, g, interpret: bool = True):
         in_specs=[blk, blk, blk],
         out_specs=(blk, blk),
         out_shape=(sds, sds),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(gs2d, g2s2d, g2d)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "interpret"))
-def moments_finalize(gs2d, g2s2d, k, shape, interpret: bool = True):
+def moments_finalize(gs2d, g2s2d, k, shape, interpret: bool | None = None):
     """Terminal /k normalize fused in one pass; unpads to ``shape``.
 
     k may be a traced scalar (int or float).  Returns (mean, sq_mean) f32.
@@ -90,7 +91,7 @@ def moments_finalize(gs2d, g2s2d, k, shape, interpret: bool = True):
         in_specs=[blk, blk, pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=(blk, blk),
         out_shape=(sds, sds),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(gs2d, g2s2d, inv)
     n = 1
     for d in shape:
@@ -107,14 +108,15 @@ def moments_finalize(gs2d, g2s2d, k, shape, interpret: bool = True):
 def _analysis_geometry(kname: str, *, n: int = 300, g_dtype: str = "float32"):
     from repro.analysis.registry import Geometry, Operand
 
-    grid, blk = _grid_blk(padded_rows(n))
-    f32 = lambda spec: Operand(spec, dtype="float32")
+    rows = (padded_rows(n), LANE)
+    grid, blk = _grid_blk(rows[0])
+    f32 = lambda spec: Operand(spec, rows)
     if kname == "grad_stats_accum":
         return Geometry(grid=grid,
                         ins={"gs": f32(blk), "g2s": f32(blk),
-                             "g": Operand(blk, dtype=g_dtype)},
+                             "g": Operand(blk, rows, dtype=g_dtype)},
                         outs={"gs_out": f32(blk), "g2s_out": f32(blk)})
-    inv = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), role="meta")
+    inv = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), (1, 1))
     return Geometry(grid=grid,
                     ins={"gs": f32(blk), "g2s": f32(blk), "inv": inv},
                     outs={"mean": f32(blk), "sq": f32(blk)})

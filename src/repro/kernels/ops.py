@@ -234,7 +234,7 @@ def lamb_trust_flat(d: FlatBuffer, params, lr, wd):
     """Stale-GSNR LAMB epilogue on the flat buffer (no kernel launch): the
     per-leaf trust ratio via a row-wise segment reduction, fully XLA-fused.
 
-    Fresh steps take the 3-phase kernel; stale steps have no Σg² pass to
+    Fresh steps take the 2-phase kernel; stale steps have no Σg² pass to
     fold in, so plain jnp over ONE flat array is already a single sweep.
     """
     from repro.core.baselines import _lamb_phi

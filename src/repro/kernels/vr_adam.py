@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.backend import resolve_interpret
 from repro.kernels.vr_update import LANE, BLOCK_ROWS, _pad2d, padded_rows
 
 
@@ -55,7 +56,7 @@ def _kernel(
 )
 def vr_adam_inner(
     g, g2, m, v, p, bc1, bc2, bc3,
-    *, b1, b2, b3, eps, gamma, gsnr_eps, interpret: bool = True, g_apply=None,
+    *, b1, b2, b3, eps, gamma, gsnr_eps, interpret: bool | None = None, g_apply=None,
 ):
     """Fused inner step on one tensor; matches ref.vr_adam_inner_ref.
 
@@ -86,7 +87,7 @@ def vr_adam_inner(
         in_specs=[blk] * 6 + [pl.BlockSpec((1, 4), lambda i: (0, 0))],
         out_specs=(blk,) * 4,
         out_shape=(sds,) * 4,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*tens, scal)
     unpad = lambda x: x.reshape(-1)[:n].reshape(shape)
     return tuple(unpad(o) for o in outs)
@@ -103,8 +104,8 @@ def _analysis_geometry(*, n: int = 65536):
     rows = padded_rows(n)
     br = min(BLOCK_ROWS, rows)
     blk = pl.BlockSpec((br, LANE), lambda i: (i, 0))
-    f32 = lambda spec: Operand(spec, dtype="float32")
-    scal = Operand(pl.BlockSpec((1, 4), lambda i: (0, 0)), role="meta")
+    f32 = lambda spec: Operand(spec, (rows, LANE))
+    scal = Operand(pl.BlockSpec((1, 4), lambda i: (0, 0)), (1, 4))
     return Geometry(
         grid=(-(-rows // br),),
         ins={"g": f32(blk), "ga": f32(blk), "g2": f32(blk), "m": f32(blk),

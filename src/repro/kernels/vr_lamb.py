@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.backend import resolve_interpret
 from repro.kernels.vr_update import BLOCK_ROWS, LANE, _pad2d, padded_rows
 
 
@@ -100,7 +101,7 @@ def _lamb_kernel(
 )
 def vr_lamb_inner(
     g, ga, g2, m, v, p, w, bc1, bc2, bc3,
-    *, b1, b2, b3, eps, wd, gamma, gsnr_eps, interpret: bool = True,
+    *, b1, b2, b3, eps, wd, gamma, gsnr_eps, interpret: bool | None = None,
 ):
     """Fused VR-LAMB step on one tensor; matches ref.vr_lamb_inner_ref.
 
@@ -136,7 +137,7 @@ def vr_lamb_inner(
         in_specs=[blk] * 7 + [pl.BlockSpec((1, 4), lambda i: (0, 0))],
         out_specs=(blk,) * 4 + (acc_blk, acc_blk),
         out_shape=(sds,) * 4 + (acc_sds, acc_sds),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*tens, scal)
     unpad = lambda x: x.reshape(-1)[:n].reshape(shape)
     return (
@@ -172,7 +173,7 @@ def _lars_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("wd", "gamma", "eps", "interpret"))
-def vr_lars_inner(g, ga, g2, w, *, wd, gamma, eps, interpret: bool = True):
+def vr_lars_inner(g, ga, g2, w, *, wd, gamma, eps, interpret: bool | None = None):
     """Fused VR-LARS scale on one tensor; matches ref.vr_lars_inner_ref.
 
     Returns (u, sum(u²), sum(w²)) with u = r*ga + wd*w; the caller computes
@@ -201,7 +202,7 @@ def vr_lars_inner(g, ga, g2, w, *, wd, gamma, eps, interpret: bool = True):
         in_specs=[blk] * 4 + [pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=(blk, acc_blk, acc_blk),
         out_shape=(sds, acc_sds, acc_sds),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*tens, inv_mean)
     u = u2d.reshape(-1)[:n].reshape(shape)
     return u, jnp.sum(uacc), jnp.sum(wacc)
@@ -219,12 +220,12 @@ def _analysis_geometry(kname: str, *, n: int = 65536):
     br = min(BLOCK_ROWS, rows)
     grid = (-(-rows // br),)  # _pad_full_blocks: the reduce grid has no edge block
     blk = pl.BlockSpec((br, LANE), lambda i: (i, 0))
-    f32 = lambda spec: Operand(spec, dtype="float32")
+    f32 = lambda spec: Operand(spec, (grid[0] * br, LANE))
     # (1, LANE) norm-partial accumulators: constant index every step, so the
     # registry replay proves the revisits are one consecutive run (no race)
-    acc = Operand(pl.BlockSpec((1, LANE), lambda i: (0, 0)), role="meta")
+    acc = Operand(pl.BlockSpec((1, LANE), lambda i: (0, 0)), (1, LANE))
     if kname == "vr_lamb_inner":
-        scal = Operand(pl.BlockSpec((1, 4), lambda i: (0, 0)), role="meta")
+        scal = Operand(pl.BlockSpec((1, 4), lambda i: (0, 0)), (1, 4))
         return Geometry(
             grid=grid,
             ins={"g": f32(blk), "ga": f32(blk), "g2": f32(blk), "m": f32(blk),
@@ -232,7 +233,7 @@ def _analysis_geometry(kname: str, *, n: int = 65536):
             outs={"u": f32(blk), "m_out": f32(blk), "v_out": f32(blk),
                   "p_out": f32(blk), "uacc": acc, "wacc": acc},
         )
-    scal = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), role="meta")
+    scal = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), (1, 1))
     return Geometry(
         grid=grid,
         ins={"g": f32(blk), "ga": f32(blk), "g2": f32(blk), "w": f32(blk),

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.analysis.layout_contracts import LANE, sublane
+from repro.backend import resolve_interpret
 
 BLOCK_ROWS = 256  # (256, 128) f32 = 128 KiB per ref; ~0.5 MiB working set
 
@@ -54,7 +55,7 @@ def _pad2d(x: jnp.ndarray):
 @functools.partial(jax.jit, static_argnames=("gamma", "eps", "interpret"))
 def vr_scale(
     g: jnp.ndarray, g2: jnp.ndarray, gamma: float, eps: float,
-    interpret: bool = True, g_apply: jnp.ndarray = None,
+    interpret: bool | None = None, g_apply: jnp.ndarray = None,
 ):
     """Fused (scaled_grad, r) for one tensor; matches ref.vr_scale_ref.
 
@@ -97,7 +98,7 @@ def vr_scale(
             pl.BlockSpec((br, LANE), lambda i: (i, 0)),
         ),
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g2d, ga2d, g22d, inv_mean)
     sg = sg2d.reshape(-1)[:n].reshape(orig_shape)
     r = r2d.reshape(-1)[:n].reshape(orig_shape)
@@ -115,8 +116,8 @@ def _analysis_geometry(*, n: int = 65536):
     rows = padded_rows(n)
     br = min(BLOCK_ROWS, rows)
     blk = pl.BlockSpec((br, LANE), lambda i: (i, 0))
-    f32 = lambda spec: Operand(spec, dtype="float32")
-    scal = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), role="meta")
+    f32 = lambda spec: Operand(spec, (rows, LANE))
+    scal = Operand(pl.BlockSpec((1, 1), lambda i: (0, 0)), (1, 1))
     return Geometry(
         grid=(-(-rows // br),),
         ins={"g": f32(blk), "ga": f32(blk), "g2": f32(blk), "scal": scal},

@@ -1,12 +1,15 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production mesh, with memory/cost/collective analysis.
 
-The two lines above MUST precede every other import (jax locks the device
-count at first init); do not set that flag globally — smoke tests and
-benchmarks must see one device.
+The lines above MUST precede every other import (jax locks the platform and
+the device count at first init).  The dry-run models 512 fake CPU devices,
+so it pins itself to the CPU: it never takes a TPU another process may
+hold.  Do not set the device-count flag globally — smoke tests and
+benchmarks must see one device.  Exits non-zero when any combination fails.
 
 Usage:
   python -m repro.launch.dryrun --arch mixtral-8x22b --shape train_4k
@@ -155,10 +158,10 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str, save_hlo:
         if overrides:
             cfg = overrides(cfg)
         if mesh_shape is not None:
-            from repro.launch.mesh import _make_mesh
+            from repro.launch.mesh import make_mesh
 
             axes = ("pod", "data", "model")[-len(mesh_shape):]
-            mesh = _make_mesh(mesh_shape, axes)
+            mesh = make_mesh(mesh_shape, axes)
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         with activate(mesh, **(rules_kw or {})) as rules:
@@ -225,6 +228,7 @@ def main() -> None:
             ap.error("--arch and --shape required unless --all")
         combos = [(args.arch, args.shape)]
 
+    failed = []
     for arch, shape in combos:
         rec = run_one(arch, shape, args.multi_pod, args.out_dir, args.save_hlo)
         mesh_name = rec["mesh"]
@@ -243,7 +247,10 @@ def main() -> None:
             )
         else:
             status = f"FAIL {rec['error']}"
+            failed.append(f"{arch}/{shape}")
         print(f"[{mesh_name}] {arch:28s} {shape:12s} {status}", flush=True)
+    if failed:
+        raise SystemExit(f"dry-run failed for {len(failed)} combination(s): {failed}")
 
 
 if __name__ == "__main__":

@@ -7,31 +7,22 @@ XLA_FLAGS before any jax import and only then builds meshes.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def compat_make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh across API generations: >= 0.5 takes axis_types (Auto by
-    default there too); 0.4.x does not.  Probe the signature rather than
-    catching TypeError so a genuine argument error is never swallowed."""
-    import inspect
-
-    if "axis_types" in inspect.signature(jax.make_mesh).parameters:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
-
-
-_make_mesh = compat_make_mesh  # internal alias
+def make_mesh(shape, axes) -> Mesh:
+    """jax.make_mesh with Auto axes (the sharding rules annotate with
+    PartitionSpecs and let XLA propagate; they do not use explicit-axis
+    sharding types)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over however many (fake) devices the test process has."""
-    return _make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

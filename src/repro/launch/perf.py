@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # models 512 fake CPU devices; never takes a TPU
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 """§Perf hillclimbing runner: named variants over the dry-run pipeline.

@@ -1,7 +1,9 @@
 """Training launcher.
 
-CPU-scale driver over the synthetic pipeline; on a real TPU mesh the same
-entry point shards params/batches per sharding/rules.py.
+Drives ``train_loop`` over the synthetic pipeline on one device: the CPU
+(Pallas kernels in interpret mode) or one TPU chip.  It builds no mesh; the
+data-parallel path over several chips goes through
+``make_train_step(cfg, mesh=...)`` (see ``chip_smoke.py --chips 4``).
 
   python -m repro.launch.train --arch granite-3-2b --smoke --steps 20
   python -m repro.launch.train --arch bert-large --optimizer vr_lamb \
@@ -15,6 +17,7 @@ import json
 
 from repro.configs import ARCH_MODULES, get_config, get_smoke
 from repro.data import lm_batches
+from repro.launch.cache import enable_compile_cache
 from repro.train import train_loop
 
 
@@ -32,6 +35,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.batch:

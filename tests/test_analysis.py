@@ -80,7 +80,7 @@ def _geom(**kw):
 
 def test_mutation_rank1_tile_is_caught():
     # a (128,) iota-shaped block: Mosaic tiling needs >= 2 dims
-    g = _geom(ins={"x": Operand(pl.BlockSpec((128,), lambda i: (i,)))})
+    g = _geom(ins={"x": Operand(pl.BlockSpec((128,), lambda i: (i,)), (512,))})
     assert "LAYOUT-RANK" in _rules_of(rules.check_geometry("mut", "rank1", g))
 
 
@@ -88,37 +88,74 @@ def test_mutation_half_height_bf16_tile_is_caught():
     # an 8-row tile is a full f32 tile but HALF a bf16 tile — the dtype-
     # derived sublane rule must fire where a hard-coded 8 would pass it
     spec = pl.BlockSpec((8, 128), lambda i: (i, 0))
-    ok = _geom(ins={"x": Operand(spec, dtype="float32")})
-    bad = _geom(ins={"x": Operand(spec, dtype="bfloat16")})
+    ok = _geom(ins={"x": Operand(spec, (64, 128), dtype="float32")})
+    bad = _geom(ins={"x": Operand(spec, (64, 128), dtype="bfloat16")})
     assert rules.check_geometry("mut", "f32", ok) == []
     assert "LAYOUT-SUBLANE" in _rules_of(rules.check_geometry("mut", "bf16", bad))
+    # a block dim equal to the array's own dim is legal at any size
+    whole = _geom(ins={"x": Operand(spec, (8, 128), dtype="bfloat16")})
+    assert rules.check_geometry("mut", "whole", whole) == []
+
+
+# the pre-fix operand specs the TPU compiler refused: each must now fire
+PRE_FIX_SPECS = {
+    # attention pos/seg row: (1, block) over (B, S) — sublane dim 1 != B
+    "row": (pl.BlockSpec((1, 128), lambda i: (i, 0)), (8, 512), "int32",
+            "LAYOUT-SUBLANE"),
+    # LSE residual: (1, 1, block_q) over (B, H, S) — sublane dim 1 != H
+    "lse": (pl.BlockSpec((1, 1, 128), lambda i: (0, i, 0)), (8, 16, 512), "float32",
+            "LAYOUT-SUBLANE"),
+    # q tile: (1, block_q, 1, D) over (B, S, H, D) — sublane dim 1 != H
+    "bshd_tile": (pl.BlockSpec((1, 128, 1, 64), lambda i: (0, 0, i, 0)),
+                  (8, 128, 16, 64), "bfloat16", "LAYOUT-SUBLANE"),
+    # flat-update leaf id: (1, 1) over (n_blocks + 1, 1) — sublane dim 1
+    "leaf_id": (pl.BlockSpec((1, 1), lambda i: (i, 0)), (41, 1), "int32",
+                "LAYOUT-SUBLANE"),
+    # a head-dim-wide slice of a (S, H*D) array: minor dim 64 != 1024
+    "lane": (pl.BlockSpec((128, 64), lambda i: (0, i)), (128, 1024), "float32",
+             "LAYOUT-LANE"),
+}
+
+
+@pytest.mark.parametrize("case", list(PRE_FIX_SPECS))
+def test_mutation_pre_fix_block_shapes_are_caught(case):
+    spec, shape, dtype, rule = PRE_FIX_SPECS[case]
+    g = _geom(ins={"x": Operand(spec, shape, dtype=dtype)})
+    assert _rules_of(rules.check_geometry("mut", case, g)) == {rule}
 
 
 def test_mutation_write_to_parked_block_is_caught():
     # output declared live only in phase 1 of a (2, 4) grid, but its index
-    # map keeps walking blocks while parked -> the parked window is written
+    # map keeps walking blocks while parked -> phase 0 copies back blocks the
+    # kernel never wrote
     layout = demo_layout("aligned")
     live_everywhere = pl.BlockSpec((layout.block_rows, 128), lambda ph, b: (b, 0))
     g = _geom(grid=(2, layout.n_blocks), phase_axis=0,
-              outs={"o": Operand(live_everywhere, window=(1, 1))})
+              outs={"o": Operand(live_everywhere, (layout.n_rows, 128), window=(1, 1))})
     assert "REVISIT-WRITE" in _rules_of(rules.check_geometry("mut", "parked", g))
+    # parking on the window's first block is the safe form
+    parked = pl.BlockSpec((layout.block_rows, 128), lambda ph, b: (b * ph, 0))
+    ok = _geom(grid=(2, layout.n_blocks), phase_axis=0,
+               outs={"o": Operand(parked, (layout.n_rows, 128), window=(1, 1))})
+    assert rules.check_geometry("mut", "parked_ok", ok) == []
 
 
 def test_mutation_parked_input_drift_is_caught():
     layout = demo_layout("aligned")
     live_everywhere = pl.BlockSpec((layout.block_rows, 128), lambda ph, b: (b, 0))
     g = _geom(grid=(2, layout.n_blocks), phase_axis=0,
-              ins={"x": Operand(live_everywhere, window=(1, 1))})
+              ins={"x": Operand(live_everywhere, (layout.n_rows, 128), window=(1, 1))})
     assert "REVISIT-PARK" in _rules_of(rules.check_geometry("mut", "drift", g))
 
 
 def test_mutation_undeclared_output_revisit_is_caught():
-    # the REAL fused-backward geometry with dq's accumulate-through-window
-    # declaration stripped: its q block recurs for every kv step
+    # the REAL fused-backward geometry with dq's parking stripped: written
+    # through the plain q-block map on every kv step, its block recurs once
+    # per kv block
     ks = all_kernels()["flash_attention_bwd"]
     geom = ks.build(**ks.configs["representative"])
     outs = dict(geom.outs)
-    outs["dq"] = dataclasses.replace(outs["dq"], accumulate=False)
+    outs["dq"] = dataclasses.replace(outs["dq"], spec=geom.ins["q"].spec, window=None)
     mutated = dataclasses.replace(geom, outs=outs)
     found = rules.check_geometry("flash_attention_bwd", "mut", mutated)
     assert _rules_of(found) == {"REVISIT-RACE"}
@@ -159,6 +196,40 @@ def test_mutation_vmem_overflow_is_caught():
     found = rules.check_geometry("flash_attention_fwd", "mut", geom,
                                  budget=64 * 1024)
     assert _rules_of(found) == {"VMEM-BUDGET"}
+
+
+def test_mutation_one_pass_backward_at_granite_20b_overflows_vmem():
+    # forced onto the one-pass path at granite-20b widths, seq 4096, the dq
+    # scratch alone is 96 MiB: the path flash_attention_bwd must not take
+    ks = all_kernels()["flash_attention_bwd"]
+    geom = ks.build(**ks.configs["granite_20b_seq4096"], with_dq=True)
+    found = rules.check_geometry("flash_attention_bwd", "mut", geom)
+    assert _rules_of(found) == {"VMEM-BUDGET"}
+
+
+@pytest.mark.parametrize("config", ["representative", "hostile_gqa_bf16",
+                                    "granite_3_2b_seq4096", "granite_20b_seq4096"])
+def test_backward_dispatch_follows_the_vmem_rule(config):
+    # the dispatcher's working-set estimate is the VMEM-BUDGET rule's count
+    # of the one-pass geometry, and it splits exactly where that count
+    # passes half the budget
+    from repro.analysis.layout_contracts import (DOUBLE_BUFFER, VMEM_BUDGET_BYTES,
+                                                 itemsize)
+    from repro.analysis.replay import _blk_bytes
+    from repro.kernels import flash_attention_bwd as fab
+
+    cfg = fab.CONFIGS[config]
+    geom = all_kernels()["flash_attention_bwd"].build(**cfg, with_dq=True)
+    counted = DOUBLE_BUFFER * sum(_blk_bytes(op.spec, itemsize(op.dtype))
+                                  for _, op, _ in geom.operands()) + geom.scratch_bytes
+    s, d, dtype = cfg["S"], cfg["D"], cfg.get("dtype", "float32")
+    bq = min(128, s)
+    estimate = fab.fused_vmem_bytes(cfg["H"] // cfg["KV"], -(-s // bq), bq, bq, d,
+                                    itemsize(dtype))
+    assert estimate == counted
+    fused = fab.use_fused_dq(cfg["H"] // cfg["KV"], -(-s // bq), bq, bq, d, itemsize(dtype))
+    assert fused == (counted <= VMEM_BUDGET_BYTES["tpu"] // 2)
+    assert fused == (config != "granite_20b_seq4096")
 
 
 def test_mutation_missing_oracle_is_caught():

@@ -288,10 +288,10 @@ def test_spmd_plan_falls_back_on_single_device():
     """Backend.shard on a 1-device mesh reports supports() False for any
     layout — the gathered single-launch path keeps serving."""
     from repro.core.layout import ParamLayout
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.sharding.rules import Rules
 
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     plan = Backend.all_fused().shard(mesh, Rules(mesh=mesh))
     layout = ParamLayout.for_tree(oracle.hostile_params())
     assert plan.supports(layout) is False

@@ -14,8 +14,8 @@ SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import grad_stats, device_grad_stats_fn
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 key = jax.random.PRNGKey(0)
 X = jax.random.normal(key, (64, 10))
 W = jnp.arange(1.0, 11.0)
@@ -73,10 +73,10 @@ FLAT_SHARD_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.layout import FlatBuffer, is_flat
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.sharding import activate, param_shardings
 
-mesh = compat_make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 import oracle
 params = oracle.hostile_params()
 from repro.configs.base import OptimizerConfig

@@ -21,8 +21,8 @@ from repro.launch.dryrun import build_lowered
 from repro.launch.hlo_analysis import analyze
 from repro.sharding import activate
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cases = [
     ("granite-3-2b", InputShape("t", 64, 8, "train")),
     ("mixtral-8x22b", InputShape("p", 128, 4, "prefill")),

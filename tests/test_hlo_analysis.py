@@ -52,10 +52,7 @@ def test_unrolled_matches_raw_cost_analysis():
     ws = jnp.zeros((4, 48, 48))
     compiled = jax.jit(unrolled).lower(x, ws).compile()
     a = analyze(compiled.as_text())
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # jax <= 0.4.x returns one dict per partition
-        ca = ca[0]
-    raw = ca.get("flops", 0)
+    raw = compiled.cost_analysis().get("flops", 0)
     assert a["flops"] == pytest.approx(raw, rel=0.05)
 
 

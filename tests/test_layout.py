@@ -70,13 +70,13 @@ def test_block_leaf_map_consistent():
     tree = oracle.hostile_params()
     layout = ParamLayout.for_tree(tree)
     ids = layout.block_leaf_ids()
-    assert ids.shape == (layout.n_blocks, 1)
+    assert ids.shape == (layout.n_blocks,)
     # every leaf owns a whole number of blocks, in offset order
-    counts = np.bincount(ids[:, 0], minlength=layout.n_leaves)
+    counts = np.bincount(ids, minlength=layout.n_leaves)
     np.testing.assert_array_equal(
         counts, np.asarray(layout.leaf_rows) // layout.block_rows
     )
-    assert (np.diff(ids[:, 0]) >= 0).all()
+    assert (np.diff(ids) >= 0).all()
 
 
 def test_structure_mismatch_raises_loudly():

@@ -137,6 +137,51 @@ def test_flash_attention_grad_oracle(case, dtype):
         oracle.assert_trees_close(a, b, msg=f"attn {name} {case}", **tol)
 
 
+# the split backward (dk/dv kernel + q-outer dq kernel), which
+# flash_attention_bwd takes where the one-pass dq scratch would not fit VMEM
+SPLIT_BWD_CASES = {
+    **{str(c): (c, None) for c in oracle.ATTN_GRAD_CASES},
+    **{n: (oracle.PACKED_ATTN_CASES[n], n) for n in oracle.PACKED_SMOKE},
+}
+
+
+@pytest.mark.parametrize("name", list(SPLIT_BWD_CASES))
+def test_split_dq_backward_matches_one_pass_and_oracle(name):
+    """Forced onto the split path, the backward gives the one-pass kernel's
+    dk/dv bit for bit and its dq to float rounding, and both match the jnp
+    replica attention_bwd_ref."""
+    from repro.kernels import flash_attention_bwd as fab
+    from repro.kernels import ref
+    from repro.kernels.flash_attention import bhsd, resolve_positions
+
+    case, packed = SPLIT_BWD_CASES[name]
+    if packed is None:
+        b, s, _, _, _, causal, window = case
+        q, k, v, t = oracle.attention_inputs(case, seed=sum(case[:5]))
+        pos = None
+    else:
+        b, s, window, causal = case[0], case[1], case[5], True
+        q, k, v, pos, t = oracle.packed_case_inputs(case, seed=sum(case[:5]))
+    qp, kp, qs, ks = (jnp.broadcast_to(x, (b, s))
+                      for x in resolve_positions(pos, pos, s, s))
+    out, lse = ref.attention_fwd_ref(q, k, v, causal=causal, window=window,
+                                     q_pos=qp, k_pos=kp, q_seg=qs, k_seg=ks)
+    do = bhsd(t)
+    delta = jnp.sum(do * bhsd(out), axis=-1, keepdims=True)
+    args = (bhsd(q), bhsd(k), bhsd(v), lse[..., None], delta, do)
+    kw = dict(causal=causal, window=window, block_q=min(128, s), block_k=min(128, s),
+              interpret=True, implicit=pos is None)
+    one = fab._one_pass_bwd(*args, qp, kp, qs, ks, **kw)
+    two = fab._split_bwd(*args, qp, kp, qs, ks, **kw)
+    want = fab.attention_bwd_ref(*args, causal=causal, window=window,
+                                 q_pos=qp, k_pos=kp, q_seg=qs, k_seg=ks)
+    np.testing.assert_array_equal(np.asarray(two[1]), np.asarray(one[1]))
+    np.testing.assert_array_equal(np.asarray(two[2]), np.asarray(one[2]))
+    oracle.assert_trees_close(two[0], one[0], msg=f"split dq {name}", atol=1e-6, rtol=1e-6)
+    for gname, a, w in zip(("dq", "dk", "dv"), two, want):
+        oracle.assert_trees_close(a, w, msg=f"split {gname} {name}", atol=2e-3, rtol=2e-3)
+
+
 def test_flash_attention_fully_masked_rows_are_zero():
     """A query row with NO valid kv position (here: q past the end of a short
     kv sequence under window=1, hitting the partial first kv block) must give
@@ -362,7 +407,7 @@ def test_bwd_rejects_implicit_sq_neq_skv():
     shapes first-class and must match the oracle."""
     from repro.kernels import flash_attention_bwd as fab
     from repro.kernels import ref
-    from repro.kernels.flash_attention import flash_attention
+    from repro.kernels.flash_attention import bhsd, flash_attention
 
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (1, 130, 4, 16))
@@ -373,10 +418,11 @@ def test_bwd_rejects_implicit_sq_neq_skv():
     with pytest.raises(ValueError, match="Sq == Skv"):
         jax.grad(lambda q_: jnp.sum(flash_attention(q_, k, v, causal=True)))(q)
     # the residual contract is validated too: a mis-shaped lse fails loudly
-    # instead of reducing garbage into dk/dv
+    # instead of reducing garbage into dk/dv (head-major kernel layout)
+    qh, kh, vh = (bhsd(x) for x in (q, k, v))
     with pytest.raises(ValueError, match="lse"):
         fab.check_bwd_shapes(
-            q, k, v, jnp.zeros((1, 4, 64)), jnp.zeros((1, 4, 130)), q
+            qh, kh, vh, jnp.zeros((1, 4, 64, 1)), jnp.zeros((1, 4, 130, 1)), qh
         )
     # explicit positions: the same shapes are well-defined and certified
     qp = jnp.arange(130, dtype=jnp.int32)[None]

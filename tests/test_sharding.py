@@ -5,17 +5,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.sharding.rules import Rules
 
-def _amesh(sizes, names):
-    """AbstractMesh across API generations: jax >= 0.5 takes (sizes, names),
-    0.4.x takes one tuple of (name, size) pairs."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-POD = _amesh((2, 16, 16), ("pod", "data", "model"))
-SINGLE = _amesh((16, 16), ("data", "model"))
+POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_generic_weight_fsdp_tp():

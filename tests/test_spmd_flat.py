@@ -50,12 +50,12 @@ from repro.core.gsnr import GradStats
 from repro.core.layout import ParamLayout, is_flat
 from repro.analysis.launch_manifest import LAUNCHES
 from repro.kernels.ops import count_pallas_calls
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.sharding.rules import Rules
 import oracle
 
 tm = jax.tree_util.tree_map
-mesh = compat_make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 bk = Backend.all_fused()
 plan = bk.shard(mesh, Rules(mesh=mesh))
 
@@ -183,14 +183,14 @@ from repro.configs import get_smoke
 from repro.data import lm_batches
 from repro.analysis.launch_manifest import LAUNCHES
 from repro.kernels.ops import count_pallas_calls
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.sharding.rules import Rules, activate
 from repro.train import init_state, make_loss_fn, make_train_step
 
 # the smoke transformer packs to 195 blocks — NOT divisible by this 8-device
 # mesh, so the END-TO-END fused train step exercises the remainder-padding
 # path for its per-shard stats and update (the ISSUE 6 carry-over case)
-mesh = compat_make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 cfg = get_smoke("granite-3-2b").replace(global_batch=16, seq_len=16)
 cfg = cfg.replace(
     optimizer=dataclasses.replace(cfg.optimizer, name="vr_lamb", k=4),
