@@ -294,13 +294,17 @@ class _TrackedPrefetch:
     """Background-prefetched batches that still expose an exact resume state."""
 
     def __init__(self, ds: IndexedPackedDataset, rows, device: bool, size: int):
+        from repro import obs
         from repro.data.pipeline import prefetch
 
         def produce():
             while True:
-                batch = ds.next_batch(rows)
-                st = ds.state
-                yield (_place(batch) if device else batch, st)
+                with obs.span(obs.DATA_PRODUCE):
+                    batch = ds.next_batch(rows)
+                    st = ds.state
+                    if device:
+                        batch = _place(batch)
+                yield batch, st
 
         self._it = prefetch(produce(), size=size)
         self.state: Optional[DataState] = None

@@ -20,6 +20,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import Config
 from repro.models import forward
 
@@ -85,7 +86,7 @@ def make_loss_fn(cfg: Config, with_aux: bool = True):
     if loss_norm not in ("token", "document"):
         raise ValueError(f"Config.loss_norm={loss_norm!r}: must be 'token' or 'document'")
 
-    def loss_fn(params, batch) -> Tuple[jnp.ndarray, Dict]:
+    def loss(params, batch) -> Tuple[jnp.ndarray, Dict]:
         extra = {}
         if "image" in batch:
             extra["image"] = batch["image"]
@@ -121,5 +122,9 @@ def make_loss_fn(cfg: Config, with_aux: bool = True):
         if not with_aux:
             return total
         return total, metrics
+
+    def loss_fn(params, batch):
+        with obs.scope(obs.MODEL):
+            return loss(params, batch)
 
     return loss_fn

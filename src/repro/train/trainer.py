@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.backend import resolve_backend
 from repro.configs.base import Config
 from repro.core import grad_only, grad_stats, gsnr_scale, gsnr_summary, make_optimizer
@@ -116,16 +117,18 @@ def make_train_step(
         else:
             loss, aux, grads = grad_only(loss_fn, state.params, batch, has_aux=True)
             stats = None
-        gnorm = global_norm(grads)
-        if opt_cfg.grad_clip > 0:
-            scale = jnp.minimum(1.0, opt_cfg.grad_clip / (gnorm + 1e-9))
-            grads = _tm(lambda g: g * scale, grads)
-        upd, opt_state = opt.update(grads, state.opt_state, state.params, stats=stats)
-        params = _tm(lambda p, u: (p + u).astype(p.dtype), state.params, upd)
+        with obs.scope(obs.OPTIMIZER):
+            gnorm = global_norm(grads)
+            if opt_cfg.grad_clip > 0:
+                scale = jnp.minimum(1.0, opt_cfg.grad_clip / (gnorm + 1e-9))
+                grads = _tm(lambda g: g * scale, grads)
+            upd, opt_state = opt.update(grads, state.opt_state, state.params, stats=stats)
+            params = _tm(lambda p, u: (p + u).astype(p.dtype), state.params, upd)
+            unorm = global_norm(upd)
         metrics = {
             "loss": loss,
             "grad_norm": gnorm,
-            "update_norm": global_norm(upd),
+            "update_norm": unorm,
             **(aux or {}),
         }
         if log_gsnr and stats is not None:

@@ -176,3 +176,58 @@ def test_flat_spmd_compiles(one_chip, bert_layout, name):
     else:
         _compile(one_chip, functools.partial(fn, **HYPER),
                  *[rows] * 7, scal, acc, lids, inv)
+
+
+# ---------------------------------------------------------------------------
+# the whole fused train step: the program's named scopes (repro.obs) rename
+# no kernel, and each kernel's op_name carries its phase's scope
+# ---------------------------------------------------------------------------
+
+
+def _step_text(one_chip, with_scopes: bool) -> str:
+    import contextlib
+    from unittest import mock
+
+    from repro import obs
+    from repro.backend import Backend
+    from repro.configs import Config, ModelConfig, OptimizerConfig, ParallelismConfig
+    from repro.train import init_state, make_train_step
+
+    model = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=256, n_heads=4,
+                        n_kv_heads=2, d_ff=512, vocab_size=512, head_dim=64,
+                        block_pattern=("attn",), norm="rmsnorm", act="swiglu", causal=True,
+                        tie_embeddings=True)
+    cfg = Config(model=model, optimizer=OptimizerConfig(name="vr_lamb", k=2),
+                 parallel=ParallelismConfig(compute_dtype="bfloat16",
+                                            backend=Backend.all_fused(interpret=False)),
+                 global_batch=4, seq_len=256)
+    place = functools.partial(jax.tree_util.tree_map,
+                              lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip))
+    state = place(jax.eval_shape(lambda: init_state(cfg)))
+    batch = place({k: jax.ShapeDtypeStruct((4, 256), I32) for k in ("tokens", "targets")})
+    bare = mock.patch.object(obs, "scope", lambda name: contextlib.nullcontext())
+    with contextlib.nullcontext() if with_scopes else bare:
+        step_fn, _ = make_train_step(cfg)
+        return jax.jit(lambda s, b: step_fn(s, b, True)).lower(state, batch).compile().as_text()
+
+
+def test_the_scopes_rename_no_kernel_of_the_fused_step(one_chip):
+    import re
+
+    from repro import obs
+
+    scoped, bare = _step_text(one_chip, True), _step_text(one_chip, False)
+    strip = re.compile(r", metadata=\{[^}]*\}")
+    assert ([strip.sub("", x) for x in scoped.splitlines() if " = " in x]
+            == [strip.sub("", x) for x in bare.splitlines() if " = " in x])
+    kernels = dict(re.findall(
+        r'%((?:flash_attention|flat_moments_accum|flat_moments_finalize|flat_vr_lamb)\.\d+) = '
+        r'.*?custom-call\(.*op_name="([^"]*)"', scoped))
+    by_kernel = {}
+    for name, op_name in kernels.items():
+        by_kernel.setdefault(name.split(".")[0], []).append(op_name)
+    assert len(by_kernel["flash_attention"]) == 3  # forward, recompute, backward
+    assert all("(model)" in n for n in by_kernel["flash_attention"])
+    assert all(f"/{obs.STATS_ACCUM}/" in n for n in by_kernel["flat_moments_accum"])
+    assert all(f"/{obs.STATS_FINALIZE}/" in n for n in by_kernel["flat_moments_finalize"])
+    assert all(f"/{obs.OPTIMIZER}/" in n for n in by_kernel["flat_vr_lamb"])
