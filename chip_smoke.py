@@ -221,7 +221,8 @@ def attention(label, b, s, h, kv, d):
                                       q_seg=qs, k_seg=ks)
         return (out, *map(bhsd, grads))
 
-    path = "one-pass" if fab.use_fused_dq(h // kv, s // 128, 128, 128, d, 4) else "split"
+    hb, bq, bk = fab.bwd_blocks(s, s, h, kv, d, 4)
+    path = "one-pass" if fab.use_fused_dq(h // kv, -(-s // bq), hb, bq, bk, d, 4) else "split"
     docs = int(jnp.sum(pos == 0))
     got = jax.block_until_ready(jax.jit(fused)(q, k, v, do))
     want = jax.block_until_ready(jax.jit(reference)(q, k, v, do))

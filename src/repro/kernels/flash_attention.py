@@ -2,17 +2,24 @@
 position- and segment-aware, with a custom VJP so the TRAINING forward runs
 on the fused path too.
 
-Forward grid (B, H, nq, nk) with the kv dim innermost: the output block for
-(b, h, iq) is revisited across ik while running max / denominator /
-accumulator live in VMEM scratch — the classic online-softmax pipeline,
-MXU-fed by (BLOCK_Q x D) @ (D x BLOCK_K) tiles.  When the call is being
+Forward grid (B, H/block_h, nq, nk) with the kv dim innermost: the output
+block for (b, head block, iq) is revisited across ik while running max /
+denominator / accumulator live in VMEM scratch — the classic online-softmax
+pipeline, MXU-fed by (block_q x D) @ (D x block_k) tiles, one head at a
+time.  A grid step carries ``block_h`` query heads and tiles sized from the
+call's shapes (``choose_blocks``: tiles up to MAX_TILE, then as many heads
+as the VMEM working set allows), so the fixed cost of a step is paid once
+for many tiles' work; the mask and the dead-tile decision depend on the
+positions alone and are shared by the step's heads.  When the call is being
 differentiated the forward additionally emits the LSE residual
 ``lse[b, h, i] = m_i + log l_i`` per query row — the only extra tensor the
 recomputation-based FlashAttention-2 backward needs (Dao 2023, Alg. 2).
 The backward kernels live in kernels/flash_attention_bwd.py.
 
-GQA: the kv-head index is h // (H // KV) inside the BlockSpec index maps, so
-grouped queries stream the same k/v tiles without materializing the repeat.
+GQA: a head block holds whole kv groups (block_h a multiple of G = H / KV)
+or part of one (block_h dividing G), and its k/v block the ``kv_block`` kv
+heads those query heads read — fetched once for the whole group, without
+materializing the repeat.
 
 Positions and segments are EXPLICIT kernel operands (the packed-sequence
 contract):
@@ -70,13 +77,88 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis.layout_contracts import LANE
+from repro.analysis.layout_contracts import DOUBLE_BUFFER, LANE, VMEM_BUDGET_BYTES
 from repro.backend import resolve_interpret
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
+DEFAULT_BLOCK_K = 128  # flash_decode's cache tile
+MAX_TILE = 512  # the longest q or kv tile one grid step takes
+# f32 values a forward step keeps live besides its operands and scratch,
+# fitted to what the TPU compiler allocates: (block_q, 1) columns per head
+# and (block_q, block_k) tiles
+FWD_COLUMN_TEMPS = 4
+FWD_TILE_TEMPS = 2
 NEG_INF = -1e30
 _BIG = 2**30  # position/segment sentinel for masked min/max bounds
+
+
+def seq_tiles(s: int) -> list:
+    """Tile lengths for a sequence of ``s``, longest first: the whole
+    sequence up to MAX_TILE, then 256 and 128."""
+    t = min(s, MAX_TILE)
+    return [t] + [x for x in (256, 128) if x < t]
+
+
+def head_blocks(h: int, kvh: int) -> list:
+    """Query heads one step can take, most first: whole kv groups (a
+    multiple of G = H / KV that divides H) or a divisor of the group."""
+    g = h // kvh
+    return [hb for hb in range(h, 0, -1) if h % hb == 0 and (hb % g == 0 or g % hb == 0)]
+
+
+def choose_blocks(sq: int, skv: int, h: int, kvh: int, fits, *, block_h=None,
+                  block_q=None, block_k=None) -> tuple:
+    """(block_h, block_q, block_k) of one call, each given value kept (a
+    tile capped at its sequence).  ``fits(block_h, block_q, block_k)`` is
+    the kernel's VMEM working set against the budget.  In order: one kv
+    group of query heads (G) a step, so its k/v tile is fetched once for
+    all the heads that read it, or the largest part of a group that fits;
+    the longest tiles that fit with it, the kv tile first (it stays
+    resident in the backward); then as many more heads as still fit."""
+    if block_h and block_h not in head_blocks(h, kvh):
+        raise ValueError(f"block_h={block_h} must divide H={h} and hold whole kv groups "
+                         f"of {h // kvh} or divide one")
+    qs = [min(block_q, sq)] if block_q else seq_tiles(sq)
+    ks = [min(block_k, skv)] if block_k else seq_tiles(skv)
+    hs = [block_h] if block_h else head_blocks(h, kvh)
+    base = [hb for hb in hs if hb <= h // kvh] or hs  # G, then its divisors
+    tiles = [(bq, bk) for bk in ks for bq in qs]
+    order = ([(base[0], bq, bk) for bq, bk in tiles]
+             + [(hb, bq, bk) for bq, bk in tiles for hb in base[1:]])
+    hb, bq, bk = next((c for c in order if fits(*c)), (base[-1], qs[-1], ks[-1]))
+    return next(c for c in hs if c <= hb or fits(c, bq, bk)), bq, bk
+
+
+def tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) value: padded to the (32 // itemsize,
+    LANE) tile, so a (block, 1) column takes block * LANE elements."""
+    sub = 32 // itemsize
+    return -(-rows // sub) * sub * -(-cols // LANE) * LANE * itemsize
+
+
+def fwd_vmem_bytes(block_h, kvb, block_q, block_k, d, itemsize) -> int:
+    """The forward step's VMEM working set, lane-padded as the TPU compiler
+    lays it out: double-buffered operand windows (q/out, k/v, the f32 lse
+    column, the pos/seg columns and rows) plus ``fwd_scratch_bytes``."""
+    col = tile_bytes(block_q, 1, 4)
+    windows = (block_h * (2 * tile_bytes(block_q, d, itemsize) + col)
+               + 2 * kvb * tile_bytes(block_k, d, itemsize)
+               + 2 * col + 2 * tile_bytes(1, block_k, 4))
+    return DOUBLE_BUFFER * windows + fwd_scratch_bytes(block_h, block_q, block_k, d)
+
+
+def fwd_scratch_bytes(block_h, block_q, block_k, d) -> int:
+    """The m/l/acc scratch and the step's f32 values, lane-padded."""
+    col = tile_bytes(block_q, 1, 4)
+    return (block_h * ((2 + FWD_COLUMN_TEMPS) * col + tile_bytes(block_q, d, 4))
+            + FWD_TILE_TEMPS * tile_bytes(block_q, block_k, 4))
+
+
+def fwd_blocks(sq, skv, h, kvh, d, itemsize, **given) -> tuple:
+    """The forward's (block_h, block_q, block_k) for one call's shapes."""
+    return choose_blocks(
+        sq, skv, h, kvh,
+        lambda hb, bq, bk: fwd_vmem_bytes(hb, kv_block(hb, h // kvh), bq, bk, d, itemsize)
+        <= VMEM_BUDGET_BYTES["tpu"], **given)
 
 
 def segment_ids_from_positions(pos: jnp.ndarray) -> jnp.ndarray:
@@ -169,10 +251,10 @@ def tile_reachable(qp, kp, qs, ks, causal: bool, window: int):
 
 
 def zero_oob_rows(x, i, block: int, seq: int):
-    """Zero rows of a (block, d) tile beyond ``seq`` (interpret mode pads
+    """Zero rows of a (..., block, d) tile beyond ``seq`` (interpret mode pads
     partial blocks with NaN; 0 * NaN would poison the MXU accumulations).
     Returns (x_zeroed, (block, 1) validity column)."""
-    valid = i * block + jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0) < seq
+    valid = i * block + jax.lax.broadcasted_iota(jnp.int32, (x.shape[-2], 1), 0) < seq
     return jnp.where(valid, x, 0.0), valid
 
 
@@ -272,6 +354,18 @@ def _maybe_skip_dead_tile(
         pl.when(tile_reachable(qp, kp, qs, ks, causal, window))(compute)
 
 
+def for_each_head(hb: int, body):
+    """Run ``body(j)`` for each query head j of the step, one (block_q,
+    block_k) tile at a time (unrolled: the compiler overlaps one head's
+    matmuls with another's softmax)."""
+    for j in range(hb):
+        body(j)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), preferred_element_type=jnp.float32)
+
+
 def _kernel(
     fetch_ref, q_ref, k_ref, v_ref, qp_ref, kp_ref, qs_ref, ks_ref, *rest,
     causal: bool, window: int, block_q: int, block_k: int, scale: float,
@@ -281,6 +375,7 @@ def _kernel(
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         (o_ref, m_scr, l_scr, acc_scr) = rest
+    hb = q_ref.shape[0]
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -295,31 +390,32 @@ def _kernel(
     kp, ks = _load_pos_seg(kp_ref, ks_ref, ik, block_k, seq_kv, seg_fill=-2)
 
     def _compute():
-        q = q_ref[...].astype(jnp.float32)  # (BQ, D)
-        k, _ = zero_oob_rows(k_ref[...].astype(jnp.float32), ik, block_k, seq_kv)
-        v, _ = zero_oob_rows(v_ref[...].astype(jnp.float32), ik, block_k, seq_kv)
-        s = jax.lax.dot_general(
-            q * scale, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (BQ, BK)
-
+        # one (BQ, BK) mask for every head of the step: it depends on the
+        # positions alone
         mask = tile_mask(qp, kp, qs, ks, causal, window)
-        s = jnp.where(mask, s, NEG_INF)
+        group = hb // k_ref.shape[0]  # query heads per kv head of the step
 
-        m_prev = m_scr[...]  # (BQ, 1)
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # exact zeros for masked entries: a fully-masked row has s == m ==
-        # NEG_INF everywhere, where exp(s - m) would be 1 and the row would
-        # silently turn into a uniform average over kv — the l stays 0 so
-        # _finalize can emit 0.
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+        def head(j):
+            q = q_ref[j].astype(jnp.float32)  # (BQ, D)
+            k, _ = zero_oob_rows(k_ref[j // group].astype(jnp.float32), ik, block_k, seq_kv)
+            v, _ = zero_oob_rows(v_ref[j // group].astype(jnp.float32), ik, block_k, seq_kv)
+            s = _dot(q * scale, k, ((1,), (1,)))  # (BQ, BK)
+            s = jnp.where(mask, s, NEG_INF)
+
+            m_prev = m_scr[j]  # (BQ, 1)
+            l_prev = l_scr[j]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # exact zeros for masked entries: a fully-masked row has s == m ==
+            # NEG_INF everywhere, where exp(s - m) would be 1 and the row would
+            # silently turn into a uniform average over kv — the l stays 0 so
+            # _finalize can emit 0.
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[j] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[j] = acc_scr[j] * corr + _dot(p, v, ((1,), (0,)))
+            m_scr[j] = m_new
+
+        for_each_head(hb, head)
 
     if implicit:
         # grid-index predicate: free, and the static fetch map is built from
@@ -358,30 +454,43 @@ def bhsd(x):
     return jnp.swapaxes(x, 1, 2)
 
 
-def fwd_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int, with_lse: bool):
+def kv_block(block_h: int, g: int) -> int:
+    """kv heads one step holds: the groups of its ``block_h`` query heads
+    (one where the step covers part of a group)."""
+    if block_h % g and g % block_h:
+        raise ValueError(f"a block of {block_h} query heads must hold whole kv groups "
+                         f"of {g} or divide one")
+    return max(1, block_h // g)
+
+
+def fwd_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int, with_lse: bool,
+                 block_h: int = 1):
     """Grid, named BlockSpecs and array shapes of the forward pallas_call.
 
     Single source of truth shared between _fwd_call, the contract checker
     and benchmarks.cost_model.  q/out are (B, H, Sq, D), k/v (B, KV, Skv, D);
     the q-side pos/seg operands are (B, Sq, 1) columns and the k-side ones
-    (B, 1, Skv) rows; the LSE residual is (B, H, Sq, 1).  Every index map
-    takes the flattened (B*nq*nk,) int32 fetch array as its trailing
-    scalar-prefetch argument; the kv-side maps (k, v, k_pos, k_seg) read the
-    fetch-mapped block so dead grid steps repeat the previous index and
-    Mosaic elides their copy-in.
+    (B, 1, Skv) rows; the LSE residual is (B, H, Sq, 1).  A step takes
+    ``block_h`` query heads ((block_h, block_q, D) q/out blocks) and the
+    ``kv_block`` kv heads they read.  Every index map takes the flattened
+    (B*nq*nk,) int32 fetch array as its trailing scalar-prefetch argument;
+    the kv-side maps (k, v, k_pos, k_seg) read the fetch-mapped block so
+    dead grid steps repeat the previous index and Mosaic elides their
+    copy-in.
     """
     g = h // kvh
+    hb, kvb = block_h, kv_block(block_h, g)
     nq = -(-sq // block_q)
     nk = -(-skv // block_k)
-    grid = (b, h, nq, nk)
+    grid = (b, h // hb, nq, nk)
 
     def fetched(b_, iq, ik, f):
         return f[(b_ * nq + iq) * nk + ik]
 
-    q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h_, iq, ik, f: (b_, h_, iq, 0))
+    q_spec = pl.BlockSpec((None, hb, block_q, d), lambda b_, h_, iq, ik, f: (b_, h_, iq, 0))
     kv_spec = pl.BlockSpec(
-        (None, None, block_k, d),
-        lambda b_, h_, iq, ik, f: (b_, h_ // g, fetched(b_, iq, ik, f), 0))
+        (None, kvb, block_k, d),
+        lambda b_, h_, iq, ik, f: (b_, h_ * hb // (g * kvb), fetched(b_, iq, ik, f), 0))
     qcol_spec = pl.BlockSpec((None, block_q, 1), lambda b_, h_, iq, ik, f: (b_, iq, 0))
     krow_spec = pl.BlockSpec(
         (None, 1, block_k), lambda b_, h_, iq, ik, f: (b_, 0, fetched(b_, iq, ik, f)))
@@ -396,7 +505,7 @@ def fwd_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int, with_lse:
         "k_seg": (b, 1, skv), "out": (b, h, sq, d),
     }
     if with_lse:
-        outs["lse"] = pl.BlockSpec((None, None, block_q, 1),
+        outs["lse"] = pl.BlockSpec((None, hb, block_q, 1),
                                    lambda b_, h_, iq, ik, f: (b_, h_, iq, 0))
         shapes["lse"] = (b, h, sq, 1)
     return grid, nq, nk, g, ins, outs, shapes
@@ -409,7 +518,8 @@ def pos_operands(q_pos, k_pos, q_seg, k_seg):
 
 
 def _fwd_call(q, k, v, q_pos, k_pos, q_seg, k_seg,
-              *, causal, window, block_q, block_k, interpret, with_lse, implicit):
+              *, causal, window, block_q, block_k, interpret, with_lse, implicit,
+              block_h=1):
     """One pallas_call on the head-major layout: q (B,H,Sq,D), k/v
     (B,KV,Skv,D), pos/seg (B,S) int32 -> out (B,H,Sq,D) [+ lse (B,H,Sq,1)
     f32 when with_lse]."""
@@ -417,7 +527,8 @@ def _fwd_call(q, k, v, q_pos, k_pos, q_seg, k_seg,
     kvh, skv = k.shape[1], k.shape[2]
     scale = d**-0.5
     grid, nq, nk, g, ins, out_spec_map, _ = fwd_geometry(
-        b, sq, h, d, skv, kvh, block_q=block_q, block_k=block_k, with_lse=with_lse
+        b, sq, h, d, skv, kvh, block_q=block_q, block_k=block_k, with_lse=with_lse,
+        block_h=block_h,
     )
     if implicit:
         fetch = jnp.asarray(
@@ -441,9 +552,9 @@ def _fwd_call(q, k, v, q_pos, k_pos, q_seg, k_seg,
         in_specs=list(ins.values()),
         out_specs=list(out_spec_map.values()),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_h, block_q, 1), jnp.float32),
+            pltpu.VMEM((block_h, block_q, 1), jnp.float32),
+            pltpu.VMEM((block_h, block_q, d), jnp.float32),
         ],
     )
     outs = pl.pallas_call(
@@ -463,10 +574,11 @@ _NO_POS_GRADS = (None, None, None, None)  # int operands: symbolic-zero cotangen
 
 
 @functools.lru_cache(maxsize=None)
-def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
+def _flash_fn(causal: bool, window: int, fwd_blocks: tuple, bwd_blocks: tuple,
               interpret: bool, implicit: bool):
     """custom_vjp'd flash attention on the head-major (B,H,S,D) layout for
-    one static config.
+    one static config; ``fwd_blocks`` / ``bwd_blocks`` are the (block_h,
+    block_q, block_k) geometry of the forward and backward kernels.
 
     Three nested custom_vjp layers keep every pallas_call out of autodiff's
     reach while staying differentiable to arbitrary order:
@@ -482,13 +594,14 @@ def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
     """
     from repro.kernels import flash_attention_bwd as fab
 
-    kw = dict(causal=causal, window=window, block_q=block_q, block_k=block_k,
-              interpret=interpret, implicit=implicit)
+    kw = dict(causal=causal, window=window, interpret=interpret, implicit=implicit)
+    fwd_kw = dict(kw, **dict(zip(("block_h", "block_q", "block_k"), fwd_blocks)))
+    bwd_kw = dict(kw, **dict(zip(("block_h", "block_q", "block_k"), bwd_blocks)))
     pos_kw = lambda qp, kp, qs, ks: dict(q_pos=qp, k_pos=kp, q_seg=qs, k_seg=ks)
 
     @jax.custom_vjp
     def _fwd_p(q, k, v, qp, kp, qs, ks):
-        return _fwd_call(q, k, v, qp, kp, qs, ks, with_lse=True, **kw)
+        return _fwd_call(q, k, v, qp, kp, qs, ks, with_lse=True, **fwd_kw)
 
     def _fwd_p_fwd(q, k, v, qp, kp, qs, ks):
         return _fwd_p(q, k, v, qp, kp, qs, ks), (q, k, v, qp, kp, qs, ks)
@@ -509,7 +622,7 @@ def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
 
     @jax.custom_vjp
     def _bwd_p(q, k, v, lse, delta, do, qp, kp, qs, ks):
-        return fab.flash_attention_bwd(q, k, v, lse, delta, do, qp, kp, qs, ks, **kw)
+        return fab.flash_attention_bwd(q, k, v, lse, delta, do, qp, kp, qs, ks, **bwd_kw)
 
     def _bwd_p_fwd(q, k, v, lse, delta, do, qp, kp, qs, ks):
         return _bwd_p(q, k, v, lse, delta, do, qp, kp, qs, ks), (
@@ -530,7 +643,7 @@ def _flash_fn(causal: bool, window: int, block_q: int, block_k: int,
 
     @jax.custom_vjp
     def flash(q, k, v, qp, kp, qs, ks):
-        return _fwd_call(q, k, v, qp, kp, qs, ks, with_lse=False, **kw)[0]
+        return _fwd_call(q, k, v, qp, kp, qs, ks, with_lse=False, **fwd_kw)[0]
 
     def flash_fwd(q, k, v, qp, kp, qs, ks):
         out, lse = _fwd_p(q, k, v, qp, kp, qs, ks)
@@ -601,7 +714,8 @@ def resolve_positions(q_pos, k_pos, sq: int, skv: int, q_seg=None, k_seg=None):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("causal", "window", "block_q", "block_k", "block_h", "interpret"),
 )
 def flash_attention(
     q: jnp.ndarray,
@@ -614,8 +728,9 @@ def flash_attention(
     *,
     causal: bool = True,
     window: int = 0,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    block_h: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """q: (B,S,H,D); k,v: (B,Skv,KV,D) -> (B,S,H,D).  Differentiable.
@@ -630,7 +745,14 @@ def flash_attention(
     aligned the two aranges).  Segment ids are derived from positions
     (segment_ids_from_positions) unless passed explicitly, so packed
     multi-document rows mask cross-document attention with no extra operand.
+
+    block_q/block_k/block_h: the q tile, kv tile and query heads of one
+    grid step, each chosen from the call's shapes where not given
+    (``fwd_blocks`` for the forward, ``flash_attention_bwd.bwd_blocks`` for
+    the backward).
     """
+    from repro.kernels import flash_attention_bwd as fab
+
     b, sq, h, d = q.shape
     skv = k.shape[1]
     implicit = q_pos is None  # static: picks the grid-index dead-tile skip
@@ -641,9 +763,10 @@ def flash_attention(
     k_pos = jnp.broadcast_to(k_pos, (b, skv))
     q_seg = jnp.broadcast_to(q_seg, (b, sq))
     k_seg = jnp.broadcast_to(k_seg, (b, skv))
-    block_q = min(block_q, sq)
-    block_k = min(block_k, skv)
-    fn = _flash_fn(causal, window, block_q, block_k, resolve_interpret(interpret), implicit)
+    shape = (sq, skv, h, k.shape[2], d, q.dtype.itemsize)
+    given = dict(block_h=block_h, block_q=block_q, block_k=block_k)
+    fn = _flash_fn(causal, window, fwd_blocks(*shape, **given),
+                   fab.bwd_blocks(*shape, **given), resolve_interpret(interpret), implicit)
     return bhsd(fn(bhsd(q), bhsd(k), bhsd(v), q_pos, k_pos, q_seg, k_seg))
 
 
@@ -665,13 +788,14 @@ def _analysis_positions(b: int, s: int, docs) -> np.ndarray:
 
 
 def _analysis_geometry(B, S, H, KV, D, *, causal=True, window=0, docs=None,
-                       dtype="float32", block_q=DEFAULT_BLOCK_Q,
-                       block_k=DEFAULT_BLOCK_K):
+                       dtype="float32", block_h=None, block_q=None, block_k=None):
+    from repro.analysis.layout_contracts import itemsize
     from repro.analysis.registry import FetchMap, Geometry, Operand
 
-    bq, bk = min(block_q, S), min(block_k, S)
+    hb, bq, bk = fwd_blocks(S, S, H, KV, D, itemsize(dtype),
+                            block_h=block_h, block_q=block_q, block_k=block_k)
     grid, nq, nk, _, ins, outs, shapes = fwd_geometry(
-        B, S, H, D, S, KV, block_q=bq, block_k=bk, with_lse=True)
+        B, S, H, D, S, KV, block_q=bq, block_k=bk, with_lse=True, block_h=hb)
     if docs is not None:
         qp, kp, qs, ks = resolve_positions(
             jnp.asarray(_analysis_positions(B, S, docs)),
@@ -694,8 +818,7 @@ def _analysis_geometry(B, S, H, KV, D, *, causal=True, window=0, docs=None,
         grid=grid,
         ins={n: op(n, s) for n, s in ins.items()},
         outs={n: op(n, s) for n, s in outs.items()},
-        # m/l are (bq, 1) columns, lane-padded in VMEM
-        scratch_bytes=4 * (2 * bq * LANE + bq * D),
+        scratch_bytes=fwd_scratch_bytes(hb, bq, bk, D),
         extra=(fetch.reshape(-1),),
         fetch_maps={"kv": fm},
     )
@@ -710,13 +833,21 @@ def _register():
         oracle="attention_fwd_ref",
         build=_analysis_geometry,
         configs={
-            "representative": dict(B=2, S=512, H=8, KV=2, D=64,
-                                   causal=True, docs=(256, 170, 54)),
+            # documents crossing the 512-token tiles the shapes choose
+            "representative": dict(B=2, S=2048, H=8, KV=2, D=64,
+                                   causal=True, docs=(700, 900, 300)),
             "hostile_packed_bf16": dict(B=1, S=130, H=4, KV=2, D=32,
                                         causal=True, docs=(70, 41, 19),
-                                        dtype="bfloat16"),
+                                        dtype="bfloat16", block_q=64, block_k=128),
             "hostile_dense_identity": dict(B=1, S=256, H=2, KV=2, D=64,
-                                           causal=False, docs=None),
+                                           causal=False, docs=None, block_q=128),
+            # granite-3-2b (GQA 32:8, D=64) at seq 4096: a kv group of 4
+            # heads x 512 x 512 a step, 512 steps a call
+            "granite_3_2b_seq4096": dict(B=1, S=4096, H=32, KV=8, D=64, causal=True,
+                                         docs=(1500, 2000, 400), dtype="bfloat16"),
+            # bert-large (MHA 16, D=64) at seq 128: all 16 heads a step
+            "bert_large_seq128": dict(B=8, S=128, H=16, KV=16, D=64, causal=False,
+                                      docs=None, dtype="bfloat16"),
         },
     )
 

@@ -14,34 +14,34 @@ recomputed as ``exp(scale * q k^T - lse)`` (already softmax-normalized):
     dS_ij = p_ij (dp_ij - delta_i) * scale
     dq_i = sum_j dS_ij k_j           dk_j = sum_i dS_ij q_i
 
-ONE kernel on grid (B, KV, nk, G*nq): the inner dim walks every
-(group member, q block) pair while the kv block stays resident, so the
-s = q kᵀ / p recompute is shared — each tile pair does 5 matmuls
-(s, dp, dv, dk, dq) where split dq + dk/dv kernels do 7 (s and dp
-recomputed by both), and the grad launch count is 2 (delta preprocess
-stays jnp):
+ONE kernel on grid (B, KV/kvb, nk, ng*nq): a step holds ``kvb`` kv heads
+and ``block_h`` of their query heads (``bwd_blocks`` chooses both, and the
+tiles, from the call's shapes); the inner dim walks every (head block of
+the group, q block) pair while the kv block stays resident — ng = 1 where
+the head block holds whole groups — so the s = q kᵀ / p recompute is
+shared: each tile pair does 5 matmuls (s, dp, dv, dk, dq) where split dq +
+dk/dv kernels do 7 (s and dp recomputed by both), and the grad launch count
+is 2 (delta preprocess stays jnp):
 
   * dk/dv accumulate in VMEM scratch owned by the resident kv block
     (init at t == 0, finalized into the kv-head-shaped outputs at
-    t == G*nq - 1, the GQA group-sum folded into the same sweep);
-  * dq accumulates in an f32 VMEM scratch holding the whole q side of the
-    kv group, (G*nq, block_q, D) — G*Sq*D*4 bytes, 4 MiB for G=4, Sq=4096,
-    D=64 — zeroed at the group's first step so all-dead rows emit exact
-    zeros, and written to its output block once, on the last kv step.  An
-    output block that is left and later revisited is NOT re-fetched from
-    HBM by the TPU pipeline, so dq cannot accumulate through its output
-    window; its index map parks until the last kv step instead
-    (bwd_geometry).
+    t == ng*nq - 1, the GQA group-sum folded into the same sweep);
+  * dq accumulates, transposed, in an f32 VMEM scratch holding the whole
+    q side of the kv group, (ng*nq, block_h, D, block_q) — G*Sq*D*4 bytes,
+    4 MiB for G=4, Sq=4096, D=64, and lane-dense where D < 128 — zeroed at
+    the group's first step so all-dead rows emit exact zeros, and written
+    to its output block once, on the last kv step.  An output block that
+    is left and later revisited is NOT re-fetched from HBM by the TPU
+    pipeline, so dq cannot accumulate through its output window; its
+    index map parks until the last kv step instead (bwd_geometry).
 
 That scratch grows with G*Sq*D: 96 MiB for granite-20b (G=48, D=128) at
-Sq=4096.  Where the one-pass kernel's working set would pass half the VMEM
-budget (``fused_vmem_bytes``; the other half is headroom for the lane
-padding of the (block, 1) columns and the (block_q, block_k) f32
-intermediates the estimate leaves out), the backward SPLITS: the same
-kernel without dq computes dk/dv, and a q-outer dq kernel on grid
-(B, H, nq, nk) accumulates one (block_q, D) block across the kv sweep —
-VMEM independent of the sequence length, at the cost of recomputing s and
-dp and a third launch.
+Sq=4096.  Where the one-pass kernel's working set (``fused_vmem_bytes``,
+lane-padded as the compiler lays it out) would pass the VMEM budget, the
+backward SPLITS: the same kernel without dq computes dk/dv, and a q-outer
+dq kernel on grid (B, H/block_h, nq, nk) accumulates one (block_h,
+block_q, D) block across the kv sweep — VMEM independent of the sequence
+length, at the cost of recomputing s and dp and a third launch.
 
 Both kernels take the same (q_pos, k_pos, q_seg, k_seg) operands as the
 forward and mask through the SAME tile_mask rule — positions < 0 are
@@ -68,12 +68,16 @@ from repro.analysis.layout_contracts import DOUBLE_BUFFER, VMEM_BUDGET_BYTES
 # p = exp(s - lse) is only valid against the exact mask the forward's lse was
 # built under
 from repro.kernels.flash_attention import (
-    DEFAULT_BLOCK_K,
-    DEFAULT_BLOCK_Q,
+    LANE,
     NEG_INF,
+    _dot,
     _load_pos_seg,
     _maybe_skip_dead_tile,
+    choose_blocks,
+    for_each_head,
+    kv_block,
     pos_operands,
+    tile_bytes,
     tile_mask,
     zero_oob_rows,
 )
@@ -83,23 +87,27 @@ from repro.kernels.flash_attention import (
 from repro.kernels import ref as rf
 from repro.kernels.ref import attention_fwd_ref  # noqa: F401
 
+# f32 values a backward step keeps live besides its operands and scratch,
+# fitted to what the TPU compiler allocates: per head, (block_q, 1) columns
+# and (block_q, D) rows (q and dO); (block_q, block_k) tiles
+BWD_COLUMN_TEMPS = 3
+BWD_ROW_TEMPS = 2
+BWD_TILE_TEMPS = 2
 
-def _dot(a, b, dims):
-    return jax.lax.dot_general(a, b, (dims, ((), ())), preferred_element_type=jnp.float32)
 
-
-def _load_q_side(q_ref, do_ref, lse_ref, delta_ref, iq, block_q, seq_q):
-    """Sanitized q-side streams: OOB rows of partial q blocks zeroed."""
-    q, q_valid = zero_oob_rows(q_ref[...].astype(jnp.float32), iq, block_q, seq_q)
-    do, _ = zero_oob_rows(do_ref[...].astype(jnp.float32), iq, block_q, seq_q)
-    lse = jnp.where(q_valid, lse_ref[...], 0.0)  # (BQ, 1)
-    delta = jnp.where(q_valid, delta_ref[...], 0.0)
+def _load_q_side(q_ref, do_ref, lse_ref, delta_ref, j, iq, block_q, seq_q):
+    """Sanitized q-side streams of head j: OOB rows of partial q blocks
+    zeroed."""
+    q, q_valid = zero_oob_rows(q_ref[j].astype(jnp.float32), iq, block_q, seq_q)
+    do, _ = zero_oob_rows(do_ref[j].astype(jnp.float32), iq, block_q, seq_q)
+    lse = jnp.where(q_valid, lse_ref[j], 0.0)  # (BQ, 1)
+    delta = jnp.where(q_valid, delta_ref[j], 0.0)
     return q, do, lse, delta
 
 
-def _load_kv_side(k_ref, v_ref, ik, block_k, seq_kv):
-    k, _ = zero_oob_rows(k_ref[...].astype(jnp.float32), ik, block_k, seq_kv)
-    v, _ = zero_oob_rows(v_ref[...].astype(jnp.float32), ik, block_k, seq_kv)
+def _load_kv_side(k_ref, v_ref, c, ik, block_k, seq_kv):
+    k, _ = zero_oob_rows(k_ref[c].astype(jnp.float32), ik, block_k, seq_kv)
+    v, _ = zero_oob_rows(v_ref[c].astype(jnp.float32), ik, block_k, seq_kv)
     return k, v
 
 
@@ -118,14 +126,16 @@ def _p_ds(q, k, v, do, lse, delta, mask, scale):
 def _fused_bwd_kernel(
     q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, qp_ref, kp_ref, qs_ref, ks_ref,
     *rest, causal: bool, window: int, block_q: int, block_k: int, scale: float,
-    seq_q: int, seq_kv: int, nq: int, nk: int, g: int, implicit: bool, with_dq: bool,
+    seq_q: int, seq_kv: int, nq: int, nk: int, ng: int, implicit: bool, with_dq: bool,
 ):
     if with_dq:
         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
     else:
         dk_ref, dv_ref, dk_scr, dv_scr = rest
+    hb = q_ref.shape[0]
+    group = hb // k_ref.shape[0]  # query heads per kv head of the step
     ik = pl.program_id(2)
-    t = pl.program_id(3)  # inner sweep over (group member, q block) pairs
+    t = pl.program_id(3)  # inner sweep over (head block of the group, q block)
     iq = t % nq
 
     @pl.when(t == 0)
@@ -134,7 +144,7 @@ def _fused_bwd_kernel(
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     if with_dq:
-        # dq for every (group member, q block) of this kv group accumulates
+        # dq for every (head block, q block) of this kv group accumulates
         # in VMEM across the kv sweep; zeroed once per group so q rows that
         # reach no kv at all still emit exact zeros
         @pl.when((ik == 0) & (t == 0))
@@ -145,14 +155,21 @@ def _fused_bwd_kernel(
     kp, ks = _load_pos_seg(kp_ref, ks_ref, ik, block_k, seq_kv, seg_fill=-2)
 
     def _compute():
-        q, do, lse, delta = _load_q_side(q_ref, do_ref, lse_ref, delta_ref, iq, block_q, seq_q)
-        k, v = _load_kv_side(k_ref, v_ref, ik, block_k, seq_kv)
         mask = tile_mask(qp, kp, qs, ks, causal, window)
-        p, ds = _p_ds(q, k, v, do, lse, delta, mask, scale)
-        dv_scr[...] += _dot(p, do, ((0,), (0,)))  # (BK, D)
-        dk_scr[...] += _dot(ds, q, ((0,), (0,)))  # (BK, D)
-        if with_dq:
-            dq_scr[t] += _dot(ds, k, ((1,), (0,)))  # (BQ, D)
+
+        def head(j):
+            c = j // group
+            q, do, lse, delta = _load_q_side(q_ref, do_ref, lse_ref, delta_ref, j, iq,
+                                             block_q, seq_q)
+            k, v = _load_kv_side(k_ref, v_ref, c, ik, block_k, seq_kv)
+            p, ds = _p_ds(q, k, v, do, lse, delta, mask, scale)
+            dv_scr[c] += _dot(p, do, ((0,), (0,)))  # (BK, D)
+            dk_scr[c] += _dot(ds, q, ((0,), (0,)))  # (BK, D)
+            if with_dq:
+                # dqᵀ (D, BQ): lane-dense for D < 128
+                dq_scr[t, j] += _dot(k, ds, ((0,), (1,)))
+
+        for_each_head(hb, head)
 
     _maybe_skip_dead_tile(_compute, qp, kp, qs, ks, causal, window,
                           implicit=implicit, iq=iq, ik=ik,
@@ -164,9 +181,12 @@ def _fused_bwd_kernel(
         # exactly once
         @pl.when(ik == nk - 1)
         def _write_dq():
-            dq_ref[...] = dq_scr[t].astype(dq_ref.dtype)
+            def head(j):
+                dq_ref[j] = dq_scr[t, j].T.astype(dq_ref.dtype)
 
-    @pl.when(t == g * nq - 1)
+            for_each_head(hb, head)
+
+    @pl.when(t == ng * nq - 1)
     def _finalize():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
@@ -178,8 +198,10 @@ def _dq_kernel(
     *, causal: bool, window: int, block_q: int, block_k: int, scale: float,
     seq_q: int, seq_kv: int, nk: int, implicit: bool,
 ):
-    """Split-path dq: one q block resident, the kv blocks innermost; dq's
-    output block recurs only on consecutive steps."""
+    """Split-path dq: one q block of a head block resident, the kv blocks
+    innermost; dq's output block recurs only on consecutive steps."""
+    hb = q_ref.shape[0]
+    group = hb // k_ref.shape[0]
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -191,11 +213,16 @@ def _dq_kernel(
     kp, ks = _load_pos_seg(kp_ref, ks_ref, ik, block_k, seq_kv, seg_fill=-2)
 
     def _compute():
-        q, do, lse, delta = _load_q_side(q_ref, do_ref, lse_ref, delta_ref, iq, block_q, seq_q)
-        k, v = _load_kv_side(k_ref, v_ref, ik, block_k, seq_kv)
         mask = tile_mask(qp, kp, qs, ks, causal, window)
-        _, ds = _p_ds(q, k, v, do, lse, delta, mask, scale)
-        dq_scr[...] += _dot(ds, k, ((1,), (0,)))  # (BQ, D)
+
+        def head(j):
+            q, do, lse, delta = _load_q_side(q_ref, do_ref, lse_ref, delta_ref, j, iq,
+                                             block_q, seq_q)
+            k, v = _load_kv_side(k_ref, v_ref, j // group, ik, block_k, seq_kv)
+            _, ds = _p_ds(q, k, v, do, lse, delta, mask, scale)
+            dq_scr[j] += _dot(ds, k, ((1,), (0,)))  # (BQ, D)
+
+        for_each_head(hb, head)
 
     _maybe_skip_dead_tile(_compute, qp, kp, qs, ks, causal, window,
                           implicit=implicit, iq=iq, ik=ik,
@@ -231,29 +258,34 @@ def check_bwd_shapes(q, k, v, lse, delta, do):
 
 
 def bwd_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int,
-                 with_dq: bool = True):
+                 with_dq: bool = True, block_h: int = 1):
     """Grid, named BlockSpecs and array shapes of the fused backward.
 
     Single source of truth shared between flash_attention_bwd, the contract
     checker and benchmarks.cost_model (which replays the index maps with
-    concrete grid indices to count block visits / HBM bytes).  Inner grid
-    dim t = ig * nq + iq walks every query head of the GQA group (head index
-    j*g + t//nq) and every q block while the kv block (b, j, ik) stays
-    resident.  dq is written only on the last kv step: before it, its index
-    map parks on the group's first block (the block the last step starts
-    on), so no dq block is ever revisited or written back unwritten.
-    ``with_dq=False`` is the split path's dk/dv-only kernel.
+    concrete grid indices to count block visits / HBM bytes).  A step holds
+    ``kv_block`` kv heads (block j) and ``block_h`` of their query heads;
+    where a head block is part of a group (``block_h`` < G), the group's ng
+    head blocks take turns.  Inner grid dim t = ig * nq + iq walks every
+    head block of the group (head block j*ng + t//nq) and every q block
+    while the kv block (b, j, ik) stays resident.  dq is written only on
+    the last kv step: before it, its index map parks on the group's first
+    block (the block the last step starts on), so no dq block is ever
+    revisited or written back unwritten.  ``with_dq=False`` is the split
+    path's dk/dv-only kernel.  Returns ng where the forward returns G.
     """
     g = h // kvh
+    hb, kvb = block_h, kv_block(block_h, g)
+    ng = max(1, g // hb)
     nq = -(-sq // block_q)
     nk = -(-skv // block_k)
-    grid = (b, kvh, nk, g * nq)
+    grid = (b, kvh // kvb, nk, ng * nq)
     q_spec = pl.BlockSpec(
-        (None, None, block_q, d), lambda b_, j, ik, t: (b_, j * g + t // nq, t % nq, 0)
+        (None, hb, block_q, d), lambda b_, j, ik, t: (b_, j * ng + t // nq, t % nq, 0)
     )
-    kv_spec = pl.BlockSpec((None, None, block_k, d), lambda b_, j, ik, t: (b_, j, ik, 0))
+    kv_spec = pl.BlockSpec((None, kvb, block_k, d), lambda b_, j, ik, t: (b_, j, ik, 0))
     res_spec = pl.BlockSpec(
-        (None, None, block_q, 1), lambda b_, j, ik, t: (b_, j * g + t // nq, t % nq, 0)
+        (None, hb, block_q, 1), lambda b_, j, ik, t: (b_, j * ng + t // nq, t % nq, 0)
     )
     qcol_spec = pl.BlockSpec((None, block_q, 1), lambda b_, j, ik, t: (b_, t % nq, 0))
     krow_spec = pl.BlockSpec((None, 1, block_k), lambda b_, j, ik, t: (b_, 0, ik))
@@ -265,24 +297,25 @@ def bwd_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int,
     outs = {"dk": kv_spec, "dv": kv_spec}
     if with_dq:
         outs = {"dq": pl.BlockSpec(
-            (None, None, block_q, d),
-            lambda b_, j, ik, t: (b_, j * g + (t // nq) * (ik == nk - 1),
+            (None, hb, block_q, d),
+            lambda b_, j, ik, t: (b_, j * ng + (t // nq) * (ik == nk - 1),
                                   (t % nq) * (ik == nk - 1), 0),
         ), **outs}
-    return grid, nq, nk, g, ins, outs, _bwd_shapes(b, h, sq, d, kvh, skv)
+    return grid, nq, nk, ng, ins, outs, _bwd_shapes(b, h, sq, d, kvh, skv)
 
 
-def dq_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int):
+def dq_geometry(b, sq, h, d, skv, kvh, *, block_q: int, block_k: int, block_h: int = 1):
     """Grid, named BlockSpecs and array shapes of the split path's dq kernel:
-    grid (B, H, nq, nk), kv blocks innermost."""
+    grid (B, H/block_h, nq, nk), kv blocks innermost."""
     g = h // kvh
+    hb, kvb = block_h, kv_block(block_h, g)
     nq = -(-sq // block_q)
     nk = -(-skv // block_k)
-    grid = (b, h, nq, nk)
-    q_spec = pl.BlockSpec((None, None, block_q, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0))
-    kv_spec = pl.BlockSpec((None, None, block_k, d),
-                           lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0))
-    res_spec = pl.BlockSpec((None, None, block_q, 1), lambda b_, h_, iq, ik: (b_, h_, iq, 0))
+    grid = (b, h // hb, nq, nk)
+    q_spec = pl.BlockSpec((None, hb, block_q, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0))
+    kv_spec = pl.BlockSpec((None, kvb, block_k, d),
+                           lambda b_, h_, iq, ik: (b_, h_ * hb // (g * kvb), ik, 0))
+    res_spec = pl.BlockSpec((None, hb, block_q, 1), lambda b_, h_, iq, ik: (b_, h_, iq, 0))
     qcol_spec = pl.BlockSpec((None, block_q, 1), lambda b_, h_, iq, ik: (b_, iq, 0))
     krow_spec = pl.BlockSpec((None, 1, block_k), lambda b_, h_, iq, ik: (b_, 0, ik))
     ins = {
@@ -302,24 +335,82 @@ def _bwd_shapes(b, h, sq, d, kvh, skv):
     }
 
 
-def fused_vmem_bytes(g, nq, block_q, block_k, d, itemsize) -> int:
-    """The one-pass kernel's VMEM working set as the VMEM-BUDGET rule counts
-    it: double-buffered operand windows (q/do/dq and k/v/dk/dv tiles, four
-    f32 (block_q, 1) columns, two int32 (1, block_k) rows) plus scratch."""
-    windows = (3 * block_q + 4 * block_k) * d * itemsize + (4 * block_q + 2 * block_k) * 4
-    return DOUBLE_BUFFER * windows + (g * nq * block_q + 2 * block_k) * d * 4
+def bwd_scratch_bytes(g, nq, block_h, block_q, block_k, d, with_dq: bool = True) -> int:
+    """The dk/dv kernel's scratch — dk/dv, on the one-pass path the whole
+    group's dqᵀ (G*Sq*D f32, lane-dense, whatever the head block) — and
+    the step's f32 values, lane-padded."""
+    kvb = kv_block(block_h, g)
+    dq = g * kvb * tile_bytes(d, nq * block_q, 4) if with_dq else 0
+    return dq + 2 * kvb * tile_bytes(block_k, d, 4) + _step_temps(block_h, block_q, block_k, d)
 
 
-def use_fused_dq(g, nq, block_q, block_k, d, itemsize) -> bool:
-    """One pass when its working set stays within half the VMEM budget."""
-    return (fused_vmem_bytes(g, nq, block_q, block_k, d, itemsize)
-            <= VMEM_BUDGET_BYTES["tpu"] // 2)
+def _step_temps(block_h, block_q, block_k, d) -> int:
+    return (block_h * (BWD_COLUMN_TEMPS * tile_bytes(block_q, 1, 4)
+                       + BWD_ROW_TEMPS * tile_bytes(block_q, d, 4))
+            + BWD_TILE_TEMPS * tile_bytes(block_q, block_k, 4))
+
+
+def _q_side_windows(block_h, block_q, d, itemsize, n_tiles):
+    """One buffer of the q-side windows: ``n_tiles`` (block_h, block_q, D)
+    tiles, the f32 lse/delta columns and the pos/seg columns."""
+    col = tile_bytes(block_q, 1, 4)
+    return block_h * (n_tiles * tile_bytes(block_q, d, itemsize) + 2 * col) + 2 * col
+
+
+def fused_vmem_bytes(g, nq, block_h, block_q, block_k, d, itemsize,
+                     with_dq: bool = True) -> int:
+    """The dk/dv kernel's VMEM working set, lane-padded as the TPU compiler
+    lays it out: double-buffered operand windows (q/do[/dq], k/v/dk/dv,
+    lse/delta, pos/seg) plus ``bwd_scratch_bytes``."""
+    kvb = kv_block(block_h, g)
+    windows = (_q_side_windows(block_h, block_q, d, itemsize, 2 + with_dq)
+               + 4 * kvb * tile_bytes(block_k, d, itemsize) + 2 * tile_bytes(1, block_k, 4))
+    return DOUBLE_BUFFER * windows + bwd_scratch_bytes(g, nq, block_h, block_q, block_k,
+                                                       d, with_dq)
+
+
+def dq_vmem_bytes(g, block_h, block_q, block_k, d, itemsize) -> int:
+    """The split path's dq kernel: its windows, the (block_h, block_q, D)
+    f32 dq scratch and the step's f32 values."""
+    kvb = kv_block(block_h, g)
+    windows = (_q_side_windows(block_h, block_q, d, itemsize, 3)
+               + 2 * kvb * tile_bytes(block_k, d, itemsize) + 2 * tile_bytes(1, block_k, 4))
+    return DOUBLE_BUFFER * windows + dq_scratch_bytes(block_h, block_q, block_k, d)
+
+
+def dq_scratch_bytes(block_h, block_q, block_k, d) -> int:
+    """The split path's (block_h, block_q, D) f32 dq scratch and the step's
+    f32 values."""
+    return block_h * tile_bytes(block_q, d, 4) + _step_temps(block_h, block_q, block_k, d)
+
+
+def use_fused_dq(g, nq, block_h, block_q, block_k, d, itemsize) -> bool:
+    """One pass when its working set stays within the VMEM budget."""
+    return (fused_vmem_bytes(g, nq, block_h, block_q, block_k, d, itemsize)
+            <= VMEM_BUDGET_BYTES["tpu"])
+
+
+def bwd_blocks(sq, skv, h, kvh, d, itemsize, **given) -> tuple:
+    """The backward's (block_h, block_q, block_k) for one call's shapes: the
+    largest step whose one-pass kernel fits, else (the dq scratch growing
+    with G*Sq) the largest whose split kernels both fit."""
+    g, budget = h // kvh, VMEM_BUDGET_BYTES["tpu"]
+
+    def one_pass(hb, bq, bk):
+        return use_fused_dq(g, -(-sq // bq), hb, bq, bk, d, itemsize)
+
+    def split(hb, bq, bk):
+        return max(fused_vmem_bytes(g, -(-sq // bq), hb, bq, bk, d, itemsize, False),
+                   dq_vmem_bytes(g, hb, bq, bk, d, itemsize)) <= budget
+
+    blocks = choose_blocks(sq, skv, h, kvh, one_pass, **given)
+    return blocks if one_pass(*blocks) else choose_blocks(sq, skv, h, kvh, split, **given)
 
 
 def flash_attention_bwd(
     q, k, v, lse, delta, do, q_pos, k_pos, q_seg, k_seg,
-    *, causal: bool, window: int, block_q: int, block_k: int, interpret: bool,
-    implicit: bool = False,
+    *, causal: bool, window: int, block_h: int, block_q: int, block_k: int,
+    interpret: bool, implicit: bool = False,
 ):
     """(dq, dk, dv): one pallas_call, or two where the backward splits.
 
@@ -330,55 +421,58 @@ def flash_attention_bwd(
     """
     check_bwd_shapes(q, k, v, lse, delta, do)
     b, h, sq, d = q.shape
-    fused = use_fused_dq(h // k.shape[1], -(-sq // block_q), block_q, block_k, d,
+    fused = use_fused_dq(h // k.shape[1], -(-sq // block_q), block_h, block_q, block_k, d,
                          q.dtype.itemsize)
     run = _one_pass_bwd if fused else _split_bwd
     return run(q, k, v, lse, delta, do, q_pos, k_pos, q_seg, k_seg, causal=causal,
-               window=window, block_q=block_q, block_k=block_k, interpret=interpret,
-               implicit=implicit)
+               window=window, block_q=block_q, block_k=block_k, block_h=block_h,
+               interpret=interpret, implicit=implicit)
 
 
-def _bwd_call(q, k, *, causal, window, block_q, block_k, implicit, with_dq):
+def _bwd_call(q, k, *, causal, window, block_q, block_k, block_h, implicit, with_dq):
     """Geometry and kernel keywords shared by both paths' dk/dv launch."""
     b, h, sq, d = q.shape
     kvh, skv = k.shape[1], k.shape[2]
     geom = bwd_geometry(b, sq, h, d, skv, kvh, block_q=block_q, block_k=block_k,
-                        with_dq=with_dq)
+                        with_dq=with_dq, block_h=block_h)
     kw = dict(causal=causal, window=window, block_q=block_q, block_k=block_k,
               scale=d**-0.5, seq_q=sq, seq_kv=skv, implicit=implicit)
     return geom, kw
 
 
-def _dkv_outputs(k, v, block_k):
+def _dkv_outputs(k, v, block_k, kvb):
     d = k.shape[3]
+    scratch = pltpu.VMEM((kvb, block_k, d), jnp.float32)
     return ([jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
-            [pltpu.VMEM((block_k, d), jnp.float32), pltpu.VMEM((block_k, d), jnp.float32)])
+            [scratch, scratch])
 
 
 def _one_pass_bwd(q, k, v, lse, delta, do, q_pos, k_pos, q_seg, k_seg,
-                  *, interpret, **static):
-    (grid, nq, nk, g, ins, outs, _), kw = _bwd_call(q, k, with_dq=True, **static)
-    out_shape, scratch = _dkv_outputs(k, v, static["block_k"])
+                  *, interpret, block_h=1, **static):
+    (grid, nq, nk, ng, ins, outs, _), kw = _bwd_call(q, k, with_dq=True, block_h=block_h,
+                                                     **static)
+    out_shape, scratch = _dkv_outputs(k, v, static["block_k"], ins["k"].block_shape[1])
     return pl.pallas_call(
-        functools.partial(_fused_bwd_kernel, nq=nq, nk=nk, g=g, with_dq=True, **kw),
+        functools.partial(_fused_bwd_kernel, nq=nq, nk=nk, ng=ng, with_dq=True, **kw),
         grid=grid,
         in_specs=list(ins.values()),
         out_specs=list(outs.values()),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), *out_shape],
-        scratch_shapes=[pltpu.VMEM((g * nq, static["block_q"], q.shape[3]), jnp.float32),
-                        *scratch],
+        scratch_shapes=[pltpu.VMEM((ng * nq, block_h, q.shape[3], static["block_q"]),
+                                   jnp.float32), *scratch],
         interpret=interpret,
     )(q, k, v, lse, delta, do, *pos_operands(q_pos, k_pos, q_seg, k_seg))
 
 
 def _split_bwd(q, k, v, lse, delta, do, q_pos, k_pos, q_seg, k_seg,
-               *, interpret, **static):
+               *, interpret, block_h=1, **static):
     """dk/dv from the one-pass kernel without dq, then the q-outer dq kernel."""
     operands = (q, k, v, lse, delta, do, *pos_operands(q_pos, k_pos, q_seg, k_seg))
-    (grid, nq, nk, g, ins, outs, _), kw = _bwd_call(q, k, with_dq=False, **static)
-    out_shape, scratch = _dkv_outputs(k, v, static["block_k"])
+    (grid, nq, nk, ng, ins, outs, _), kw = _bwd_call(q, k, with_dq=False, block_h=block_h,
+                                                     **static)
+    out_shape, scratch = _dkv_outputs(k, v, static["block_k"], ins["k"].block_shape[1])
     dk, dv = pl.pallas_call(
-        functools.partial(_fused_bwd_kernel, nq=nq, nk=nk, g=g, with_dq=False, **kw),
+        functools.partial(_fused_bwd_kernel, nq=nq, nk=nk, ng=ng, with_dq=False, **kw),
         grid=grid,
         in_specs=list(ins.values()),
         out_specs=list(outs.values()),
@@ -389,14 +483,14 @@ def _split_bwd(q, k, v, lse, delta, do, q_pos, k_pos, q_seg, k_seg,
     b, h, sq, d = q.shape
     grid, nq, nk, _, ins, outs, _ = dq_geometry(
         b, sq, h, d, k.shape[2], k.shape[1], block_q=static["block_q"],
-        block_k=static["block_k"])
+        block_k=static["block_k"], block_h=block_h)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nk=nk, **kw),
         grid=grid,
         in_specs=list(ins.values()),
         out_specs=outs["dq"],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((static["block_q"], d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_h, static["block_q"], d), jnp.float32)],
         interpret=interpret,
     )(*operands)
     return dq, dk, dv
@@ -448,24 +542,16 @@ def attention_bwd_ref(
 # ---------------------------------------------------------------------------
 
 CONFIGS = {
-    "representative": dict(B=2, S=512, H=8, KV=2, D=64),
-    "hostile_gqa_bf16": dict(B=1, S=130, H=4, KV=1, D=32, dtype="bfloat16"),
-    # granite-3-2b (GQA 32:8, D=64) at seq 4096: one pass, 4 MiB dq scratch
+    "representative": dict(B=2, S=2048, H=8, KV=2, D=64),
+    "hostile_gqa_bf16": dict(B=1, S=130, H=4, KV=1, D=32, dtype="bfloat16",
+                             block_h=2, block_q=64, block_k=128),
+    # granite-3-2b (GQA 32:8, D=64) at seq 4096: one pass, the kv group's
+    # 4 MiB dqᵀ scratch; BLOCKS_COMMENT
     "granite_3_2b_seq4096": dict(B=1, S=4096, H=32, KV=8, D=64, dtype="bfloat16"),
     # granite-20b (MQA 48:1, D=128) at seq 4096: a one-pass dq scratch of
     # 96 MiB, so the backward splits
     "granite_20b_seq4096": dict(B=1, S=4096, H=48, KV=1, D=128, dtype="bfloat16"),
 }
-
-
-def _blocks(S, block_q, block_k):
-    return min(block_q, S), min(block_k, S)
-
-
-def _fused_for(S, H, KV, D, dtype, bq, bk):
-    from repro.analysis.layout_contracts import itemsize
-
-    return use_fused_dq(H // KV, -(-S // bq), bq, bk, D, itemsize(dtype))
 
 
 def _operands(ins, outs, shapes, dtype, dq_window=None):
@@ -483,32 +569,40 @@ def _operands(ins, outs, shapes, dtype, dq_window=None):
     return ({n: op(n, s) for n, s in ins.items()}, {n: op(n, s) for n, s in outs.items()})
 
 
-def _analysis_geometry(B, S, H, KV, D, *, dtype="float32",
-                       block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, with_dq=None):
-    """The dk/dv (+ dq on the one-pass path) kernel; ``with_dq`` overrides
+def _analysis_geometry(B, S, H, KV, D, *, dtype="float32", block_h=None, block_q=None,
+                       block_k=None, with_dq=None):
+    """The dk/dv (+ dq on the one-pass path) kernel at the blocks
+    ``bwd_blocks`` chooses (each override as given); ``with_dq`` overrides
     the dispatch."""
+    from repro.analysis.layout_contracts import itemsize
     from repro.analysis.registry import Geometry
 
-    bq, bk = _blocks(S, block_q, block_k)
+    hb, bq, bk = bwd_blocks(S, S, H, KV, D, itemsize(dtype),
+                            block_h=block_h, block_q=block_q, block_k=block_k)
+    g, nq = H // KV, -(-S // bq)
     if with_dq is None:
-        with_dq = _fused_for(S, H, KV, D, dtype, bq, bk)
-    grid, nq, nk, g, ins, outs, shapes = bwd_geometry(B, S, H, D, S, KV, block_q=bq,
-                                                      block_k=bk, with_dq=with_dq)
+        with_dq = use_fused_dq(g, nq, hb, bq, bk, D, itemsize(dtype))
+    grid, nq, nk, _, ins, outs, shapes = bwd_geometry(
+        B, S, H, D, S, KV, block_q=bq, block_k=bk, with_dq=with_dq, block_h=hb)
     ins, outs = _operands(ins, outs, shapes, dtype, dq_window=(nk - 1, nk - 1))
-    dq_scratch = g * nq * bq if with_dq else 0
     return Geometry(grid=grid, ins=ins, outs=outs,
-                    scratch_bytes=(dq_scratch + 2 * bk) * D * 4, phase_axis=2)
+                    scratch_bytes=bwd_scratch_bytes(g, nq, hb, bq, bk, D, with_dq),
+                    phase_axis=2)
 
 
-def _analysis_dq_geometry(B, S, H, KV, D, *, dtype="float32",
-                          block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+def _analysis_dq_geometry(B, S, H, KV, D, *, dtype="float32", block_h=None,
+                          block_q=None, block_k=None):
     """The split path's dq kernel."""
+    from repro.analysis.layout_contracts import itemsize
     from repro.analysis.registry import Geometry
 
-    bq, bk = _blocks(S, block_q, block_k)
-    grid, _, _, _, ins, outs, shapes = dq_geometry(B, S, H, D, S, KV, block_q=bq, block_k=bk)
+    hb, bq, bk = bwd_blocks(S, S, H, KV, D, itemsize(dtype),
+                            block_h=block_h, block_q=block_q, block_k=block_k)
+    grid, _, _, _, ins, outs, shapes = dq_geometry(B, S, H, D, S, KV, block_q=bq,
+                                                   block_k=bk, block_h=hb)
     ins, outs = _operands(ins, outs, shapes, dtype)
-    return Geometry(grid=grid, ins=ins, outs=outs, scratch_bytes=bq * D * 4)
+    return Geometry(grid=grid, ins=ins, outs=outs,
+                    scratch_bytes=dq_scratch_bytes(hb, bq, bk, D))
 
 
 def _register():

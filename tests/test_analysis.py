@@ -207,29 +207,54 @@ def test_mutation_one_pass_backward_at_granite_20b_overflows_vmem():
     assert _rules_of(found) == {"VMEM-BUDGET"}
 
 
+def _rule_count(geom):
+    """The VMEM-BUDGET rule's count: double-buffered block windows + scratch."""
+    from repro.analysis.layout_contracts import DOUBLE_BUFFER, itemsize
+    from repro.analysis.replay import _blk_bytes
+
+    return DOUBLE_BUFFER * sum(_blk_bytes(op.spec, itemsize(op.dtype))
+                               for _, op, _ in geom.operands()) + geom.scratch_bytes
+
+
+def _given_blocks(cfg):
+    return {k: cfg.get(k) for k in ("block_h", "block_q", "block_k")}
+
+
 @pytest.mark.parametrize("config", ["representative", "hostile_gqa_bf16",
                                     "granite_3_2b_seq4096", "granite_20b_seq4096"])
 def test_backward_dispatch_follows_the_vmem_rule(config):
-    # the dispatcher's working-set estimate is the VMEM-BUDGET rule's count
-    # of the one-pass geometry, and it splits exactly where that count
-    # passes half the budget
-    from repro.analysis.layout_contracts import (DOUBLE_BUFFER, VMEM_BUDGET_BYTES,
-                                                 itemsize)
-    from repro.analysis.replay import _blk_bytes
+    # the dispatcher's working-set estimate bounds the VMEM-BUDGET rule's
+    # count of the one-pass geometry (the estimate lane-pads the operand
+    # windows the rule counts at their block shapes), and it splits exactly
+    # where that estimate passes the budget
+    from repro.analysis.layout_contracts import VMEM_BUDGET_BYTES, itemsize
     from repro.kernels import flash_attention_bwd as fab
 
     cfg = fab.CONFIGS[config]
     geom = all_kernels()["flash_attention_bwd"].build(**cfg, with_dq=True)
-    counted = DOUBLE_BUFFER * sum(_blk_bytes(op.spec, itemsize(op.dtype))
-                                  for _, op, _ in geom.operands()) + geom.scratch_bytes
-    s, d, dtype = cfg["S"], cfg["D"], cfg.get("dtype", "float32")
-    bq = min(128, s)
-    estimate = fab.fused_vmem_bytes(cfg["H"] // cfg["KV"], -(-s // bq), bq, bq, d,
-                                    itemsize(dtype))
-    assert estimate == counted
-    fused = fab.use_fused_dq(cfg["H"] // cfg["KV"], -(-s // bq), bq, bq, d, itemsize(dtype))
-    assert fused == (counted <= VMEM_BUDGET_BYTES["tpu"] // 2)
+    s, d, g, size = cfg["S"], cfg["D"], cfg["H"] // cfg["KV"], itemsize(cfg.get("dtype", "float32"))
+    hb, bq, bk = fab.bwd_blocks(s, s, cfg["H"], cfg["KV"], d, size, **_given_blocks(cfg))
+    estimate = fab.fused_vmem_bytes(g, -(-s // bq), hb, bq, bk, d, size)
+    assert _rule_count(geom) <= estimate
+    fused = fab.use_fused_dq(g, -(-s // bq), hb, bq, bk, d, size)
+    assert fused == (estimate <= VMEM_BUDGET_BYTES["tpu"])
     assert fused == (config != "granite_20b_seq4096")
+
+
+@pytest.mark.parametrize("config", ["representative", "hostile_packed_bf16",
+                                    "granite_3_2b_seq4096", "bert_large_seq128"])
+def test_forward_blocks_fit_the_vmem_rule(config):
+    # the forward's chosen blocks fit its working-set estimate, which bounds
+    # the VMEM-BUDGET rule's count of the same geometry
+    from repro.analysis.layout_contracts import VMEM_BUDGET_BYTES, itemsize
+    from repro.kernels import flash_attention as fa
+
+    ks = all_kernels()["flash_attention_fwd"]
+    cfg = ks.configs[config]
+    s, d, g, size = cfg["S"], cfg["D"], cfg["H"] // cfg["KV"], itemsize(cfg.get("dtype", "float32"))
+    hb, bq, bk = fa.fwd_blocks(s, s, cfg["H"], cfg["KV"], d, size, **_given_blocks(cfg))
+    estimate = fa.fwd_vmem_bytes(hb, fa.kv_block(hb, g), bq, bk, d, size)
+    assert _rule_count(ks.build(**cfg)) <= estimate <= VMEM_BUDGET_BYTES["tpu"]
 
 
 def test_mutation_missing_oracle_is_caught():

@@ -108,13 +108,124 @@ def test_flash_attention_sweep(case, dtype):
     )
 
 
-def test_flash_attention_block_size_invariance():
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 200, 4, 32))
-    k = jax.random.normal(jax.random.PRNGKey(1), (1, 200, 2, 32))
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, 200, 2, 32))
-    o1 = flash_attention(q, k, v, block_q=64, block_k=64)
-    o2 = flash_attention(q, k, v, block_q=128, block_k=32)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
+def _docs_positions(b, s, docs):
+    """(B, S) int32 packed positions: per-document aranges, -1 padding."""
+    row = np.full(s, -1, np.int32)
+    i = 0
+    for n in docs:
+        row[i:i + n] = np.arange(n)
+        i += n
+    return jnp.asarray(np.tile(row, (b, 1)))
+
+
+ONE_HEAD = dict(block_h=1, block_q=32, block_k=32)
+
+# name: ((B, Sq, Skv, H, KV, D), causal, positions, geometry a, geometry b).
+# positions: None (implicit arange), a tuple of document lengths (packed
+# self-attention), or "cross" (Sq != Skv, explicit aranges, zero segments).
+# A geometry of {} is the one the kernels choose from the shapes.
+GEOMETRY_CASES = {
+    "gqa_s200_tiles": ((1, 200, 200, 4, 2, 32), True, None,
+                       dict(block_q=64, block_k=64), dict(block_q=128, block_k=32)),
+    "mha_heads_per_step": ((2, 128, 128, 8, 8, 32), False, (70, 58), {}, ONE_HEAD),
+    "gqa_docs_cross_tiles": ((1, 1024, 1024, 8, 2, 32), True, (300, 400, 250), {},
+                             dict(block_h=1, block_q=128, block_k=128)),
+    "gqa_part_of_group": ((1, 256, 256, 8, 2, 32), True, (100, 90, 50),
+                          dict(block_h=2, block_q=64, block_k=128), ONE_HEAD),
+    "partial_edges_s130": ((1, 130, 130, 4, 2, 32), True, None,
+                           dict(block_q=128, block_k=128), dict(ONE_HEAD, block_q=64)),
+    "partial_edges_s200": ((1, 200, 200, 4, 2, 32), True, (120, 80), {},
+                           dict(block_h=1, block_q=64, block_k=48)),
+    "mqa_split_backward": ((1, 136, 136, 6, 1, 16), True, (60, 76), {}, ONE_HEAD),
+    "cross_attention": ((2, 96, 160, 4, 2, 32), False, "cross", {},
+                        dict(block_h=1, block_q=32, block_k=64)),
+}
+
+
+@pytest.mark.parametrize("name", list(GEOMETRY_CASES))
+def test_flash_attention_block_size_invariance(name, monkeypatch):
+    """Forward output and (dq, dk, dv) do not depend on the grid geometry:
+    a block of heads and shape-sized tiles against one head per step and
+    small tiles, to float32 rounding."""
+    from repro.analysis.launch_manifest import _count
+    from repro.kernels import flash_attention_bwd as fab
+
+    (b, sq, skv, h, kvh, d), causal, layout, geo_a, geo_b = GEOMETRY_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(sum(map(ord, name))), 4)
+    q = jax.random.normal(ks[0], (b, sq, h, d))
+    k = jax.random.normal(ks[1], (b, skv, kvh, d))
+    v = jax.random.normal(ks[2], (b, skv, kvh, d))
+    t = jax.random.normal(ks[3], (b, sq, h, d))
+    if layout is None:
+        pos = {}
+    elif layout == "cross":
+        pos = dict(q_pos=jnp.broadcast_to(jnp.arange(sq, dtype=jnp.int32), (b, sq)),
+                   k_pos=jnp.broadcast_to(jnp.arange(skv, dtype=jnp.int32), (b, skv)),
+                   q_seg=jnp.zeros((b, sq), jnp.int32), k_seg=jnp.zeros((b, skv), jnp.int32))
+    else:
+        p = _docs_positions(b, sq, layout)
+        pos = dict(q_pos=p, k_pos=p)
+    if name == "mqa_split_backward":  # as where the group's dq does not fit VMEM
+        monkeypatch.setattr(fab, "use_fused_dq", lambda *a: False)
+
+    def run(geo):
+        attn = lambda q_, k_, v_: flash_attention(q_, k_, v_, **pos, causal=causal, **geo)
+        grads = jax.grad(lambda *a: jnp.sum(attn(*a) * t), argnums=(0, 1, 2))
+        return attn(q, k, v), grads(q, k, v), _count(grads, q, k, v)
+
+    out_a, grads_a, launches = run(geo_a)
+    out_b, grads_b, _ = run(geo_b)
+    assert launches == (3 if name == "mqa_split_backward" else 2)
+    np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b), atol=1e-5, rtol=1e-5)
+    for a, r in zip(grads_a, grads_b):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=1e-5, rtol=1e-5)
+
+
+# (B, S, H, KV, D) of the benchmark cells' attention calls
+GRANITE_3_2B = (1, 4096, 32, 8, 64)
+BERT_LARGE = (8, 128, 16, 16, 64)
+GRANITE_20B = (1, 4096, 48, 1, 128)
+
+
+def _chosen(shape, itemsize=2):
+    """(forward blocks, grid steps), (backward blocks, grid steps, one pass)
+    at the blocks the kernels choose for ``shape``."""
+    from math import prod
+
+    from repro.kernels import flash_attention_bwd as fab
+    from repro.kernels.flash_attention import fwd_blocks, fwd_geometry
+
+    b, s, h, kvh, d = shape
+    fb = fwd_blocks(s, s, h, kvh, d, itemsize)
+    fwd = fwd_geometry(b, s, h, d, s, kvh, block_q=fb[1], block_k=fb[2], with_lse=True,
+                       block_h=fb[0])[0]
+    hb, bq, bk = bb = fab.bwd_blocks(s, s, h, kvh, d, itemsize)
+    one_pass = fab.use_fused_dq(h // kvh, -(-s // bq), hb, bq, bk, d, itemsize)
+    bwd = fab.bwd_geometry(b, s, h, d, s, kvh, block_q=bq, block_k=bk, block_h=hb,
+                           with_dq=one_pass)[0]
+    return (fb, prod(fwd)), (bb, prod(bwd), one_pass)
+
+
+def test_shape_chosen_geometry_at_the_cells_shapes():
+    """The cells' calls take few, large grid steps: granite-3-2b's forward
+    and backward at most 1,024 steps a call (32,768 at one head x 128 x
+    128), each a whole kv group of 4 heads at least; bert-large's forward
+    at most 16; granite-3-2b's backward keeps the one-pass kernel and
+    granite-20b's (MQA 48:1) splits."""
+    (fb, fwd), (bb, bwd, one_pass) = _chosen(GRANITE_3_2B)
+    assert fwd <= 1024 and bwd <= 1024 and one_pass
+    assert fb[0] % 4 == 0 and bb[0] % 4 == 0
+    assert _chosen(BERT_LARGE)[0][1] <= 16
+    assert not _chosen(GRANITE_20B)[1][2]
+
+
+@pytest.mark.parametrize("block_h", [3, 8])
+def test_a_head_block_must_fit_the_heads(block_h):
+    # 4 query heads over 2 kv heads: 3 splits a group, 8 is more than H
+    q = jnp.zeros((1, 16, 4, 8))
+    k = jnp.zeros((1, 16, 2, 8))
+    with pytest.raises(ValueError, match="block_h"):
+        flash_attention(q, k, k, block_h=block_h)
 
 
 def _paged_cache_case(key, b, c, lanes, kvh, d, n_fill):
