@@ -1,8 +1,11 @@
 """The system under test, as the benchmark drives it: the program's config
 objects built from a configuration file and a traffic file, its parameter
 tree filled with the benchmark's weights, its jitted ``make_train_step``,
-its token cache and its packed dataset.  Everything this module touches of
-the program is its public training API under ``src/repro``.
+its token cache and its packed dataset.  A cell on several chips runs the
+program's data-parallel mesh, with its sharded state and batch feed.
+Everything this module touches of the program is its public training API
+under ``src/repro``; what depends on the model's shape is the family's
+(``families/<family>.py``).
 """
 from __future__ import annotations
 
@@ -14,37 +17,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.chip import traffic as traffic_mod
-from benchmarks.chip.weights import dims, leaf_norms
-
-# What the program's model computes and no configuration can change: its
-# norms' epsilon (repro.models.common.apply_norm), RoPE and no token types,
-# no scalar multipliers; attention scaled by 1/sqrt(head_dim).  A
-# configuration file that states another value cannot be run as stated.
-PROGRAM_FIXED = {"norm_eps": 1e-6, "position_embedding_type": "rope", "type_vocab_size": 0,
-                 "embedding_multiplier": 1.0, "residual_multiplier": 1.0, "logits_scaling": 1.0}
+from benchmarks.chip.spec import family
+from benchmarks.chip.weights import leaf_norms
 
 
 def model_config(conf: Dict):
-    from repro.configs import ModelConfig
-
-    n = dims(conf)
-    fixed = dict(PROGRAM_FIXED, attention_multiplier=n["hd"] ** -0.5)
-    for key, value in fixed.items():
-        if key in conf and conf[key] != value:
-            raise ValueError(f"{key} {conf[key]!r} cannot be run: the program computes {value!r}")
-    act = {("gated", "silu"): "swiglu", ("dense", "gelu_tanh"): "gelu"}.get(
-        (conf["mlp"], conf["hidden_act"]))
-    if act is None:
-        raise ValueError(f"no program activation for mlp {conf['mlp']!r} with "
-                         f"{conf['hidden_act']!r}")
-    if n["L"] < 2:
-        raise ValueError("the program stacks layer tensors only from two layers up")
-    return ModelConfig(
-        name=conf.get("name", "chipbench"), family="dense", n_layers=n["L"], d_model=n["D"], n_heads=n["H"],
-        n_kv_heads=n["KV"], d_ff=n["F"], vocab_size=n["V"], head_dim=n["hd"],
-        block_pattern=("attn",), rope_theta=float(conf["rope_theta"]), norm=conf["norm"],
-        act=act, causal=bool(conf["causal"]), tie_embeddings=bool(conf["tie_word_embeddings"]),
-    )
+    """The program's ``ModelConfig`` of a configuration (its family's)."""
+    return family(conf).model_config(conf)
 
 
 def train_config(conf: Dict, traffic: Dict):
@@ -60,29 +39,13 @@ def train_config(conf: Dict, traffic: Dict):
     )
 
 
-def _norm_tree(bp, prefix):
-    out = {"scale": bp[f"{prefix}_scale" if prefix != "final" else "final.scale"]}
-    bias = f"{prefix}_bias" if prefix != "final" else "final.bias"
-    if bias in bp:
-        out["bias"] = bp[bias]
-    return out
-
-
-def to_program(bp: Dict, cfg) -> Dict:
-    """The benchmark's flat weights as the program's parameter tree (the same
-    arrays, no copies); its structure and shapes must equal the program's
-    own ``init_params``."""
+def to_program(bp: Dict, cfg, conf: Dict) -> Dict:
+    """The benchmark's flat weights as the program's parameter tree (the
+    family's mapping: the same arrays, no copies); its structure and shapes
+    must equal the program's own ``init_params``."""
     from repro.models import init_params
 
-    layer = {
-        "ln1": _norm_tree(bp, "layers.ln1"), "ln2": _norm_tree(bp, "layers.ln2"),
-        "attn": {w: bp[f"layers.{w}"] for w in ("wq", "wk", "wv", "wo")},
-        "mlp": {w: bp[f"layers.{w}"] for w in ("wi", "wg", "wd") if f"layers.{w}" in bp},
-    }
-    tree = {"embed": {"embed": bp["embed"]}, "groups": {"pos0": layer}, "tail": [],
-            "final_norm": _norm_tree(bp, "final")}
-    if "head" in bp:
-        tree["head"] = bp["head"]
+    tree = family(conf).to_program(bp, cfg)
     want = jax.eval_shape(lambda: init_params(cfg.model, jax.random.PRNGKey(0),
                                               scan_layers=cfg.parallel.scan_layers))
     got = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
@@ -94,43 +57,76 @@ def to_program(bp: Dict, cfg) -> Dict:
     return tree
 
 
-def from_program(tree: Dict) -> Dict:
+def from_program(tree: Dict, conf: Dict) -> Dict:
     """Inverse of ``to_program``."""
-    layer = tree["groups"]["pos0"]
-    bp = {"embed": tree["embed"]["embed"], "final.scale": tree["final_norm"]["scale"]}
-    if "bias" in tree["final_norm"]:
-        bp["final.bias"] = tree["final_norm"]["bias"]
-    for ln in ("ln1", "ln2"):
-        for part, x in layer[ln].items():
-            bp[f"layers.{ln}_{part}"] = x
-    for group in ("attn", "mlp"):
-        for w, x in layer[group].items():
-            bp[f"layers.{w}"] = x
-    if "head" in tree:
-        bp["head"] = tree["head"]
-    return bp
+    return family(conf).from_program(tree)
 
 
-def init_state(cfg, bp: Dict):
+def init_state(cfg, bp: Dict, conf: Dict, mesh=None):
+    """The program's train state from the benchmark's weights; on a mesh,
+    placed as the program shards it (FSDP rows of the flat state)."""
     from repro.train import init_state as program_init_state
 
-    return program_init_state(cfg, params=to_program(bp, cfg))
+    state = program_init_state(cfg, params=to_program(bp, cfg, conf))
+    if mesh is None:
+        return state
+    from repro.sharding import activate, param_shardings
+
+    with activate(mesh) as rules:
+        return jax.device_put(state, param_shardings(state, rules))
 
 
-def make_step(cfg, wrap: Optional[Callable] = None):
+def make_mesh(chips: int):
+    """The program's data-parallel mesh over the first ``chips`` devices;
+    None for one chip, which runs with no mesh."""
+    if chips == 1:
+        return None
+    from repro.launch.mesh import make_mesh as program_mesh
+
+    return program_mesh((chips,), ("data",))
+
+
+def make_step(cfg, wrap: Optional[Callable] = None, mesh=None, state=None):
     """The timed call: the jitted fresh-stats train step, state donated.
-    ``wrap`` plants a fault in it (tests only)."""
+    On a mesh the step takes the placed ``state`` and returns the next one
+    placed alike.  ``wrap`` plants a fault in it (tests only)."""
     from repro.train import make_train_step
 
-    step_fn, _ = make_train_step(cfg)
+    step_fn, _ = make_train_step(cfg, mesh=mesh)
 
     def step(state, batch):
         return step_fn(state, batch, True)
 
-    return jax.jit(wrap(step) if wrap else step, donate_argnums=0)
+    fn = wrap(step) if wrap else step
+    if mesh is None:
+        return jax.jit(fn, donate_argnums=0)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    out = (jax.tree_util.tree_map(lambda x: x.sharding, state), NamedSharding(mesh, P()))
+    return jax.jit(fn, donate_argnums=0, out_shardings=out)
 
 
-def first_step_readings(cfg):
+def feed(ds, mesh=None):
+    """The program's prefetching batch feed, two batches ahead: placed on
+    the device, or on a mesh split by rows over its data axis, inside the
+    producer thread."""
+    if mesh is None:
+        return ds.iter_batches(device=True, prefetch_size=2)
+    from repro.data.pipeline import device_prefetch
+
+    return device_prefetch(ds.iter_batches(), size=2, mesh=mesh)
+
+
+def replicate(tree, mesh):
+    """``tree`` on every device of ``mesh`` (as is without one)."""
+    if mesh is None:
+        return tree
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.device_put(tree, NamedSharding(mesh, P()))
+
+
+def first_step_readings(cfg, conf: Dict):
     """jitted state -> per-tensor norms of the first step's gradient g as
     the optimizer got it (``grad``) and of its GSNR r (``gsnr``), worked out
     from the optimizer's state after it: then the GSNR momentum is
@@ -138,7 +134,7 @@ def first_step_readings(cfg):
     b1, b3 = float(cfg.optimizer.b1), float(cfg.optimizer.b3)
 
     def tree(x):
-        return from_program(x.unpack() if hasattr(x, "unpack") else x)
+        return from_program(x.unpack() if hasattr(x, "unpack") else x, conf)
 
     def run(state):
         m, p = tree(state.opt_state["m"]), tree(state.opt_state["p"])
@@ -148,9 +144,9 @@ def first_step_readings(cfg):
     return jax.jit(run)
 
 
-def change_norms(params_tree, bp0: Dict):
+def change_norms(params_tree, bp0: Dict, conf: Dict):
     return jax.jit(lambda t, b: leaf_norms(
-        {k: v - b[k] for k, v in from_program(t).items()}))(params_tree, bp0)
+        {k: v - b[k] for k, v in from_program(t, conf).items()}))(params_tree, bp0)
 
 
 def write_corpus(traffic: Dict, vocab: int, seed: int, cache_dir) -> None:
@@ -185,10 +181,17 @@ def piece_lengths(ds, lo: int, hi: int) -> np.ndarray:
     return np.concatenate(out) if out else np.zeros(0, np.int32)
 
 
-def batch_shapes(traffic: Dict) -> Dict:
-    """The batch the dataset serves (``repro.data.pack_index.gather_rows``)."""
+def batch_shapes(traffic: Dict, mesh=None) -> Dict:
+    """The batch the dataset serves (``repro.data.pack_index.gather_rows``),
+    on a mesh split by rows as ``feed`` places it."""
     shape = (int(traffic["rows"]), int(traffic["seq_len"]))
-    return {k: jax.ShapeDtypeStruct(shape, jnp.float32 if k == "mask" else jnp.int32)
+    sharding = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        sharding = NamedSharding(mesh, P("data", None))
+    return {k: jax.ShapeDtypeStruct(shape, jnp.float32 if k == "mask" else jnp.int32,
+                                    sharding=sharding)
             for k in ("tokens", "targets", "positions", "segments", "mask")}
 
 

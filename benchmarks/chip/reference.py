@@ -5,8 +5,10 @@ precision.  It imports nothing of the program.
 What it computes, for a configuration file (``configs/*.json``) and a
 traffic file (``traffic/*.json``):
 
-  model    token embedding (tied head or a separate one); per layer a pre-norm
-           block x + attn(norm(x)), then x + mlp(norm(x)); RoPE on q and k by
+  model    the loss of the configuration's family (``families/<family>.py``)
+           built from the blocks here; for the dense family: token embedding
+           (tied head or a separate one); per layer a pre-norm block
+           x + attn(norm(x)), then x + mlp(norm(x)); RoPE on q and k by
            each token's position within its document; attention only between
            live tokens (position >= 0) of the same document, causal where the
            configuration says so, GQA by repeating each kv head over its group
@@ -40,7 +42,8 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 
-from benchmarks.chip.weights import dims, leaf_norms, make_params, per_layer
+from benchmarks.chip.spec import family
+from benchmarks.chip.weights import leaf_norms, make_params, per_layer
 
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
@@ -81,11 +84,13 @@ def _mm_fp8(spec):
     return f
 
 
-def _mm(spec, a, b, quant):
+def mm(spec, a, b, quant):
+    """``einsum(spec, a, b)`` in float32 at the highest precision; with
+    ``quant`` its operands, and its cotangent backward, rounded to float8."""
     return _mm_fp8(spec)(a, b) if quant else _einsum(spec, a, b)
 
 
-def _norm(x, scale, bias, kind, eps):
+def norm(x, scale, bias, kind, eps):
     if kind == "layernorm":
         mu = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
@@ -93,7 +98,7 @@ def _norm(x, scale, bias, kind, eps):
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = pos[..., None].astype(jnp.float32) * freqs
@@ -102,7 +107,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def _attention(q, k, v, pos, seg, causal, quant):
+def attention(q, k, v, pos, seg, causal, quant):
     """q (B,S,H,hd), k/v (B,S,KV,hd) -> (B,S,H,hd), over blocks of q rows."""
     b, s, h, hd = q.shape
     g = h // k.shape[2]
@@ -117,7 +122,7 @@ def _attention(q, k, v, pos, seg, causal, quant):
     @jax.checkpoint
     def block(args):
         qi, qp, qs = args  # (B,blk,H,hd), (B,blk), (B,blk)
-        sc = _mm("bqhd,bkhd->bhqk", qi, k, quant) / math.sqrt(hd)
+        sc = mm("bqhd,bkhd->bhqk", qi, k, quant) / math.sqrt(hd)
         ok = (qp[:, :, None] >= 0) & (pos[:, None, :] >= 0) & (qs[:, :, None] == seg[:, None, :])
         if causal:
             ok &= pos[:, None, :] <= qp[:, :, None]
@@ -125,52 +130,20 @@ def _attention(q, k, v, pos, seg, causal, quant):
         sc = jnp.where(ok, sc, -1e30)
         e = jnp.where(ok, jnp.exp(sc - jnp.max(sc, axis=-1, keepdims=True)), 0.0)
         p = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
-        return _mm("bhqk,bkhd->bqhd", p, v, quant)
+        return mm("bhqk,bkhd->bqhd", p, v, quant)
 
     out = jax.lax.map(block, (jnp.moveaxis(qb, 1, 0), jnp.moveaxis(qpos, 1, 0),
                               jnp.moveaxis(qseg, 1, 0)))
     return jnp.moveaxis(out, 0, 1).reshape(b, nb * blk, h, hd)[:, :s]
 
 
-def loss(conf: Dict, params: Dict, mb: Dict, quant: bool = False):
-    """Mean cross-entropy over the live tokens of one microbatch."""
-    n = dims(conf)
-    kind, eps = conf["norm"], float(conf["norm_eps"])
-    pos, seg = mb["positions"], mb["segments"]
-    b, s = pos.shape
-    x = params["embed"][mb["tokens"]]
-    layer_names = sorted(k for k in params if k.startswith("layers."))
-    stacked = {k[len("layers."):]: params[k] for k in layer_names}
-
-    def zero_bias(name):
-        return stacked.get(name, jnp.zeros_like(stacked["ln1_scale"]))
-
-    stacked.setdefault("ln1_bias", zero_bias("ln1_bias"))
-    stacked.setdefault("ln2_bias", zero_bias("ln2_bias"))
-
-    @jax.checkpoint
-    def layer(x, p):
-        h = _norm(x, p["ln1_scale"], p["ln1_bias"], kind, eps)
-        q = _mm("bsd,de->bse", h, p["wq"], quant).reshape(b, s, n["H"], n["hd"])
-        k = _mm("bsd,de->bse", h, p["wk"], quant).reshape(b, s, n["KV"], n["hd"])
-        v = _mm("bsd,de->bse", h, p["wv"], quant).reshape(b, s, n["KV"], n["hd"])
-        theta = float(conf["rope_theta"])
-        q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-        a = _attention(q, k, v, pos, seg, bool(conf["causal"]), quant)
-        x = x + _mm("bse,ed->bsd", a.reshape(b, s, -1), p["wo"], quant)
-        h = _norm(x, p["ln2_scale"], p["ln2_bias"], kind, eps)
-        up = _mm("bsd,df->bsf", h, p["wi"], quant)
-        if conf["mlp"] == "gated":
-            f = jax.nn.silu(_mm("bsd,df->bsf", h, p["wg"], quant)) * up
-        else:
-            f = jax.nn.gelu(up, approximate=True)
-        return x + _mm("bsf,fd->bsd", f, p["wd"], quant), None
-
-    x, _ = jax.lax.scan(layer, x, stacked)
-    x = _norm(x, params["final.scale"], params.get("final.bias", 0.0), kind, eps)
-    head = params["embed"].T if conf["tie_word_embeddings"] else params["head"]
+def mean_nll(x, head, targets, mask, quant: bool):
+    """Mean cross-entropy of the final hidden states ``x`` (B,S,D) through
+    the vocabulary projection ``head`` (D,V) over the tokens ``mask`` keeps,
+    the projection and its loss over blocks of tokens."""
+    b, s = targets.shape
     flat = x.reshape(b * s, -1)
-    tgt, mask = mb["targets"].reshape(-1), mb["mask"].reshape(-1).astype(jnp.float32)
+    tgt, mask = targets.reshape(-1), mask.reshape(-1).astype(jnp.float32)
     blk = min(TOKEN_BLOCK, b * s)
     nb = -(-(b * s) // blk)
     pad = nb * blk - b * s
@@ -180,13 +153,19 @@ def loss(conf: Dict, params: Dict, mb: Dict, quant: bool = False):
 
     @jax.checkpoint
     def nll_sum(xs, t, m):
-        logits = _mm("td,dv->tv", xs, head, quant)
+        logits = mm("td,dv->tv", xs, head, quant)
         lz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
         return jnp.sum((lz - gold) * m)
 
     total = jnp.sum(jax.lax.map(lambda a: nll_sum(*a), (flat, tgt, mask)))
     return total / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def loss(conf: Dict, params: Dict, mb: Dict, quant: bool = False):
+    """Mean cross-entropy over the live tokens of one microbatch: the
+    configuration's family computes it (``families/<family>.py``)."""
+    return family(conf).loss(conf, params, mb, quant)
 
 
 @functools.lru_cache(maxsize=None)

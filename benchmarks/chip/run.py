@@ -6,7 +6,10 @@ Set-up makes the cell's corpus from the seed and writes it through the
 program's token cache, makes the weights on the device from the seed, builds
 the program's jitted ``make_train_step`` with its state, compiles it and
 drives it through the first steps that the reference checks, on batches from
-``IndexedPackedDataset.iter_batches(device=True, prefetch_size=2)``.  The
+``IndexedPackedDataset.iter_batches(device=True, prefetch_size=2)``.  A cell
+on several chips builds the program's data mesh over them, shards the state
+as the program does and feeds batches split by rows from the program's
+``device_prefetch`` (``program.py``).  The
 same compiled step, state and batch feed then run the measured window: steps
 are dispatched back to back, at most ``IN_FLIGHT`` of them ahead of the
 device, with no device value read until the last step has finished.  With
@@ -70,7 +73,9 @@ class RunInfo:
     trace: object = None  # xplane.Trace, --trace 1
     traced_steps: int = 0
     traced_tokens: int = 0
-    traced_pairs: int = 0
+    traced_pairs: object = 0  # what the family's attention counts take (work.pairs)
+    traced_pieces: object = None  # trained lengths of the traced document pieces
+    op_scopes: Optional[Dict[str, str]] = None  # the traced step's op_names (scopes.op_scopes)
 
 
 @dataclasses.dataclass
@@ -81,6 +86,7 @@ class Setup:
     compiled: object
     cache_dir: pathlib.Path
     phases: Dict[str, float]  # seconds of each part of set-up
+    mesh: object = None  # the program's mesh of a cell on several chips
 
 
 def chips_for(chips: int, require_chip: bool):
@@ -118,6 +124,7 @@ def prepare(cell, seed: int, wrap_step: Optional[Callable] = None, compiled=None
         phases[name], t = now - t, now
 
     cfg = program.train_config(conf, traffic)
+    mesh = program.make_mesh(cell.chips)
     cache_dir = cell.bench_dir / ".corpus" / cell.name
     program.write_corpus(traffic, int(conf["vocab_size"]), seed, cache_dir)
     lap("corpus")
@@ -125,14 +132,14 @@ def prepare(cell, seed: int, wrap_step: Optional[Callable] = None, compiled=None
     for epoch in range(2):  # both epochs' pack indices are built here, never in a window
         ds.pack_for(epoch)
     lap("pack_index")
-    state = program.init_state(cfg, weights.make_params(conf, seed))
-    it = ds.iter_batches(device=True, prefetch_size=2)
+    state = program.init_state(cfg, weights.make_params(conf, seed), conf, mesh)
+    it = program.feed(ds, mesh)
     lap("weights_state")
     if compiled is None:
-        compiled = program.make_step(cfg, wrap_step).lower(
-            state, program.batch_shapes(traffic)).compile()
+        compiled = program.make_step(cfg, wrap_step, mesh, state).lower(
+            state, program.batch_shapes(traffic, mesh)).compile()
     lap("compile")
-    return Setup(cfg, it, state, compiled, cache_dir, phases)
+    return Setup(cfg, it, state, compiled, cache_dir, phases, mesh)
 
 
 def checked_steps(su: Setup, conf: Dict, seed: int, n: int) -> Dict:
@@ -147,9 +154,9 @@ def checked_steps(su: Setup, conf: Dict, seed: int, n: int) -> Dict:
         su.state, m = su.compiled(su.state, next(su.it))
         losses.append(m["loss"])
         if i == 0:
-            first = program.first_step_readings(su.cfg)(su.state)
-    bp0 = weights.make_params(conf, seed)
-    change = program.change_norms(su.state.params, bp0)
+            first = program.first_step_readings(su.cfg, conf)(su.state)
+    bp0 = program.replicate(weights.make_params(conf, seed), su.mesh)
+    change = program.change_norms(su.state.params, bp0, conf)
     del bp0
     jax.block_until_ready((su.state, change))
     return {"loss": losses, **first, "change": change}
@@ -222,7 +229,7 @@ def run_cell(root, name: str, seed: int, seconds: float, trace: bool, *,
     ``require_chip=False`` skips the look for a chip (tests on the CPU only)."""
     import jax
 
-    from benchmarks.chip import check, program, reference, spec, work
+    from benchmarks.chip import check, program, reference, scopes, spec, work
     from benchmarks.chip import xplane
     from benchmarks.chip.peaks import peaks
 
@@ -245,6 +252,7 @@ def run_cell(root, name: str, seed: int, seconds: float, trace: bool, *,
                    itemsize=program.compute_itemsize(conf), setup_s=setup_s)
     if trace:
         info.traced_steps, info.trace = _trace_window(su, cell, int(traffic["trace_steps"]))
+        info.op_scopes = scopes.op_scopes(su.compiled.as_text())
         measured = info.traced_steps
     else:
         n, t_start, t_end = drive(su, seconds=seconds)
@@ -261,7 +269,8 @@ def run_cell(root, name: str, seed: int, seconds: float, trace: bool, *,
     pieces = program.piece_lengths(counter, lo, hi)
     if trace:
         info.traced_tokens = int(pieces.sum())
-        info.traced_pairs = work.live_pairs(pieces, bool(conf["causal"]))
+        info.traced_pieces = pieces
+        info.traced_pairs = work.pairs(conf, pieces)
     else:
         info.window_tokens = int(pieces.sum())
     ref = reference.readings(conf, traffic, seed, program.host_batches(counter, n_checked))

@@ -6,15 +6,13 @@ its metadata.  The compiled step's text carries both: every instruction's
 under (``repro.obs.SCOPES``) and the autodiff transforms around them.  So a
 traced op's phase is read from the step's text, keyed by instruction name.
 
-The text is that of the cell's step compiled again at the cell's shapes
-(``step_op_scopes``): the same program as the traced one, so the same
-persistent-cache entry on the chip and the same instruction names.  A
-program without ``repro.obs`` has no scopes to read, and every reading
-here is then ``None``.
+The text is that of the traced step itself, mesh and all: ``run.py`` keeps
+its ``op_scopes`` as ``RunInfo.op_scopes``.  A program without
+``repro.obs`` has no scopes to read, and every reading here is then
+``None``.
 """
 from __future__ import annotations
 
-import json
 import re
 from typing import Dict, Optional
 
@@ -122,26 +120,6 @@ def phase(op_name: str) -> str:
     return "other"
 
 
-_STEP_SCOPES: Dict[str, Dict[str, str]] = {}
-
-
-def step_op_scopes(conf: Dict, traffic: Dict) -> Dict[str, str]:
-    """``op_scopes`` of the cell's timed step (``program.make_step``),
-    lowered at the state's and the batch's shapes and compiled as
-    ``run.prepare`` compiles it; kept per cell for the process."""
-    key = json.dumps([conf, traffic], sort_keys=True)
-    if key not in _STEP_SCOPES:
-        import jax
-
-        from benchmarks.chip import program, weights
-
-        cfg = program.train_config(conf, traffic)
-        state = jax.eval_shape(lambda: program.init_state(cfg, weights.make_params(conf, 0)))
-        compiled = program.make_step(cfg).lower(state, program.batch_shapes(traffic)).compile()
-        _STEP_SCOPES[key] = op_scopes(compiled.as_text())
-    return _STEP_SCOPES[key]
-
-
 def phase_ns(trace, scopes: Dict[str, str]) -> Optional[Dict[str, int]]:
     """Summed device time of the trace's ops in each phase; None where an
     op of the trace is not an instruction of ``scopes``, whose text is then
@@ -160,9 +138,10 @@ def phase_ns(trace, scopes: Dict[str, str]) -> Optional[Dict[str, int]]:
 
 def phase_ms(run, name: str) -> Optional[float]:
     """Device time per traced step of the ops in phase ``name``, in ms;
-    None without a trace, without the program's scopes, or with none of
-    the phase's ops."""
-    if run.trace is None or not run.traced_steps or program_obs() is None:
+    None without a trace and the traced step's text, without the program's
+    scopes, or with none of the phase's ops."""
+    if (run.trace is None or not run.traced_steps or run.op_scopes is None
+            or program_obs() is None):
         return None
-    ns = phase_ns(run.trace, step_op_scopes(run.conf, run.traffic))
+    ns = phase_ns(run.trace, run.op_scopes)
     return ns[name] * 1e-6 / run.traced_steps if ns and ns[name] else None

@@ -10,6 +10,10 @@ a new entry in ``BENCHMARK.json`` and never an edit of a file that exists:
   <bench>/limits/<cell>.json      the limits of the numbers that decide
                                   ``correct``, with the readings behind them
   <bench>/metrics/<metric>.py     one reader per metric: ``read(run)``
+  <bench>/families/<family>.py    everything that depends on the model's
+                                  shape, for the configurations that name
+                                  this ``"family"`` (``"dense"`` where a
+                                  configuration names none)
 
 ``<bench>`` is the first of ``paths``; every file name is relative to the
 root that holds ``BENCHMARK.json``.
@@ -17,6 +21,7 @@ root that holds ``BENCHMARK.json``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -57,6 +62,30 @@ def _reader(bench_dir: pathlib.Path, name: str) -> Callable:
     return mod.read
 
 
+HERE = pathlib.Path(__file__).resolve().parent
+# the in-memory key under which ``load_cell`` records where the
+# configuration's family module lies
+FAMILY_FILE = "family_file"
+
+
+def family(conf: Dict):
+    """The family module of a configuration: the file ``load_cell`` found
+    for it, else ``families/<family>.py`` beside this file."""
+    path = conf.get(FAMILY_FILE) or HERE / "families" / f"{conf.get('family', 'dense')}.py"
+    return load_family(str(path))
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(path: str):
+    path = pathlib.Path(path)
+    if not path.exists():
+        raise FileNotFoundError(f"model family {path.stem!r} has no module at {path}")
+    mod_spec = importlib.util.spec_from_file_location(f"chipbench_family_{path.stem}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 def _metrics(entries, bench_dir) -> List[Metric]:
     """Every metric is read in every cell; a reader that finds nothing to
     read returns None and its metric is left out of the run's line."""
@@ -75,6 +104,8 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     conf = _load_json(root / configs[w["config"]]["file"])
+    conf[FAMILY_FILE] = str(bench_dir / "families" / f"{conf.get('family', 'dense')}.py")
+    family(conf)
     traffic = _load_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     limits = _load_json(bench_dir / "limits" / f"{name}.json")
     return Cell(

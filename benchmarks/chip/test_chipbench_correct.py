@@ -25,7 +25,7 @@ from benchmarks.chip import check, reference  # noqa: E402
 from benchmarks.chip import run as harness  # noqa: E402
 
 BENCH = ROOT / "benchmarks" / "chip"
-CELLS = ("bert-large.p1-k8", "granite-3-2b.pack4k-k8")
+CELLS = ("bert-large.p1-k8", "granite-3-2b.pack4k-k8", "bert-large.p1-dp4")
 SEED = 2**31 + 29
 CONF = {"name": "tiny", "source": "test", "hidden_size": 64, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -74,8 +74,8 @@ def tiny_root(tmp_path_factory):
     bench = root / "bench"
     for d in ("configs", "traffic", "limits"):
         (bench / d).mkdir(parents=True)
-    shutil.copytree(BENCH / "metrics", bench / "metrics",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for d in ("metrics", "families"):
+        shutil.copytree(BENCH / d, bench / d, ignore=shutil.ignore_patterns("__pycache__"))
     (bench / "configs" / "tiny.json").write_text(json.dumps(CONF))
     tr = json.loads((BENCH / "traffic" / "pack4k-k8.json").read_text())
     # k = 8 as in the cells: from two microbatches the GSNR is a ratio of

@@ -8,7 +8,6 @@ import importlib.util
 import json
 import pathlib
 import sys
-from unittest import mock
 
 import pytest
 
@@ -89,7 +88,7 @@ def _reader(name):
     return mod.read
 
 
-def _hand_run(steps=2, trace=True):
+def _hand_run(steps=2, trace=True, op_scopes=None):
     t, ops = 0, []
     for _ in range(steps):
         for name, (_, _, ns) in HAND.items():
@@ -97,8 +96,11 @@ def _hand_run(steps=2, trace=True):
             t += ns
     tr = xplane.Trace(ops={"/device:TPU:0": ops}, modules={"/device:TPU:0": []},
                       spans=[("window", 0, t)]) if trace else None
+    if op_scopes is None:
+        op_scopes = {n: v[0] for n, v in HAND.items()}
     return RunInfo(conf=TINY, traffic={"k": 2, "checked_steps": 3}, chips=1, peak=None,
-                   itemsize=2, setup_s=1.0, trace=tr, traced_steps=steps if trace else 0)
+                   itemsize=2, setup_s=1.0, trace=tr, traced_steps=steps if trace else 0,
+                   op_scopes=op_scopes if trace else None)
 
 
 def test_op_scopes_reads_every_instruction_of_the_text():
@@ -141,19 +143,18 @@ def test_phase_matches_whole_path_components_and_the_first_rule_wins():
 def tiny_step_scopes():
     """op_scopes of the benchmark's step at a tiny size on the fused plan
     (Pallas in interpret mode): the CPU's auto plan is the reference."""
+    import jax
+
+    from benchmarks.chip import weights
     from repro.backend import Backend
-
-    real = program.train_config
-
-    def fused(conf, traffic):
-        cfg = real(conf, traffic)
-        return cfg.replace(parallel=dataclasses.replace(cfg.parallel,
-                                                        backend=Backend.all_fused()))
 
     tr = json.loads((HERE / "traffic" / "p1-k8.json").read_text())
     tr.update(seq_len=32, rows=4, k=2)
-    with mock.patch.object(program, "train_config", fused):
-        return scopes.step_op_scopes(TINY, tr)
+    cfg = program.train_config(TINY, tr)
+    cfg = cfg.replace(parallel=dataclasses.replace(cfg.parallel, backend=Backend.all_fused()))
+    state = jax.eval_shape(lambda: program.init_state(cfg, weights.make_params(TINY, 0), TINY))
+    compiled = program.make_step(cfg).lower(state, program.batch_shapes(tr)).compile()
+    return scopes.op_scopes(compiled.as_text())
 
 
 def test_a_compiled_fused_step_holds_every_phase(tiny_step_scopes):
@@ -163,9 +164,7 @@ def test_a_compiled_fused_step_holds_every_phase(tiny_step_scopes):
     assert set(found) == set(scopes.PHASES), sorted(found)
 
 
-def test_the_phase_readers_on_a_hand_made_trace(monkeypatch):
-    monkeypatch.setattr(scopes, "step_op_scopes",
-                        lambda conf, traffic: {n: v[0] for n, v in HAND.items()})
+def test_the_phase_readers_on_a_hand_made_trace():
     run = _hand_run()
     for metric, ph in PHASE_METRICS.items():
         want = sum(ns for _, p, ns in HAND.values() if p == ph) * 1e-6
@@ -175,15 +174,14 @@ def test_the_phase_readers_on_a_hand_made_trace(monkeypatch):
 
 
 def test_the_phase_readers_read_nothing_without_their_inputs(monkeypatch):
-    monkeypatch.setattr(scopes, "step_op_scopes",
-                        lambda conf, traffic: {n: v[0] for n, v in HAND.items()})
     for metric in PHASE_METRICS:
         assert _reader(metric)(_hand_run(trace=False)) is None
+    # a run that kept no text of its step
+    no_text = dataclasses.replace(_hand_run(), op_scopes=None)
+    assert all(_reader(m)(no_text) is None for m in PHASE_METRICS)
     # a text that is not the traced program's: an op of the trace is missing
-    monkeypatch.setattr(scopes, "step_op_scopes",
-                        lambda conf, traffic: {n: v[0] for n, v in HAND.items()
-                                               if n != "copy.10"})
-    assert all(_reader(m)(_hand_run()) is None for m in PHASE_METRICS)
+    other = {n: v[0] for n, v in HAND.items() if n != "copy.10"}
+    assert all(_reader(m)(_hand_run(op_scopes=other)) is None for m in PHASE_METRICS)
     # a program that predates repro.obs
     monkeypatch.setattr(scopes, "program_obs", lambda: None)
     assert all(_reader(m)(_hand_run()) is None for m in NEW_METRICS)
@@ -241,10 +239,9 @@ def scoped():
 def test_the_new_readers_read_the_scoped_chip_fixture(scoped, monkeypatch):
     op_scopes, trace, spans = scoped
     assert spans and all(d > 0 for d in spans)
-    monkeypatch.setattr(scopes, "step_op_scopes", lambda conf, traffic: op_scopes)
     monkeypatch.setattr(obs, "traced_durations", lambda name: spans)
     run = RunInfo(conf=TINY, traffic={"k": 2}, chips=1, peak=None, itemsize=2, setup_s=1.0,
-                  trace=trace, traced_steps=2)
+                  trace=trace, traced_steps=2, op_scopes=op_scopes)
     values = {n: _reader(n)(run) for n in NEW_METRICS}
     assert all(v is not None and v > 0 for v in values.values()), values
 

@@ -109,3 +109,58 @@ def test_hand_trace_busy_gaps_and_attribution():
     top = xplane.top_ops(t, 2)
     assert [n for n, _ in top] == ["fusion.2", "flat_moments_accum.1"]
     assert [v for _, v in top] == pytest.approx([15e-9, 10e-9])
+
+
+def _hlo_op(name, start, dur, opcode):
+    return xplane.Op(name, start, dur, f"%{name} = (f32[8]{{0:T(128)}}, f32[8]) {opcode}(%x)")
+
+
+# one plane: a collective that compute covers, one that nothing covers, an
+# asynchronous pair whose start launches it and whose done is the wait, and
+# one that compute covers in part
+COLLECTIVE_OPS = [
+    _hlo_op("fusion.1", 0, 100, "fusion"),
+    _hlo_op("all-reduce.2", 20, 40, "all-reduce"),  # covered: 0
+    _hlo_op("all-gather.3", 120, 30, "all-gather"),  # bare: 30
+    _hlo_op("all-reduce-start.4", 160, 2, "all-reduce-start"),
+    _hlo_op("fusion.5", 162, 28, "fusion"),
+    _hlo_op("all-reduce-done.6", 190, 10, "all-reduce-done"),  # the wait: 10
+    _hlo_op("reduce-scatter.7", 210, 30, "reduce-scatter"),
+    _hlo_op("fusion.8", 230, 30, "fusion"),  # covers the last 10 of it: 20
+]
+
+
+def test_collectives_are_told_by_their_opcode():
+    picked = [o.name for o in COLLECTIVE_OPS if xplane.is_collective(o)]
+    assert picked == ["all-reduce.2", "all-gather.3", "all-reduce-done.6", "reduce-scatter.7"]
+    assert xplane.opcode(COLLECTIVE_OPS[0]) == "fusion"
+    assert xplane.opcode(xplane.Op("x", 0, 1, "no instruction text")) == ""
+    for code in ("all-to-all", "collective-permute", "collective-permute-done"):
+        assert xplane.is_collective(_hlo_op("c.1", 0, 1, code)), code
+    assert not xplane.is_collective(_hlo_op("c.1", 0, 1, "all-gather-start"))
+
+
+def test_exposed_time_counts_only_what_no_other_op_covers():
+    tr = xplane.Trace(ops={"/device:TPU:0": COLLECTIVE_OPS}, modules={},
+                      spans=[("window", 0, 300)])
+    assert xplane.exposed_ns(tr, "/device:TPU:0", xplane.is_collective) == 0 + 30 + 10 + 20
+    assert xplane.overlap_ns([(0, 10), (20, 30)], [(5, 25)]) == 5 + 5
+
+
+def test_collective_exposed_ms_averages_the_chips_per_step():
+    covered = [_hlo_op("fusion.1", 0, 100, "fusion"), _hlo_op("all-reduce.2", 20, 40, "all-reduce")]
+    tr = xplane.Trace(ops={"/device:TPU:0": COLLECTIVE_OPS, "/device:TPU:1": covered},
+                      modules={}, spans=[("window", 0, 300)])
+    read = _reader("collective_exposed_ms")
+    run = type("R", (), {"trace": tr, "traced_steps": 2})()
+    assert read(run) == pytest.approx((60 + 0) / 2 * 1e-6 / 2)
+    # one chip, no collectives: nothing to read
+    solo = xplane.Trace(ops={"/device:TPU:0": COLLECTIVE_OPS[:1]}, modules={},
+                        spans=[("window", 0, 300)])
+    assert read(type("R", (), {"trace": solo, "traced_steps": 2})()) is None
+    assert read(type("R", (), {"trace": None, "traced_steps": 0})()) is None
+
+
+def test_the_recorded_one_chip_trace_has_no_collectives(recorded):
+    assert not any(xplane.is_collective(o) for o in recorded.ops["/device:TPU:0"])
+    assert all(xplane.opcode(o) for o in recorded.ops["/device:TPU:0"])

@@ -1,7 +1,8 @@
 """Weights from ``--seed``, in the benchmark's own layout, made on the device
 in one jitted call.
 
-The layout is a flat dict of named arrays.  Layer weights are stacked over
+The layout is a flat dict of named arrays, the family's
+(``families/<family>.py``: ``leaf_shapes``).  Layer weights are stacked over
 the layers under ``layers.<name>`` (leading axis L), which is how the
 program groups its parameter tensors too; the rest are ``embed``,
 ``final.scale``/``final.bias`` and, for an untied head, ``head``.  Matrices
@@ -18,38 +19,13 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-
-def dims(conf: Dict) -> Dict[str, int]:
-    d = int(conf["hidden_size"])
-    h = int(conf["num_attention_heads"])
-    return {
-        "L": int(conf["num_hidden_layers"]), "D": d, "H": h,
-        "KV": int(conf["num_key_value_heads"]), "hd": int(conf.get("head_dim") or d // h),
-        "F": int(conf["intermediate_size"]), "V": int(conf["vocab_size"]),
-    }
+from benchmarks.chip.spec import family
 
 
 def leaf_shapes(conf: Dict) -> Dict[str, Tuple[int, ...]]:
-    """Name -> shape of every parameter tensor."""
-    n = dims(conf)
-    L, D, F, V = n["L"], n["D"], n["F"], n["V"]
-    q, kv = n["H"] * n["hd"], n["KV"] * n["hd"]
-    layernorm = conf["norm"] == "layernorm"
-    s = {"embed": (V, D)}
-    for ln in ("ln1", "ln2"):
-        s[f"layers.{ln}_scale"] = (L, D)
-        if layernorm:
-            s[f"layers.{ln}_bias"] = (L, D)
-    s.update({"layers.wq": (L, D, q), "layers.wk": (L, D, kv), "layers.wv": (L, D, kv),
-              "layers.wo": (L, q, D), "layers.wi": (L, D, F), "layers.wd": (L, F, D)})
-    if conf["mlp"] == "gated":
-        s["layers.wg"] = (L, D, F)
-    s["final.scale"] = (D,)
-    if layernorm:
-        s["final.bias"] = (D,)
-    if not conf["tie_word_embeddings"]:
-        s["head"] = (D, V)
-    return s
+    """Name -> shape of every parameter tensor, as the configuration's
+    family lays them out."""
+    return family(conf).leaf_shapes(conf)
 
 
 def seed_key(seed: int):

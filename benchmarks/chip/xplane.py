@@ -183,6 +183,49 @@ def idle_gaps(trace: Trace, n: int = 10) -> List[Tuple[str, float]]:
     return sorted(out, key=lambda g: -g[1])[:n]
 
 
+def overlap_ns(a: List[Tuple[int, int]], b: List[Tuple[int, int]]) -> int:
+    """Length of the intersection of two lists of sorted, disjoint
+    intervals (as ``union`` gives them)."""
+    total, i, j = 0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] <= b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def exposed_ns(trace: Trace, plane: str, pick: Callable[[Op], bool]) -> int:
+    """Time on ``plane`` in which an op that ``pick`` selects runs and no
+    other op does."""
+    span = _span(trace, plane)
+    ops = trace.ops[plane]
+    mine = union(_intervals([o for o in ops if pick(o)]), *span)
+    rest = union(_intervals([o for o in ops if not pick(o)]), *span)
+    return sum(e - s for s, e in mine) - overlap_ns(mine, rest)
+
+
+# the HLO opcode: the first word after the instruction's type that opens
+# its operand list
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all", "collective-permute")
+
+
+def opcode(op: Op) -> str:
+    """The HLO opcode of the op's instruction, "" where its text has none."""
+    parts = op.text.split(" = ", 1)
+    m = _OPCODE.search(" " + parts[1]) if len(parts) == 2 else None
+    return m.group(1) if m else ""
+
+
+def is_collective(op: Op) -> bool:
+    """A synchronous collective, or the wait (``-done``) of an asynchronous
+    one; its ``-start`` only launches it and is not selected."""
+    code = opcode(op)
+    return code.removesuffix("-done") in COLLECTIVES
+
+
 def spans_ns(trace: Trace, name: str) -> Optional[int]:
     lo, hi = trace.window
     got = [d for n, s, d in trace.spans if n == name and lo <= s < hi]
