@@ -6,8 +6,9 @@ frozen dataclasses so they hash and are safe as jit static args.
 
 Block kinds understood by ``models/transformer.py``:
 
-  "attn"    full (causal) self-attention + MLP
-  "swa"     sliding-window self-attention + MLP
+  "attn"    full (causal) self-attention + MLP; YaRN RoPE where
+            ``ModelConfig.rope_yarn`` is set
+  "swa"     sliding-window self-attention + MLP (plain RoPE)
   "local"   sliding-window self-attention + MLP (recurrentgemma naming)
   "xattn"   self-attention + cross-attention (to image/audio memory) + MLP
   "rec"     RG-LRU recurrent block + MLP                     [arXiv:2402.19427]
@@ -32,12 +33,37 @@ from repro.backend import Backend
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8  # the router's width: experts routed over
     top_k: int = 2
-    capacity_factor: float = 1.25
+    # None: the dropless layer (every routed row computed, grouped matmuls
+    # over rows sorted by expert); a number: capacity-bounded dispatch
+    capacity_factor: Optional[float] = 1.25
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
     n_shared_experts: int = 0  # llama4-style always-on shared expert
+    # the share of the experts this layer holds (dropless only): experts
+    # first_held .. first_held + n_held - 1; 0 holds all n_experts
+    n_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+    @property
+    def dropless(self) -> bool:
+        return self.capacity_factor is None
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (arXiv:2309.00071), as HF ``rope_type: yarn``."""
+
+    factor: float
+    original_max_positions: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float  # cos and sin are multiplied by it
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +89,7 @@ class ModelConfig:
     block_pattern: Tuple[str, ...] = ("attn",)
     sliding_window: int = 0  # 0 -> full attention for "attn"; "swa"/"local" need >0
     rope_theta: float = 10000.0
+    rope_yarn: Optional[YarnConfig] = None  # "attn" layers only; None: plain RoPE
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     act: str = "swiglu"  # swiglu | gelu
     moe: Optional[MoEConfig] = None
@@ -131,21 +158,22 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: only top_k + shared experts count)."""
+        """Active params per token (MoE: only top_k + shared experts count;
+        of a held share, its nominal part: top_k * held / n_experts)."""
         if self.moe is None:
             return self.param_count()
         m = self.moe
         full = self.param_count()
         mlp = 3 * self.d_model * self.d_ff if self.act == "swiglu" else 2 * self.d_model * self.d_ff
         n_moe_layers = sum(1 for k in self.pattern_layers() if k in ("attn", "swa", "local", "xattn"))
-        inactive = n_moe_layers * mlp * (m.n_experts - m.top_k)
+        inactive = n_moe_layers * mlp * (m.held * (m.n_experts - m.top_k) // m.n_experts)
         return full - inactive
 
     def _mlp_or_moe(self, mlp: int) -> int:
         if self.moe is None:
             return mlp
         m = self.moe
-        return mlp * (m.n_experts + m.n_shared_experts) + self.d_model * m.n_experts
+        return mlp * (m.held + m.n_shared_experts) + self.d_model * m.n_experts
 
 
 # ---------------------------------------------------------------------------

@@ -190,6 +190,7 @@ def attention(
     head_dim: int,
     q_pos: jnp.ndarray,
     rope_theta: float = 0.0,
+    rope_yarn=None,
     causal: bool = True,
     window: int = 0,
     memory: Optional[jnp.ndarray] = None,
@@ -210,6 +211,7 @@ def attention(
     into an existing one when ``cache`` is passed), "decode" (consumes/
     returns cache; x is (B, L, d) — L lanes decode in lock-step per row).
     memory: (B, M, d) for cross-attention (causal/window ignored).
+    rope_yarn: a YarnConfig scaling the RoPE frequencies (None: plain).
     q_pos: (B, S) int32 absolute positions; pos < 0 marks padding.  Packed
     and offset layouts are first-class everywhere: segment ids gate
     cross-document attention on the jnp paths AND the fused kernels, and
@@ -272,8 +274,8 @@ def attention(
         k = _split_heads(x @ p["wk"].astype(dtype), n_kv_heads)
         v = _split_heads(x @ p["wv"].astype(dtype), n_kv_heads)
         if rope_theta:
-            q = apply_rope(q, q_pos, rope_theta)
-            k = apply_rope(k, q_pos, rope_theta)
+            q = apply_rope(q, q_pos, rope_theta, rope_yarn)
+            k = apply_rope(k, q_pos, rope_theta, rope_yarn)
         if mode == "train":
             k_pos = q_pos
             new_cache = None

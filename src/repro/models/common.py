@@ -19,10 +19,11 @@ by ndim.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 PyTree = Any
 
@@ -102,13 +103,39 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (B, S, H, D); positions: (B, S) int32."""
+def yarn_freqs(head_dim: int, theta: float, yarn) -> Tuple[np.ndarray, float]:
+    """YaRN's frequencies and the factor on cos and sin (HF
+    ``_compute_yarn_parameters``): the dims below the correction range keep
+    their frequencies (extrapolation), those above it take them divided by
+    ``factor`` (interpolation), a linear ramp between.  ``yarn``: a
+    YarnConfig."""
+
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(yarn.original_max_positions / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    pos_freqs = theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv = (1.0 / (yarn.factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1.0 - ramp)
+    return inv.astype(np.float32), float(yarn.attention_factor)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, yarn=None) -> jnp.ndarray:
+    """x: (B, S, H, D); positions: (B, S) int32; ``yarn`` (a YarnConfig)
+    scales the frequencies and cos and sin."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # (D/2,)
+    if yarn is None:
+        freqs = rope_freqs(d, theta)  # (D/2,)
+    else:
+        freqs, attention_factor = yarn_freqs(d, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * attention_factor, sin * attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

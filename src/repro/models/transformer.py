@@ -42,8 +42,11 @@ from repro.sharding.rules import constrain
 AUX_ZERO = {"moe_lb_loss": 0.0, "moe_z_loss": 0.0, "moe_util": 0.0}
 
 
-def _aux_zero():
-    return {k: jnp.zeros((), jnp.float32) for k in AUX_ZERO}
+def _aux_zero(cfg: ModelConfig):
+    keys = list(AUX_ZERO)
+    if cfg.moe is not None and cfg.moe.dropless:
+        keys.append(moe_mod.MOE_ROWS)
+    return {k: jnp.zeros((), jnp.float32) for k in keys}
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +103,7 @@ def _block_apply(
     q_seg: Optional[jnp.ndarray] = None,
     seg_base: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[Dict], Dict]:
-    aux = _aux_zero()
+    aux = _aux_zero(cfg)
     new_cache: Optional[Dict] = None
     h = apply_norm(p["ln1"], x, cfg.norm)
     common = dict(
@@ -122,6 +125,7 @@ def _block_apply(
             p["attn"],
             h,
             rope_theta=cfg.rope_theta,
+            rope_yarn=cfg.rope_yarn if kind == "attn" else None,
             causal=causal,
             window=window,
             cache=None if cache is None else cache.get("self"),
@@ -143,7 +147,8 @@ def _block_apply(
             x = x + out
         h2 = apply_norm(p["ln2"], x, cfg.norm)
         if "moe" in p:
-            out, moe_aux = moe_mod.apply_moe(p["moe"], h2, cfg.act, cfg.moe)
+            out, moe_aux = moe_mod.apply_moe(p["moe"], h2, cfg.act, cfg.moe,
+                                             interpret=common["backend"].interpret)
             aux.update(moe_aux)
         else:
             out = apply_mlp(p["mlp"], h2, cfg.act)
@@ -287,7 +292,7 @@ def forward(
 
     x = embed_tokens(params["embed"], tokens, dtype)
     x = constrain(x, ("batch", None, None))
-    aux_total = _aux_zero()
+    aux_total = _aux_zero(cfg)
 
     def apply_one(kind, p, xx, blk_cache):
         return _block_apply(
@@ -297,6 +302,11 @@ def forward(
             implicit_layout=implicit_layout,
             q_seg=segments, seg_base=seg_base,
         )
+
+    remat = pcfg.remat and mode == "train"
+    # layers outside the scan (an unscanned stack, the tail) are
+    # rematerialized one block at a time, as the scan does one group
+    apply_unscanned = jax.checkpoint(apply_one, static_argnums=(0,)) if remat else apply_one
 
     use_cache_in = cache is not None and mode in ("decode", "prefill")
     group_caches = None
@@ -316,7 +326,7 @@ def forward(
                     new_gc[f"pos{i}"] = nc
                 return (xx, aux), new_gc
 
-            if pcfg.remat and mode == "train":
+            if remat:
                 group_fn = jax.checkpoint(group_fn)
             gcache_in = cache["groups"] if use_cache_in else None
             if gcache_in is None:
@@ -333,7 +343,7 @@ def forward(
                 new_gc = {}
                 for i, kind in enumerate(pattern):
                     blk_c = cache["groups"][gi].get(f"pos{i}") if use_cache_in else None
-                    x, nc, a = apply_one(kind, gp[f"pos{i}"], x, blk_c)
+                    x, nc, a = apply_unscanned(kind, gp[f"pos{i}"], x, blk_c)
                     aux_total = {k_: aux_total[k_] + a[k_] for k_ in aux_total}
                     new_gc[f"pos{i}"] = nc
                 group_caches.append(new_gc)
@@ -341,7 +351,7 @@ def forward(
     tail_caches = []
     for ti, kind in enumerate(tail):
         blk_c = cache["tail"][ti] if use_cache_in else None
-        x, nc, a = apply_one(kind, params["tail"][ti], x, blk_c)
+        x, nc, a = apply_unscanned(kind, params["tail"][ti], x, blk_c)
         aux_total = {k_: aux_total[k_] + a[k_] for k_ in aux_total}
         tail_caches.append(nc)
 
@@ -360,7 +370,9 @@ def forward(
         logits = apply_head(params, x, cfg.logit_softcap)
 
     n_layers = max(1, cfg.n_layers)
-    aux_total = {k_: v / n_layers for k_, v in aux_total.items()}
+    # the routed-row count is summed over the layers, the losses averaged
+    aux_total = {k_: v if k_ == moe_mod.MOE_ROWS else v / n_layers
+                 for k_, v in aux_total.items()}
     out_cache = None
     if mode in ("prefill", "decode"):
         out_cache = {"groups": group_caches, "tail": tail_caches}

@@ -1,4 +1,5 @@
-"""The program's own instrumentation: the names of its phases and two helpers.
+"""The program's own instrumentation: the names of its phases, spans and
+counters, and the helpers that place them.
 
 Scopes (:func:`scope`, ``jax.named_scope``) name the phases of the train
 step.  Each adds a component to the ``op_name`` metadata of every HLO
@@ -17,7 +18,15 @@ instruction; the compiled step's text maps that name to its ``op_name``.
   ``stats_accum``     the moment accumulation kernels                 kernels/ops.py
   ``stats_finalize``  the /k normalize of the moments                 kernels/ops.py
   ``optimizer``       grad norm, clip, VR update, unpack and apply    train/trainer.py
+  ``moe``             a dropless expert layer, holding the four       models/moe.py
+                      below: ``moe_route`` (router, top-k, losses),
+                      ``moe_dispatch`` (rows sorted by expert and
+                      gathered), ``moe_experts`` (the grouped
+                      matmuls), ``moe_combine`` (weighted scatter)
   ==================  ==============================================  =======
+
+The ``moe`` scopes lie inside ``model`` and are not phases of their own
+(``SCOPES`` holds the phases).
 
 Spans (:func:`span`, ``jax.profiler.TraceAnnotation``) time host work on the
 profiler's clock; every span's name starts with ``repro.``.  A span that
@@ -31,6 +40,18 @@ the spans the trace holds.
   ``repro.data.produce``  one prefetched batch: gathered and placed
                           (data/memmap.py, the prefetch thread)
   ======================  ==============================================
+
+Counters (:func:`count`) bring a number the step computes on the device to
+the host, once a step, through one host callback; a value that arrives
+while a profiler trace records is logged (:func:`traced_counts`).
+
+  ==================  ==============================================
+  counter             what it counts
+  ==================  ==============================================
+  ``repro.moe.rows``  (token, choice) rows routed to the experts this
+                      program holds, summed over the dropless layers
+                      and the microbatches of a step (train/trainer.py)
+  ==================  ==============================================
 """
 from __future__ import annotations
 
@@ -47,13 +68,24 @@ STATS_ACCUM = "stats_accum"
 STATS_FINALIZE = "stats_finalize"
 OPTIMIZER = "optimizer"
 SCOPES = (MODEL, STATS_PACK, STATS_ACCUM, STATS_FINALIZE, OPTIMIZER)
+MOE = "moe"
+MOE_ROUTE = "moe_route"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_COMBINE = "moe_combine"
+MOE_SCOPES = (MOE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 
 DATA_PRODUCE = "repro.data.produce"
 SPANS = (DATA_PRODUCE,)
 
-# traced spans kept per name; a process that records more keeps the newest
+MOE_ROWS = "repro.moe.rows"
+COUNTERS = (MOE_ROWS,)
+
+# traced spans (and counts) kept per name; a process that records more
+# keeps the newest
 LOG_SIZE = 4096
-_LOG: Dict[str, Deque[int]] = {name: collections.deque(maxlen=LOG_SIZE) for name in SPANS}
+_LOG: Dict[str, Deque[int]] = {name: collections.deque(maxlen=LOG_SIZE)
+                               for name in SPANS + COUNTERS}
 
 
 def scope(name: str):
@@ -71,6 +103,23 @@ def span(name: str) -> Iterator[None]:
         yield
     if traced:
         _LOG[name].append(time.perf_counter_ns() - t0)
+
+
+def count(name: str, value) -> None:
+    """Inside a jitted step: sends the scalar ``value`` to the host each
+    time the step runs, where it is logged if a profiler trace records."""
+
+    def log(v):
+        if jax.profiler.TraceAnnotation.is_enabled():
+            _LOG[name].append(int(round(float(v))))
+
+    jax.debug.callback(log, value)
+
+
+def traced_counts(name: str) -> List[int]:
+    """Values of counter ``name`` that arrived while a profiler trace
+    recorded, oldest first."""
+    return list(_LOG[name])
 
 
 def traced_durations(name: str) -> List[int]:

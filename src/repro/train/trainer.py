@@ -25,6 +25,7 @@ from repro.core import grad_only, grad_stats, gsnr_scale, gsnr_summary, make_opt
 from repro.core.distributed import device_grad_stats_fn
 from repro.models import init_params
 from repro.models.common import global_norm
+from repro.models.moe import MOE_ROWS
 from repro.train.loss import make_loss_fn
 from repro.train.train_state import TrainState
 
@@ -117,6 +118,9 @@ def make_train_step(
         else:
             loss, aux, grads = grad_only(loss_fn, state.params, batch, has_aux=True)
             stats = None
+        if aux and MOE_ROWS in aux:
+            # aux is the microbatches' mean: the step's rows are k times it
+            obs.count(obs.MOE_ROWS, aux[MOE_ROWS] * (opt_cfg.k if is_vr else 1))
         with obs.scope(obs.OPTIMIZER):
             gnorm = global_norm(grads)
             if opt_cfg.grad_clip > 0:

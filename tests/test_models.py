@@ -1,12 +1,20 @@
 """Model-layer unit tests: attention paths, RoPE, norms, MLP."""
+import json
+import math
+import pathlib
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.base import YarnConfig
 from repro.kernels import ref
 from repro.models.attention import _chunked_sdpa, _mask, _sdpa, attention, attn_init
-from repro.models.common import apply_norm, apply_rope, norm_init
+from repro.models.common import apply_norm, apply_rope, norm_init, yarn_freqs
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def mk_qkv(key, b=2, s=64, h=4, kv=2, d=16, dtype=jnp.float32):
@@ -309,3 +317,121 @@ def test_packed_two_segment_batch_matches_unpacked(pallas):
         np.testing.assert_allclose(
             np.asarray(la), np.asarray(lb), atol=5e-4, rtol=5e-3
         )
+
+
+# ---------------------------------------------------------------------------
+# YaRN RoPE, and a window/full sparse-expert stack against its reference
+# ---------------------------------------------------------------------------
+
+MELLUM_YARN = YarnConfig(factor=16.0, original_max_positions=8192, beta_fast=32.0,
+                         beta_slow=1.0, attention_factor=1.2772588722239782)
+
+
+def test_yarn_frequencies_follow_the_formulas():
+    """Mellum's full layers (head_dim 128, theta 5e5): the correction range
+    is dims 18-35, original frequencies below it, divided by the factor past
+    it, a linear ramp between; cos and sin carry the attention factor."""
+    hd, theta = 128, 5e5
+    inv, scale = yarn_freqs(hd, theta, MELLUM_YARN)
+
+    def c(rotations):
+        return hd * math.log(8192 / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low, high = math.floor(c(32)), math.ceil(c(1))
+    assert (low, high) == (18, 35)
+    pos_freqs = theta ** (np.arange(0, hd, 2) / hd)
+    ramp = np.clip((np.arange(hd // 2) - low) / (high - low), 0, 1)
+    want = (1 / (16 * pos_freqs)) * ramp + (1 / pos_freqs) * (1 - ramp)
+    np.testing.assert_allclose(inv, want, rtol=1e-6)
+    np.testing.assert_allclose(inv[:low], 1 / pos_freqs[:low], rtol=1e-6)
+    np.testing.assert_allclose(inv[high:], 1 / (16 * pos_freqs[high:]), rtol=1e-6)
+    assert scale == MELLUM_YARN.attention_factor
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 6, 2, hd))
+    pos = jnp.arange(6)[None] * 1000
+    plain, yarn = apply_rope(x, pos, theta), apply_rope(x, pos, theta, MELLUM_YARN)
+    np.testing.assert_allclose(np.linalg.norm(yarn, axis=-1),
+                               scale * np.linalg.norm(x, axis=-1), rtol=1e-5)
+    np.testing.assert_allclose(apply_rope(x, pos, theta, None), plain)
+    # dim 0 keeps its frequency (angle pos * 1), the last is interpolated
+    for i in (0, hd // 2 - 1):
+        ang = np.asarray(pos[0, 1:], np.float64) * want[i]
+        got = np.asarray(yarn[0, 1:, 0, i]) / scale
+        x1, x2 = np.asarray(x[0, 1:, 0, i]), np.asarray(x[0, 1:, 0, i + hd // 2])
+        np.testing.assert_allclose(got, x1 * np.cos(ang) - x2 * np.sin(ang), atol=1e-4)
+
+
+def _mellum():
+    """The benchmark's Mellum family and its configuration at a tiny width:
+    4 layers (3 window, 1 full), experts 2-3 of a router over 8, top-2."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from benchmarks.chip.spec import load_family
+
+    fam = load_family(str(ROOT / "benchmarks" / "chip" / "families" / "moe.py"))
+    conf = json.loads((ROOT / "benchmarks" / "chip" / "configs" / "mellum2-12b-a2.5b.json").read_text())
+    conf.update(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                moe_intermediate_size=32, router_experts=8, num_experts=2, first_held_expert=2,
+                num_experts_per_tok=2, vocab_size=256, sliding_window=8)
+    conf["rope_parameters"]["full_attention"]["original_max_position_embeddings"] = 16
+    return fam, conf
+
+
+def _gaps(got, want):
+    """Worst |got - want| over the tensor's largest |want|, per tensor."""
+    return {k: float(jnp.max(jnp.abs(got[k] - want[k])) / jnp.max(jnp.abs(want[k])))
+            for k in want}
+
+
+@pytest.fixture(scope="module")
+def mellum_stack():
+    """The program's loss and gradients (float32) on one packed row of two
+    documents longer than the window, and pads, from the family's weights."""
+    from benchmarks.chip import weights
+    from repro.configs import Config, ParallelismConfig
+    from repro.train.loss import make_loss_fn
+
+    fam, conf = _mellum()
+    model = fam.model_config(conf)
+    assert model.block_pattern == ("swa", "swa", "swa", "attn") and model.sliding_window == 8
+    cfg = Config(model=model, parallel=ParallelismConfig(compute_dtype="float32"))
+    bp = weights.make_params(conf, 5)
+    rng = np.random.default_rng(0)
+    pos = np.concatenate([np.arange(25), np.arange(13), [-1, -1]])[None].astype(np.int32)
+    seg = np.concatenate([np.zeros(25), np.ones(13), [-1, -1]])[None].astype(np.int32)
+    mb = {"tokens": jnp.asarray(rng.integers(0, 256, (1, 40)), jnp.int32),
+          "targets": jnp.asarray(rng.integers(0, 256, (1, 40)), jnp.int32),
+          "positions": jnp.asarray(pos), "segments": jnp.asarray(seg),
+          "mask": jnp.asarray(pos >= 0, jnp.float32)}
+    loss_fn = make_loss_fn(cfg, with_aux=False)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: loss_fn(p, mb)))(fam.to_program(bp, cfg))
+    return fam, conf, bp, mb, float(loss), fam.from_program(grads)
+
+
+def _reference(fam, conf, bp, mb):
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.jit(jax.value_and_grad(lambda p: fam.loss(conf, p, mb)))(bp)
+    return float(loss), grads
+
+
+def test_window_full_stack_matches_the_family_reference(mellum_stack):
+    """Loss and every gradient of a (swa, swa, swa, attn) stack of dropless
+    held-share expert layers equal the benchmark family's plain reference."""
+    fam, conf, bp, mb, loss, grads = mellum_stack
+    ref_loss, ref_grads = _reference(fam, conf, bp, mb)
+    assert loss == pytest.approx(ref_loss, rel=1e-5)
+    assert set(grads) == set(ref_grads)
+    gaps = _gaps(grads, ref_grads)
+    assert max(gaps.values()) < 1e-4, gaps
+
+
+def test_full_layers_without_yarn_fail_the_comparison(mellum_stack):
+    """Negative control: a reference whose full layer takes plain RoPE is
+    far outside the tolerance the program meets."""
+    fam, conf, bp, mb, loss, grads = mellum_stack
+    plain = json.loads(json.dumps(conf))
+    plain["rope_parameters"]["full_attention"] = {"rope_type": "default", "rope_theta": 500000}
+    ref_loss, ref_grads = _reference(fam, plain, bp, mb)
+    gaps = _gaps(grads, ref_grads)
+    assert max(gaps.values()) > 100 * 1e-4, gaps
+    assert max(gaps[f"layer3.{w}"] for w in ("wq", "wk", "wv")) > 100 * 1e-4

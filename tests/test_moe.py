@@ -1,11 +1,13 @@
 """MoE dispatch correctness: sparse gather/scatter vs dense oracle."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import MoEConfig
-from repro.models.moe import apply_moe, apply_moe_dense, moe_init
+from repro.models.moe import apply_moe, apply_moe_dense, apply_moe_dropless, moe_init
 
 
 def setup(key, e=4, k=2, cap=8.0, shared=0, d=16, f=32):
@@ -83,3 +85,83 @@ def test_moe_gradients_flow_to_router():
     g = jax.grad(loss)(p)
     assert float(jnp.max(jnp.abs(g["router"]))) > 0
     assert float(jnp.max(jnp.abs(g["expert_wi"]))) > 0
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer that holds a share of the experts
+# ---------------------------------------------------------------------------
+
+
+def held_setup(key, e=8, k=2, held=0, first=0, d=16, f=32, tokens=24):
+    cfg = MoEConfig(n_experts=e, top_k=k, capacity_factor=None, n_held=held, first_held=first)
+    p = moe_init(key, d, f, "swiglu", cfg)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, tokens // 2, d))
+    return cfg, p, x
+
+
+def share(p, first, held):
+    """The uncut layer's weights as a share holds them: every router column,
+    its own experts."""
+    return {k_: v[first:first + held] if k_.startswith("expert_") else v for k_, v in p.items()}
+
+
+def test_dropless_held_share_matches_the_dense_oracle():
+    """Experts 3-5 of 8, top-2: the grouped matmuls over the rows routed to
+    them give the dense oracle restricted to them, output and gradients."""
+    cfg, p, x = held_setup(jax.random.PRNGKey(6), held=3, first=3)
+    assert p["router"].shape == (16, 8) and p["expert_wi"].shape == (3, 16, 32)
+
+    def loss(fn, p_):
+        out, aux = fn(p_)
+        return jnp.sum(out * out) + aux["moe_lb_loss"]
+
+    dropless = lambda p_: apply_moe_dropless(p_, x, "swiglu", cfg, interpret=True)  # noqa: E731
+    dense = lambda p_: apply_moe_dense(p_, x, "swiglu", cfg)  # noqa: E731
+    out, aux = jax.jit(dropless)(p)
+    out_d, aux_d = dense(p)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_d), atol=2e-5)
+    assert float(aux["moe_lb_loss"]) == pytest.approx(float(aux_d["moe_lb_loss"]), rel=1e-6)
+    g = jax.jit(jax.grad(lambda p_: loss(dropless, p_)))(p)
+    g_d = jax.grad(lambda p_: loss(dense, p_))(p)
+    for name in g:
+        np.testing.assert_allclose(np.asarray(g[name]), np.asarray(g_d[name]), atol=1e-4,
+                                   err_msg=name)
+
+
+def test_dropless_counts_the_rows_routed_to_held_experts():
+    cfg, p, x = held_setup(jax.random.PRNGKey(7), held=3, first=3)
+    _, aux = apply_moe_dropless(p, x, "swiglu", cfg, interpret=True)
+    logits = x.reshape(-1, 16) @ p["router"]
+    _, idx = jax.lax.top_k(logits, 2)
+    assert int(aux["moe_rows"]) == int(jnp.sum((idx >= 3) & (idx < 6))) > 0
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """E = 8 held in 4 shares of 2: what the four shares return sums to the
+    output of the layer that holds all 8."""
+    cfg, p, x = held_setup(jax.random.PRNGKey(8))
+    def part(first, held):
+        c = dataclasses.replace(cfg, n_held=held, first_held=first)
+        return apply_moe_dropless(share(p, first, held), x, "swiglu", c, interpret=True)[0]
+
+    whole = part(0, 8)
+    parts = [part(2 * i, 2) for i in range(4)]
+    assert all(float(jnp.max(jnp.abs(part))) > 0 for part in parts)
+    np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(whole), atol=2e-5)
+    whole_d, _ = apply_moe_dense(p, x, "swiglu", cfg)
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(whole_d), atol=2e-5)
+
+
+def test_the_capacity_path_under_skewed_routing_fails_the_comparison():
+    """Negative control: at capacity_factor 1.0 a router that sends most
+    tokens to one expert overflows its buffer, and the dropped rows put the
+    capacity path far outside the tolerance the dropless layer meets."""
+    cfg, p, x = held_setup(jax.random.PRNGKey(9))
+    skewed = dict(p, router=p["router"].at[:, 0].add(3.0))
+    x = jnp.abs(x) + 0.5
+    capped = dataclasses.replace(cfg, capacity_factor=1.0)
+    out_cap, _ = apply_moe(skewed, x, "swiglu", capped)
+    out_drop, _ = apply_moe_dropless(skewed, x, "swiglu", cfg, interpret=True)
+    oracle, _ = apply_moe_dense(skewed, x, "swiglu", cfg)
+    np.testing.assert_allclose(np.asarray(out_drop), np.asarray(oracle), atol=2e-5)
+    assert float(jnp.max(jnp.abs(out_cap - oracle))) > 100 * 2e-5

@@ -7,8 +7,9 @@ so every interpret-mode test can pass while the chip refuses the kernel.
 Here each kernel is lowered and compiled for one chip of a described
 ``v5e:2x2`` topology, at the widths the training path runs (BERT-large
 attention and flat state, granite-3-2b GQA and granite-20b MQA attention
-at seq 4096), and the
-compiled program must hold the Mosaic custom call.
+at seq 4096, and the dropless expert layer's grouped matmuls at
+Mellum2-12B-A2.5B's widths), and the compiled program must hold the Mosaic
+custom call.
 
 The topology is described inside a module fixture — never at import — so
 pytest-xdist workers collect identical tests and only the worker that runs
@@ -99,6 +100,31 @@ def test_flash_decode_compiles(one_chip):
     _compile(one_chip, fn, ((b, lanes, h, d), BF16), ((b, cache, kv, d), BF16),
              ((b, cache, kv, d), BF16), ((b, lanes), I32), ((b, cache), I32),
              ((b, lanes), I32), ((b, cache), I32))
+
+
+# ---------------------------------------------------------------------------
+# the dropless expert layer's grouped matmuls at Mellum2-12B-A2.5B's widths:
+# 8 held experts, d_model 2304 <-> expert width 896, the 65,536-row buffer
+# of an 8192-token microbatch at top-8
+# ---------------------------------------------------------------------------
+
+GMM = {"up": (2304, 896), "down": (896, 2304)}
+
+
+@pytest.mark.parametrize("name", list(GMM))
+@pytest.mark.parametrize("grad", [False, True], ids=("fwd", "fwd_bwd"))
+def test_grouped_matmul_compiles(one_chip, name, grad):
+    from repro.kernels.grouped_matmul import grouped_matmul
+
+    k, n = GMM[name]
+
+    def fn(x, w, sizes):
+        if not grad:
+            return grouped_matmul(x, w, sizes)
+        return jax.grad(lambda x, w: jnp.sum(grouped_matmul(x, w, sizes).astype(jnp.float32)),
+                        argnums=(0, 1))(x, w)
+
+    _compile(one_chip, fn, ((65536, k), BF16), ((8, k, n), BF16), ((8,), I32))
 
 
 # ---------------------------------------------------------------------------
