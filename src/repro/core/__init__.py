@@ -15,6 +15,7 @@ from repro.core.gsnr import (  # noqa: F401
     gsnr_summary,
     normalize_per_layer,
     raw_gsnr,
+    stats_summary,
     variance,
 )
 from repro.core.noise_scale import (  # noqa: F401

@@ -6,10 +6,16 @@ squares with two Ring-AllReduces.  On a TPU mesh under shard_map we instead:
   * compute the local gradient of the local batch shard (pure DP over the
     "data" axis — this variant targets the replicated-params regime the
     paper ran; the sharded-params regime uses core/accumulate.py),
-  * stack [g_d, g_d^2] into ONE pytree and issue a SINGLE psum — the fused
-    collective halves the number of latency-bound reduction launches
+  * stack [g_d, g_d^2] into ONE payload and issue a SINGLE collective — the
+    fused collective halves the number of latency-bound reduction launches
     (beyond-paper optimization; ``fused=False`` reproduces the paper's
-    two-collective schedule for the §Perf comparison).
+    two-collective schedule for the §Perf comparison),
+  * on the flat path, where the flat optimizer state is split by rows over
+    the data axis (sharding/rules.py ``flat_buffer_pspec``), reduce-SCATTER
+    g_d and g_d^2 into those row shards instead of all-reducing them: each
+    device receives the summed statistics of its own rows only, which
+    halves the bytes on the links and leaves the clip, the norm and the
+    per-shard update a quarter of the buffer each on four devices.
 
 Statistics are identical to k-microbatch accumulation for equal group sizes
 (property-tested in tests/test_distributed.py).
@@ -24,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.gsnr import GradStats
+from repro.core.layout import LANE, FlatBuffer, ParamLayout
 
 PyTree = Any
 _tm = jax.tree_util.tree_map
@@ -38,27 +45,40 @@ def device_grad_stats_fn(
     flat: bool = False,
     backend=None,
     with_noise_terms: bool = False,
+    rules=None,
 ) -> Callable:
     """Returns f(params, batch) -> (loss, aux, GradStats) with device-wise k
     — or (loss, aux, GradStats, terms) when ``with_noise_terms``, where terms
     is the (2,) array [|G_big|², |G_small|²] the noise-scale estimator
     consumes (core/noise_scale.py).  The two norms reduce INSIDE shard_map
-    from the already-pmean'ed moment payload — they ride the existing fused
+    from the already-reduced moment payload — they ride the existing fused
     collective (a pre-reduction sum would be wrong for |E[g]|², and a
-    post-shard_map read of the replicated stats would be a second sweep),
-    adding two scalars and zero collectives/launches.
+    post-shard_map read of the stats would be a second sweep): replicated
+    moments sum locally, row-scattered ones sum their shard and add the two
+    scalars with one psum.
 
     params replicated, batch sharded over ``data_axis``.
 
     flat=True (the flat-state path; implied by a Backend plan whose stats
     subsystem is fused): the local gradient packs into the ParamLayout flat
-    buffer first, then ONE Pallas kernel (flat_stats.flat_pack_square)
-    emits the collective-shaped (2, rows, LANE) [g; g²] payload in a single
-    read of the buffer — no per-leaf tree copy, and no jnp
-    concatenate/split round-trip either — so the fused collective is one
-    pmean and mean/sq come back as views of the reduced payload, ready for
-    the single-launch optimizer kernels.  fused=False still reproduces the
-    paper's two-collective schedule, over flat carries.
+    buffer first.  Where the sharding rules of the flat state (``rules``:
+    the train step passes its FlatSpmd plan's; by default the active ones
+    for ``mesh`` when this is built, else fresh defaults) split its rows
+    over ``data_axis`` alone, g and g² are each reduce-scattered into that
+    split and divided by k: mean/sq come back as FlatBuffers sharded
+    exactly as the state, so the per-shard update reads them where they
+    land.  Both schedules take these two collectives: the TPU compiler
+    keeps a reduce-scatter only over the major dimension, so one collective
+    would need a shard-major [g; g²] payload, which XLA builds in two
+    passes over the buffer (a shard, 651,104 rows for BERT-large on four
+    devices, ends inside a 64-row kernel block); that costs more than the
+    second collective (on four TPU v5e chips: 14.0 ms a step for the
+    payload, 4.05 ms for g² alone, the exchange ~32.5 ms either way).
+    Elsewhere (rows replicated, or split over other axes too) the
+    statistics stay replicated: ONE Pallas kernel
+    (flat_stats.flat_pack_square) emits the (2, rows, LANE) payload in a
+    single read of the buffer and one pmean reduces it, mean/sq views of
+    the reduced payload; fused=False takes two pmeans.
     """
     resolved = None
     if backend is not None:
@@ -71,21 +91,26 @@ def device_grad_stats_fn(
 
         resolved = resolve_backend(backend, where="device_grad_stats_fn")
         flat = resolved.fused("stats")
+    if rules is None:
+        from repro.sharding.rules import rules_for  # sharding imports core
+
+        rules = rules_for(mesh)
     k = dict(mesh.shape)[data_axis]
     gfn = jax.value_and_grad(loss_fn, has_aux=has_aux)
 
-    def inner(params, batch):
+    def inner(params, batch, layout, scatter):
         out, g = gfn(params, batch)
         loss, aux = out if has_aux else (out, None)
         g = _tm(lambda x: x.astype(jnp.float32), g)
         if flat:
-            from repro.core.layout import ParamLayout
             from repro.kernels.flat_stats import flat_pack_square
             from repro.kernels.ops import _interp
 
-            layout = ParamLayout.for_tree(params)
             gf = layout.pack(g, jnp.float32)
-            if fused:
+            if scatter:  # this device's row shard of the sums, either schedule
+                mean = jax.lax.psum_scatter(gf, data_axis, tiled=True) / k
+                sq = jax.lax.psum_scatter(jnp.square(gf), data_axis, tiled=True) / k
+            elif fused:
                 # one kernel builds the [g; g²] payload in a single read of
                 # gf; mean/sq are views of the reduced payload, not copies
                 payload = flat_pack_square(gf, layout, interpret=_interp(resolved))
@@ -106,41 +131,38 @@ def device_grad_stats_fn(
         if has_aux:
             aux = jax.lax.pmean(aux, data_axis)
         if with_noise_terms:
-            # reduced moments are identical on every shard, so these sums
-            # need no further collective; flat buffers sum exactly (zero
-            # tail padding) and the tree path reduces leaf-wise
+            # flat buffers sum exactly (zero tail padding) and the tree path
+            # reduces leaf-wise; replicated moments need no further
+            # collective, row shards add their partial sums
             if flat:
-                g2_big = jnp.sum(jnp.square(mean))
-                g2_small = jnp.sum(sq)
+                terms = jnp.stack([jnp.sum(jnp.square(mean)), jnp.sum(sq)])
+                if scatter:
+                    terms = jax.lax.psum(terms, data_axis)
             else:
                 g2_big = sum(jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(mean))
                 g2_small = sum(jnp.sum(x) for x in jax.tree_util.tree_leaves(sq))
-            terms = jnp.stack([g2_big, g2_small])
+                terms = jnp.stack([g2_big, g2_small])
         else:
             terms = jnp.zeros((2,), jnp.float32)
-        return loss, aux, GradStats(mean=mean, sq_mean=sq, k=k), terms
-
-    # k is static; keep it outside shard_map and rebuild GradStats at the end
-    def inner2(params, batch):
-        loss, aux, stats, terms = inner(params, batch)
         aux_out = aux if has_aux else jnp.zeros(())
-        return loss, aux_out, stats.mean, stats.sq_mean, terms
-
-    smapped = jax.shard_map(
-        inner2,
-        mesh=mesh,
-        in_specs=(P(), P(data_axis)),
-        out_specs=(P(), P(), P(), P(), P()),
-        check_vma=False,
-    )
+        # k is static; keep it outside shard_map and rebuild GradStats after
+        return loss, aux_out, mean, sq, terms
 
     @functools.wraps(loss_fn)
     def fn(params, batch):
+        layout = ParamLayout.for_tree(params) if flat else None
+        rows = rules.flat_buffer_pspec((layout.n_rows, LANE)) if flat else P()
+        scatter = rows == P(data_axis, None)
+        moments = rows if scatter else P()
+        smapped = jax.shard_map(
+            functools.partial(inner, layout=layout, scatter=scatter),
+            mesh=mesh,
+            in_specs=(P(), P(data_axis)),
+            out_specs=(P(), P(), moments, moments, P()),
+            check_vma=False,
+        )
         loss, aux, mean, sq, terms = smapped(params, batch)
         if flat:
-            from repro.core.layout import FlatBuffer, ParamLayout
-
-            layout = ParamLayout.for_tree(params)
             mean, sq = FlatBuffer(mean, layout), FlatBuffer(sq, layout)
         stats = GradStats(mean=mean, sq_mean=sq, k=k)
         if with_noise_terms:

@@ -91,3 +91,36 @@ def gsnr_summary(scale: PyTree, gamma: float = 0.1) -> dict:
         "gsnr/min": jnp.min(flat),
         "gsnr/frac_floor": jnp.mean((flat <= gamma * (1 + 1e-5)).astype(jnp.float32)),
     }
+
+
+def stats_summary(stats: GradStats, gamma: float = 0.1, eps: float = 1e-12) -> dict:
+    """``gsnr_summary(gsnr_scale(stats, gamma, eps), gamma)``.
+
+    Flat moments are summarized where they lie: the per-leaf means of r are
+    a segment sum over the rows, and the zero tail padding is masked out of
+    the mean, the min and the floor share.  Moments sharded by rows (the
+    data-axis statistics' reduce-scatter, core/distributed.py) are then
+    never gathered back into leaves just to be logged.
+    """
+    from repro.core.layout import LANE, is_flat
+
+    if not is_flat(stats.mean):
+        return gsnr_summary(gsnr_scale(stats, gamma, eps), gamma)
+    layout = stats.mean.layout
+    m, s = stats.mean.data, stats.sq_mean.data
+    r = jnp.square(m) / (jnp.maximum(s - jnp.square(m), 0.0) + eps)  # raw_gsnr
+    ids = jnp.asarray(layout.row_leaf_ids())
+    sizes = jnp.asarray(layout.sizes, jnp.int32)
+    leaf_mean = jax.ops.segment_sum(
+        jnp.sum(r, axis=1), ids, num_segments=layout.n_leaves
+    ) / sizes.astype(jnp.float32)
+    scale = jnp.clip(r / jnp.maximum(leaf_mean, 1e-30)[ids][:, None], gamma, 1.0)
+    # an element's index inside its leaf: past the leaf's size is padding
+    row_in_leaf = jnp.arange(layout.n_rows) - jnp.asarray(layout.row_offsets, jnp.int32)[ids]
+    live = row_in_leaf[:, None] * LANE + jnp.arange(LANE) < sizes[ids][:, None]
+    n = float(sum(layout.sizes))
+    return {
+        "gsnr/mean": jnp.sum(jnp.where(live, scale, 0.0)) / n,
+        "gsnr/min": jnp.min(jnp.where(live, scale, jnp.inf)),
+        "gsnr/frac_floor": jnp.sum(live & (scale <= gamma * (1 + 1e-5))) / n,
+    }

@@ -128,9 +128,11 @@ def flat_pack_square(gf, layout: ParamLayout, interpret: bool | None = None):
     """(rows, LANE) flat gradient -> (2, rows, LANE) [g; g²] payload: one
     launch, ONE read of gf per block.
 
-    The output is the COLLECTIVE-SHAPED carry device_grad_stats_fn pmean's
-    across the data axis (mean = payload[0], sq = payload[1] are views, not
-    copies) — replacing the jnp concatenate([gf, square(gf)]) / split
+    The output is the COLLECTIVE-SHAPED carry device_grad_stats_fn pmeans
+    across the data axis where the statistics stay replicated (mean =
+    payload[0], sq = payload[1] are views, not copies; where the flat
+    state's rows are split over that axis it reduce-scatters g and g²
+    instead) — replacing the jnp concatenate([gf, square(gf)]) / split
     round-trip that re-read gf and materialized two extra copies of the
     buffer per step.  Grid derives from the LOCAL rows (_local_blocks) so
     the same wrapper runs per-shard under shard_map."""

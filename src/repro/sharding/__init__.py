@@ -7,4 +7,5 @@ from repro.sharding.rules import (  # noqa: F401
     param_pspecs,
     param_shardings,
     pspec_for_leaf,
+    rules_for,
 )

@@ -200,6 +200,13 @@ def active_rules() -> Optional[Rules]:
     return _ACTIVE
 
 
+def rules_for(mesh: Mesh) -> Rules:
+    """The active rules where they are ``mesh``'s, else fresh defaults for it."""
+    if _ACTIVE is not None and _ACTIVE.mesh is mesh:
+        return _ACTIVE
+    return Rules(mesh=mesh)
+
+
 @contextlib.contextmanager
 def activate(mesh: Mesh, **kw):
     global _ACTIVE

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.backend import resolve_backend
 from repro.configs.base import Config
-from repro.core import grad_only, grad_stats, gsnr_scale, gsnr_summary, make_optimizer
+from repro.core import grad_only, grad_stats, make_optimizer, stats_summary
 from repro.core.distributed import device_grad_stats_fn
 from repro.models import init_params
 from repro.models.common import global_norm
@@ -39,12 +39,9 @@ def _shard_plan(backend, mesh):
     gracefully when the buffer doesn't shard or divide)."""
     if mesh is None:
         return None
-    from repro.sharding.rules import Rules, active_rules
+    from repro.sharding.rules import rules_for
 
-    rules = active_rules()
-    if rules is None or rules.mesh is not mesh:
-        rules = Rules(mesh=mesh)
-    return backend.shard(mesh, rules)
+    return backend.shard(mesh, rules_for(mesh))
 
 
 def make_train_step(
@@ -61,7 +58,8 @@ def make_train_step(
     lr) to the metrics of every fresh-stats step.  They are jnp reductions
     over the already-materialized moment carry (core/noise_scale.py), so the
     step's pallas_call count is unchanged; on the data_axis source the two
-    norm readings ride the existing fused psum payload inside shard_map.
+    norm readings are taken inside shard_map from the reduced moments (on
+    row shards, one psum of the two scalars; core/distributed.py).
     """
     opt_cfg = cfg.optimizer
     bk = resolve_backend(cfg.parallel, where="make_train_step")
@@ -76,7 +74,7 @@ def make_train_step(
     if use_device_stats:
         stats_fn = device_grad_stats_fn(
             lambda p, b: loss_fn(p, b), mesh, has_aux=True, backend=bk,
-            with_noise_terms=noise_scale,
+            with_noise_terms=noise_scale, rules=spmd.rules,
         )
     if noise_scale:
         from repro.core import noise_scale as ns
@@ -136,7 +134,7 @@ def make_train_step(
             **(aux or {}),
         }
         if log_gsnr and stats is not None:
-            metrics.update(gsnr_summary(gsnr_scale(stats, opt_cfg.gamma), opt_cfg.gamma))
+            metrics.update(stats_summary(stats, opt_cfg.gamma))
         if noise_scale:
             metrics["lr"] = lr_dbg(state.step)
             if noise_est is not None:

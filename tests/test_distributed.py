@@ -36,7 +36,8 @@ for fused in (True, False):
     assert s1.k == 8
 
 # flat path: stats arrive as FlatBuffers, identical statistics, and the
-# single all-reduce runs over the contiguous flat carry (no stacked tree copy)
+# stats collectives are reduce-scatters of the contiguous flat g and g²
+# into row shards (no stacked tree copy, no all-reduce of the statistics)
 f = jax.jit(device_grad_stats_fn(loss_fn, mesh, flat=True))
 l3, _, s3 = f(params, (X, Y))
 _, _, s2 = grad_stats(loss_fn, params, (X, Y), 8)
@@ -46,8 +47,9 @@ s3t = s3.as_tree()
 assert np.allclose(s3t.mean["w"], s2.mean["w"], rtol=1e-4, atol=1e-6)
 assert np.allclose(s3t.sq_mean["w"], s2.sq_mean["w"], rtol=1e-4, atol=1e-6)
 txt = f.lower(params, (X, Y)).compile().as_text()
-n_ar = txt.count(" all-reduce(")
-assert n_ar <= 2, f"expected one flat stats reduction, got {n_ar} all-reduces"
+n_rs, n_ar = txt.count(" reduce-scatter("), txt.count(" all-reduce(")
+assert n_rs == 2, f"expected the flat g and g² reduce-scatters, got {n_rs}"
+assert n_ar <= 1, f"expected no all-reduce but the loss's, got {n_ar}"
 
 # fused path emits exactly ONE all-reduce for the stats payload
 txt = jax.jit(device_grad_stats_fn(loss_fn, mesh, fused=True)).lower(params, (X, Y)).compile().as_text()

@@ -103,3 +103,34 @@ def test_multi_layer_normalization_independent():
     r = normalize_per_layer(raw_gsnr(stats))
     assert float(jnp.mean(r["a"])) == pytest.approx(1.0, rel=1e-4)
     assert float(jnp.mean(r["b"])) == pytest.approx(1.0, rel=1e-4)
+
+
+@pytest.mark.parametrize("shapes", [
+    {"w": (3,)},  # one leaf, nearly all padding
+    {"a": (40, 24), "b": (24,), "c": ()},  # several leaves and a scalar
+    {"e": (130, 64), "f": (64 * 128 + 5,), "g": (7, 3)},  # past one 64-row block, ragged tails
+], ids=("one", "several", "blocks"))
+@pytest.mark.parametrize("gamma", [0.1, 0.5, 1.0])
+def test_flat_summary_equals_the_tree_pipelines(shapes, gamma):
+    """stats_summary on flat moments (segment sums over rows, tail padding
+    masked) reads what gsnr_summary(gsnr_scale(...)) reads on the tree."""
+    from repro.core import gsnr_summary, stats_summary
+    from repro.core.layout import FlatBuffer, ParamLayout
+
+    rng = np.random.RandomState(len(shapes))
+    mean = {n: jnp.asarray(rng.uniform(-3, 3, s), jnp.float32) for n, s in shapes.items()}
+    # a fifth of the coordinates noiseless (r at its largest), the rest noisy
+    extra = {n: jnp.asarray(rng.uniform(0, 9, s) * (rng.uniform(size=s) > 0.2), jnp.float32)
+             for n, s in shapes.items()}
+    tree = GradStats(mean=mean, sq_mean=jax.tree_util.tree_map(
+        lambda m, e: jnp.square(m) + e, mean, extra), k=4)
+    layout = ParamLayout.for_tree(mean)
+    assert layout.n_rows * 128 > sum(layout.sizes)  # padding is there to mask
+    flat = GradStats(mean=FlatBuffer(layout.pack(tree.mean, jnp.float32), layout),
+                     sq_mean=FlatBuffer(layout.pack(tree.sq_mean, jnp.float32), layout), k=4)
+    got = stats_summary(flat, gamma)
+    want = gsnr_summary(gsnr_scale(tree, gamma), gamma)
+    assert got.keys() == want.keys()
+    for key in want:
+        np.testing.assert_allclose(np.asarray(got[key]), np.asarray(want[key]),
+                                   rtol=1e-5, atol=1e-7, err_msg=key)

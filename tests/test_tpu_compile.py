@@ -19,6 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -30,7 +31,7 @@ BF16, I32 = jnp.bfloat16, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     from jax.experimental import topologies
@@ -38,10 +39,22 @@ def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler library here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The four chips of the described host as a data-parallel mesh."""
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices[:4]), ("data",))
 
 
 @pytest.fixture(scope="module")
@@ -257,3 +270,38 @@ def test_the_scopes_rename_no_kernel_of_the_fused_step(one_chip):
     assert all(f"/{obs.STATS_ACCUM}/" in n for n in by_kernel["flat_moments_accum"])
     assert all(f"/{obs.STATS_FINALIZE}/" in n for n in by_kernel["flat_moments_finalize"])
     assert all(f"/{obs.OPTIMIZER}/" in n for n in by_kernel["flat_vr_lamb"])
+
+
+# ---------------------------------------------------------------------------
+# the data-axis GSNR statistics across the four chips
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=("fused", "two"))
+def test_data_axis_statistics_reduce_scatter_on_four_chips(four_chips, fused):
+    """The [g; g²] exchange compiles to two reduce-scatters into the row
+    shards, one of g and one of g², in either schedule, and no all-reduce of
+    a flat buffer or more is left.  The buffer is 32 MiB: past 16 MiB the
+    compiler lowers a reduce-scatter it cannot keep, such as one over the
+    middle dimension of a (2, rows, LANE) payload, as an all-reduce of the
+    whole payload and a slice."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.distributed import device_grad_stats_fn
+
+    n = 8 * 1024 * 1024  # 65,536 flat rows
+    params = {"w": jax.ShapeDtypeStruct((n,), jnp.float32,
+                                        sharding=NamedSharding(four_chips, P()))}
+    batch = jax.ShapeDtypeStruct((4, n), jnp.float32,
+                                 sharding=NamedSharding(four_chips, P("data")))
+    loss = lambda p, b: jnp.mean(jnp.square(p["w"] - b))
+    fn = device_grad_stats_fn(loss, four_chips, fused=fused, flat=True)
+    text = jax.jit(fn).lower(params, batch).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    scatters = len(re.findall(r"calls=%all-reduce-scatter| reduce-scatter\(", entry))
+    assert scatters == 2, scatters
+    reduced = re.findall(r"= f32\[([\d,]+)\]\S* all-reduce(?:-start)?\(", entry)
+    full = (n // LANE) * LANE
+    assert all(np.prod([int(d) for d in dims.split(",")]) < full for dims in reduced), reduced
