@@ -1,26 +1,22 @@
 """VRGD systems cost (not a paper table; the deployment question the paper
 leaves implicit): step-time overhead of GSNR statistics + the fused-kernel
-win on the update math.
+win on the moment accumulation.
 
   a) trainer overhead: base optimizer vs VR at equal k-microbatch structure
      (isolates the Σg² accumulation + GSNR pipeline cost),
-  b) update-math microbench: jnp GSNR pipeline vs fused Pallas kernel
-     (interpret mode on CPU — structural check; wall-clock wins are TPU),
+  b) update-math microbench: the jnp GSNR pipeline,
   c) accumulation microbench: the paper scan body's two jnp moment tree
      passes vs the fused Pallas sweep (kernels/flat_stats.py), end to end
      through grad_stats under a fused-stats Backend plan, reporting the
      fused/unfused delta.
-  d) flat vs per-leaf dispatch: the single-launch flat-buffer optimizer step
-     (kernels/flat_update.py) against PR 1's kernel-per-leaf loop, reporting
-     step latency and the structural pallas_call launch counts, emitted
-     machine-readable to BENCH_flat_state.json so the perf trajectory is
-     tracked across PRs.
-
+  d) packed vs unpacked fused attention, fwd and grad, with launch counts;
   e) structural cost model (benchmarks/cost_model.py): per-step block
      visits, HBM bytes, and MXU FLOPs counted by replaying the kernels'
      real grid specs and index maps, with the superseded geometries
      (split backward, identity fetch, phase-blind updates) as baselines —
      hardware-independent, and gated on the claimed reductions.
+
+d) and e) are written machine-readable to BENCH_flat_state.json.
 
 Every machine-readable record carries the fully-resolved backend ``plan``
 (Backend.describe(): per-subsystem fused/reference + interpret + platform)
@@ -92,11 +88,6 @@ def update_math(fast: bool) -> None:
     dt_j, _ = timed(jnp_path, stats)
     emit("update_math_jnp", dt_j * 1e6, f"n={n}")
 
-    from repro.kernels.vr_update import vr_scale
-
-    dt_k, _ = timed(lambda: vr_scale(g, g2, 0.1, 1e-12))
-    emit("update_math_pallas_interpret", dt_k * 1e6, f"n={n};note=CPU-interpret")
-
 
 def accumulation(fast: bool) -> None:
     """Fused vs unfused k-group moment accumulation (the scan-body Σg/Σg²).
@@ -138,83 +129,6 @@ def accumulation(fast: bool) -> None:
         0.0,
         f"fused/unfused={times[True]/times[False]:.3f} (TPU is the real number)",
     )
-
-
-def flat_vs_per_leaf(fast: bool) -> dict:
-    """Single-launch flat update vs PR 1's kernel-per-leaf dispatch.
-
-    Same optimizer math, same multi-leaf param tree: the delta isolates the
-    per-leaf pad/unpad DMA + launch overhead the flat refactor removes.  On
-    CPU the Pallas numbers carry interpreter overhead (structural check);
-    the launch counts are the hardware-independent part of the story.
-    """
-    import sys
-
-    tests_dir = os.path.join(os.path.dirname(__file__), "..", "tests")
-    if tests_dir not in sys.path:  # the per-leaf reference dispatch lives there
-        sys.path.insert(0, tests_dir)
-    import oracle
-
-    from repro.configs.base import OptimizerConfig
-    from repro.core import GradStats, make_optimizer
-    from repro.kernels.ops import count_pallas_calls
-
-    _tm = jax.tree_util.tree_map
-    params = oracle.hostile_params()
-    n_leaves = len(jax.tree_util.tree_leaves(params))
-    g = _tm(lambda x: x * 0.01, params)
-    stats = GradStats(mean=g, sq_mean=_tm(lambda x: jnp.square(x) + 1e-3, g), k=8)
-    cfg = OptimizerConfig(name="vr_lamb", lr=0.01, schedule="constant", weight_decay=0.01)
-
-    iters = 2 if fast else 4
-    plan = Backend.all_fused()
-    opt = make_optimizer(cfg, backend=plan)
-    s_flat = opt.init(params)
-    flat_fn = jax.jit(lambda s: opt.update(g, s, params, stats=stats))
-    n_flat = count_pallas_calls(jax.make_jaxpr(flat_fn)(s_flat))
-    dt_flat, _ = timed(flat_fn, s_flat, warmup=1 if fast else 2, iters=iters)
-
-    z = lambda: _tm(lambda x: jnp.zeros(x.shape, jnp.float32), params)
-    zero = jnp.zeros((), jnp.int32)
-    s_leaf = {"step": zero, "pt": zero, "m": z(), "v": z(), "p": z()}
-    leaf_fn = jax.jit(
-        lambda s: oracle.per_leaf_vr_lamb_update(
-            g, s, stats, 0.01, 0.9, 0.999, 0.9, 1e-6, 0.01, 0.1, 1e-12, params
-        )
-    )
-    n_leafcalls = count_pallas_calls(jax.make_jaxpr(leaf_fn)(s_leaf))
-    dt_leaf, _ = timed(leaf_fn, s_leaf, warmup=1 if fast else 2, iters=iters)
-
-    emit("flat_update_step", dt_flat * 1e6, f"launches={n_flat};note=CPU-interpret")
-    emit(
-        "per_leaf_update_step", dt_leaf * 1e6,
-        f"launches={n_leafcalls};leaves={n_leaves};note=CPU-interpret",
-    )
-    emit(
-        "flat_vs_per_leaf_ratio", 0.0,
-        f"flat/per_leaf={dt_flat/dt_leaf:.3f};launches {n_flat} vs {n_leafcalls} (TPU is the real number)",
-    )
-    return {
-        "optimizer": "vr_lamb",
-        "n_leaves": n_leaves,
-        # measurement config: key-wise checked against every other record's
-        # config by common.check_configs_agree (cost_model counts the same
-        # hostile layout, so flat.params/state_dtype must line up)
-        "config": {"flat": {"params": "oracle.hostile_params",
-                            "optimizer_name": "vr_lamb",
-                            "state_dtype": "float32"}},
-        # the resolved execution plan: per-subsystem fused/reference plus
-        # interpret + platform.  interpret=True means the latency numbers are
-        # CPU-interpret (structural only); TPU reruns write interpret=False,
-        # so the perf trajectory can never silently mix interpreter and
-        # hardware measurements — run.py refuses mixed-plan records outright.
-        "plan": plan.describe(),
-        "interpret": plan.interpret_mode(),
-        "backend": jax.default_backend(),
-        "flat": {"launches": n_flat, "us_per_step": dt_flat * 1e6},
-        "per_leaf": {"launches": n_leafcalls, "us_per_step": dt_leaf * 1e6},
-        "note": "CPU interpret mode: latency is structural only; launch counts are hardware-independent",
-    }
 
 
 def packed_attention(fast: bool) -> dict:
@@ -294,7 +208,7 @@ def main(fast: bool = False) -> None:
     # measurement configs conflict key-wise (common.py); cost_compute also
     # gates the PR's claimed structural reductions (cost_model.check_claims)
     rec = merge_bench_records(
-        flat_vs_per_leaf(fast),
+        {},
         packed_attention=packed_attention(fast),
         cost_model=cost_compute(fast=fast),
     )

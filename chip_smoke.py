@@ -104,11 +104,10 @@ def run(label, cfg, batches, *, mesh=None, keys=("loss", "grad_norm", "update_no
         state, batches = place_on_mesh(state, batches, mesh)
         # the next step's state keeps the placement this one was compiled for
         out = (jax.tree_util.tree_map(lambda x: x.sharding, state), NamedSharding(mesh, P()))
-        jitted = jax.jit(lambda s, b: step_fn(s, b, True), donate_argnums=0,
-                         out_shardings=out)
+        jitted = jax.jit(step_fn, donate_argnums=0, out_shardings=out)
     else:
         batches = [jax.device_put(b) for b in batches]
-        jitted = jax.jit(lambda s, b: step_fn(s, b, True), donate_argnums=0)
+        jitted = jax.jit(step_fn, donate_argnums=0)
     t0 = time.perf_counter()
     compiled = jitted.lower(state, batches[0]).compile()
     compile_s = time.perf_counter() - t0

@@ -6,9 +6,8 @@ and the analyzer (rules.LAUNCH-COUNT traces the cheap entries and compares).
 When the next kernel fusion changes a count, update THIS table — the tests
 and the analyzer follow.  Keys group by surface:
 
-  flat_update            one fused optimizer launch per fresh VRGD step
-  flat_update_stale      amortized-GSNR steps are pure jnp flat math
-  grad_stats_*           scan accum + finalize / g-only stale / vmap stack
+  flat_update            one fused optimizer launch per VRGD step
+  grad_stats_scan        scan accum + finalize
   attention_*            custom-VJP structure: 1 primal, 2 under jax.grad
                          (LSE-emitting fwd + fused one-pass dq/dk/dv bwd)
   model_forward_*        attention dispatch under a Backend plan
@@ -27,11 +26,8 @@ from typing import Dict, List
 LAUNCHES: Dict[str, int] = {
     # gathered flat-buffer optimizer update
     "flat_update": 1,
-    "flat_update_stale": 0,
     # gradient-moment accumulation
     "grad_stats_scan": 2,
-    "grad_stats_scan_stale": 1,
-    "grad_stats_vmap": 1,
     # flash-attention custom VJP
     "attention_primal": 1,
     "attention_grad": 2,
@@ -41,7 +37,6 @@ LAUNCHES: Dict[str, int] = {
     # end-to-end composites (consumed by tests only)
     "train_step_fused": 6,   # attn fwd + remat LSE fwd + fused bwd + 2 stats + update
     "train_step_packed": 6,  # packed positions ride the same calls as operands
-    "train_step_stale": 4,   # attn fwd + remat fwd + fused bwd + g-only accum
     # dynamic-k autoscale path: the noise-scale readings (core/noise_scale.py)
     # are jnp reductions over the already-materialized moment carry, so a
     # noise_scale=True step launches EXACTLY what train_step_fused does —
@@ -51,16 +46,11 @@ LAUNCHES: Dict[str, int] = {
     # SPMD per-shard flat path (shard_map; subprocess tests)
     "spmd_update": 2,  # r-partials + apply, per shard
     "spmd_grad_stats_scan": 2,
-    "spmd_grad_stats_stale": 1,
     "spmd_train_step": 7,  # train_step_fused with the update split in two
 }
 
 # The subset the analyzer traces itself (cheap jaxprs, a few seconds total).
-TRACED = (
-    "flat_update", "flat_update_stale",
-    "grad_stats_scan", "grad_stats_scan_stale", "grad_stats_vmap",
-    "attention_primal", "attention_grad",
-)
+TRACED = ("flat_update", "grad_stats_scan", "attention_primal", "attention_grad")
 
 
 def _count(fn, *args) -> int:
@@ -84,7 +74,7 @@ def traced_counts() -> Dict[str, int]:
     tm = jax.tree_util.tree_map
     counts: Dict[str, int] = {}
 
-    # optimizer update: fresh (fused launch) and stale (pure jnp flat math)
+    # optimizer update: one fused launch
     params = tm(jnp.asarray, demo_tree("hostile"))
     g = tm(lambda x: x + 0.01, params)
     stats = GradStats(mean=g, sq_mean=tm(lambda x: x * x + 1e-3, g), k=8)
@@ -94,11 +84,8 @@ def traced_counts() -> Dict[str, int]:
     state = opt.init(params)
     counts["flat_update"] = _count(
         lambda s: opt.update(g, s, params, stats=stats), state)
-    _, state1 = opt.update(g, state, params, stats=stats)
-    counts["flat_update_stale"] = _count(
-        lambda s: opt.update(g, s, params, stats=None), state1)
 
-    # grad stats: scan accum+finalize, g-only stale scan, vmap stack
+    # grad stats: scan accum + finalize
     lin = {"w": jnp.ones(300), "b": jnp.zeros(())}
 
     def loss_fn(p, b):
@@ -106,14 +93,8 @@ def traced_counts() -> Dict[str, int]:
         return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
 
     batch = (jnp.ones((16, 300)), jnp.ones((16,)))
-    fused = Backend.all_fused()
     counts["grad_stats_scan"] = _count(
-        lambda p, b: grad_stats(loss_fn, p, b, 4, backend=fused)[2], lin, batch)
-    counts["grad_stats_scan_stale"] = _count(
-        lambda p, b: grad_stats(loss_fn, p, b, 4, squares=False, backend=fused)[2],
-        lin, batch)
-    counts["grad_stats_vmap"] = _count(
-        lambda p, b: grad_stats(loss_fn, p, b, 4, method="vmap", backend=fused)[2],
+        lambda p, b: grad_stats(loss_fn, p, b, 4, backend=Backend.all_fused())[2],
         lin, batch)
 
     # attention custom VJP: primal vs jax.grad structure

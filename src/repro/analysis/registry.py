@@ -1,10 +1,10 @@
 """Kernel registry: every ``pallas_call`` site declares its contract here.
 
 Each kernel module (kernels/flash_attention.py, flash_attention_bwd.py,
-flash_decode.py, flat_update.py, flat_stats.py, flat_spmd.py,
-grad_stats.py) calls ``register_kernel`` at import time with a geometry
-BUILDER — a zero-cost closure over the kernel's own single-source-of-truth
-spec constructors (fwd_geometry, _phased_specs, _blk, ...) — plus the
+flash_decode.py, flat_update.py, flat_stats.py, flat_spmd.py) calls
+``register_kernel`` at import time with a geometry BUILDER — a zero-cost
+closure over the kernel's own single-source-of-truth spec constructors
+(fwd_geometry, _phased_specs, _blk, ...) — plus the
 configs (representative and hostile) the analyzer replays it at.  Nothing
 heavy runs at registration; geometries materialize only inside
 ``repro.analysis.check``.
@@ -96,11 +96,6 @@ KERNEL_MODULES = (
     "repro.kernels.flat_update",
     "repro.kernels.flat_stats",
     "repro.kernels.flat_spmd",
-    "repro.kernels.grad_stats",
-    # per-leaf legacy path (reference backend's fused per-tensor kernels)
-    "repro.kernels.vr_update",
-    "repro.kernels.vr_adam",
-    "repro.kernels.vr_lamb",
 )
 
 

@@ -323,18 +323,6 @@ class FlatSpmd:
         )
         return tuple(o[: layout.n_rows] for o in out)
 
-    def g_accum(self, gs, g, layout):
-        from repro.kernels import flat_stats as fs
-
-        interp = self._interp()
-        pad = self._pad_rows(layout)
-        row = self._row_spec(layout)
-        body = lambda a, b: fs.flat_g_accum(a, b, layout, interpret=interp)
-        out = self._smap(body, (row, row), row)(
-            self._padded(gs, pad), self._padded(g, pad)
-        )
-        return out[: layout.n_rows]
-
     def moments_finalize(self, gs, g2s, k, layout):
         from repro.kernels import flat_stats as fs
 

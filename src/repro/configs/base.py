@@ -200,8 +200,6 @@ class OptimizerConfig:
     k: int = 8  # statistic groups; paper: min devices holding LB, >= 8
     gsnr_source: str = "microbatch"  # microbatch | data_axis
     gsnr_eps: float = 1e-12
-    stats_method: str = "scan"  # scan (paper) | vmap (shared FSDP gathers)
-    gsnr_refresh: int = 1  # recompute GradStats every R steps (1 = paper)
     state_dtype: str = "float32"  # storage dtype for m/v/p moments (math in f32)
     # --- batch-size LR scaling (paper §6; live rescale via train/autoscale) ---
     base_batch: int = 0  # reference batch cfg.lr was tuned at; 0 = no rescale

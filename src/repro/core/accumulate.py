@@ -64,8 +64,6 @@ def grad_stats(
     k: int,
     *,
     has_aux: bool = False,
-    method: str = "scan",
-    squares: bool = True,
     backend: Optional[Backend] = None,
     spmd=None,
     use_pallas=None,
@@ -74,127 +72,66 @@ def grad_stats(
 
     loss_fn(params, microbatch) -> loss  (or (loss, aux) when has_aux).
 
-    method="scan" (paper-faithful accumulation): sequential lax.scan; memory
-    cost is two f32 trees regardless of k, but under FSDP the per-microbatch
-    parameter all-gathers repeat k times (loop-multiplied collective traffic
-    — measured in EXPERIMENTS.md §Perf).
-
-    method="vmap" (beyond-paper): one vmapped backward over the k groups —
-    every layer's FSDP gather is shared across groups (k x fewer all-gather
-    bytes) at the cost of a transient (k, param)-shaped gradient stack.
-    Right choice for <= ~20B-param models; scan remains the default for
-    memory-critical giants.
+    A sequential lax.scan over the k microbatches (the paper's gradient
+    accumulation): memory holds the two f32 moment carries, Σg and Σg²,
+    whatever k is.
 
     backend: the execution plan (repro.backend.Backend; the deprecated
     boolean keyword maps through the shim there, warning once).  With a
-    fused ``stats`` subsystem the GradStats carry lives as a ParamLayout
-    flat buffer (core/layout.py).  Under method="scan" each microbatch's
-    moment update (g_sum += g; g2_sum += g²) is ONE fused pallas_call over
-    the flat carry (kernels/flat_stats.py) — the gradient tree is packed
-    once per microbatch and the terminal /k normalize is a second single
-    call; squares=False (amortized-GSNR stale steps) runs the g-only flat
-    accumulation kernel instead, so stale steps stay fully flat with no jnp
-    tree carry.  Under method="vmap" the whole (k, param) gradient stack
-    reduces to (mean, sq_mean) in one call.  Either way the returned
-    GradStats carries FlatBuffers, already contiguous for the single-launch
-    optimizer kernels; statistics are identical to the jnp path
-    (oracle-tested).  spmd (Backend.shard) runs the SCAN path's flat sweeps
-    per-shard under shard_map on FSDP-sharded buffer rows; the vmap path
-    keeps the gathered one-launch reduction (its (k, param) stack has no
-    per-shard wrapper yet — same graceful fallback as an unsupported
-    layout).
+    fused ``stats`` subsystem the carries live as ParamLayout flat buffers
+    (core/layout.py): each microbatch's moment update (g_sum += g;
+    g2_sum += g²) is ONE fused pallas_call over the flat carries
+    (kernels/flat_stats.py) — the gradient tree is packed once per
+    microbatch — and the terminal /k normalize is a second single call.
+    The returned GradStats then carries FlatBuffers, already contiguous for
+    the single-launch optimizer kernels; statistics are identical to the
+    jnp path (oracle-tested).  spmd (Backend.shard) runs those flat sweeps
+    per-shard under shard_map on FSDP-sharded buffer rows.
     """
     bk = resolve_backend(backend, use_pallas=use_pallas, where="grad_stats")
     fused_stats = bk.fused("stats")
     mb = split_batch(batch, k)
-    if method == "vmap":
-        gfn = jax.value_and_grad(loss_fn, has_aux=has_aux)
-        outs, gs = jax.vmap(gfn, in_axes=(None, 0))(params, mb)
-        loss, aux = outs if has_aux else (outs, None)
-        gs = _tm(lambda x: x.astype(jnp.float32), gs)
-        if fused_stats and squares:
-            from repro.core.layout import ParamLayout
-            from repro.kernels import ops as kops
-
-            stats = kops.vmap_moments_flat(gs, ParamLayout.for_tree(params), k, backend=bk)
-        elif fused_stats:  # g-only: one mean over the packed stack, stays flat
-            from repro.core.layout import FlatBuffer, ParamLayout
-            from repro.kernels import ops as kops
-
-            layout = ParamLayout.for_tree(params)
-            gstack = jax.vmap(lambda t: layout.pack(t, jnp.float32))(gs)
-            stats = GradStats(
-                mean=FlatBuffer(jnp.mean(gstack, axis=0), layout), sq_mean=None, k=k
-            )
-        else:
-            stats = GradStats(
-                mean=_tm(lambda x: jnp.mean(x, axis=0), gs),
-                sq_mean=(
-                    _tm(lambda x: jnp.mean(jnp.square(x), axis=0), gs) if squares else None
-                ),
-                k=k,
-            )
-        aux_out = _tm(lambda x: jnp.mean(x, axis=0), aux) if has_aux else None
-        return jnp.mean(loss), aux_out, stats
     gfn = jax.value_and_grad(loss_fn, has_aux=has_aux)
     if fused_stats:
-        from repro.core.layout import FlatBuffer, ParamLayout
+        from repro.core.layout import ParamLayout
         from repro.kernels import ops as kops
 
         layout = ParamLayout.for_tree(params)
 
     def step(carry, microbatch):
-        loss_sum, aux_sum, g_sum = carry[:3]
+        loss_sum, aux_sum, g_sum, g2_sum = carry
         out, g = gfn(params, microbatch)
         loss, aux = out if has_aux else (out, aux_sum)
         g = _tm(lambda x: x.astype(jnp.float32), g)
         aux_new = _tm(jnp.add, aux_sum, aux) if has_aux else aux_sum
-        if fused_stats and squares:
+        if fused_stats:
             g_sum, g2_sum = kops.moments_accum_flat(
-                g_sum, carry[3], g, layout, backend=bk, spmd=spmd
+                g_sum, g2_sum, g, layout, backend=bk, spmd=spmd
             )
-            return (loss_sum + loss, aux_new, g_sum, g2_sum), None
-        if fused_stats:  # stale: g-only flat accumulation, no Σg² stream
-            g_sum = kops.g_accum_flat(g_sum, g, layout, backend=bk, spmd=spmd)
-            return (loss_sum + loss, aux_new, g_sum), None
-        g_sum = _tm(jnp.add, g_sum, g)
-        new = (loss_sum + loss, aux_new, g_sum)
-        if squares:  # amortized-GSNR stale steps skip the Σg² tree entirely
-            new += (_tm(lambda a, x: a + jnp.square(x), carry[3], g),)
-        return new, None
+        else:
+            g_sum = _tm(jnp.add, g_sum, g)
+            g2_sum = _tm(lambda a, x: a + jnp.square(x), g2_sum, g)
+        return (loss_sum + loss, aux_new, g_sum, g2_sum), None
 
     aux0 = None
     if has_aux:
         # probe aux structure abstractly (zeros of the right shapes)
         aux_shape = jax.eval_shape(lambda p, b: loss_fn(p, b)[1], params, _tm(lambda x: x[0], mb))
         aux0 = _tm(lambda s: jnp.zeros(s.shape, s.dtype), aux_shape)
-    if fused_stats and squares:
+    if fused_stats:
         g0, g20 = kops.moments_init_flat(layout)
-        carry0 = (jnp.zeros((), jnp.float32), aux0, g0, g20)
-    elif fused_stats:
-        carry0 = (jnp.zeros((), jnp.float32), aux0, layout.zeros(jnp.float32))
     else:
-        zeros = _tm(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        carry0 = (jnp.zeros((), jnp.float32), aux0, zeros)
-        if squares:
-            carry0 += (_tm(jnp.zeros_like, zeros),)
-    out_carry, _ = jax.lax.scan(step, carry0, mb)
-    loss_sum, aux_sum = out_carry[:2]
+        g0 = _tm(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        g20 = _tm(jnp.zeros_like, g0)
+    (loss_sum, aux_sum, g_sum, g2_sum), _ = jax.lax.scan(
+        step, (jnp.zeros((), jnp.float32), aux0, g0, g20), mb
+    )
     inv = 1.0 / k
-    if fused_stats and squares:
-        stats = kops.moments_finalize_flat(
-            out_carry[2], out_carry[3], k, layout, backend=bk, spmd=spmd
-        )
-    elif fused_stats:
-        # /k on the single flat carry: element-wise, XLA-fused (no launch)
-        stats = GradStats(mean=FlatBuffer(out_carry[2] * inv, layout), sq_mean=None, k=k)
+    if fused_stats:
+        stats = kops.moments_finalize_flat(g_sum, g2_sum, k, layout, backend=bk, spmd=spmd)
     else:
-        g_sum = out_carry[2]
-        g2_sum = out_carry[3] if squares else None
         stats = GradStats(
-            mean=_tm(lambda x: x * inv, g_sum),
-            sq_mean=_tm(lambda x: x * inv, g2_sum) if squares else None,
-            k=k,
+            mean=_tm(lambda x: x * inv, g_sum), sq_mean=_tm(lambda x: x * inv, g2_sum), k=k
         )
     aux_out = _tm(lambda x: x * inv, aux_sum) if has_aux else None
     return loss_sum * inv, aux_out, stats

@@ -77,11 +77,7 @@ def noise_terms(stats: GradStats, *, per_leaf: bool = False) -> NoiseTerms:
     fall back to a leaf-wise reduction — identical values (property-tested).
     """
     if stats.sq_mean is None:
-        raise ValueError(
-            "noise_terms needs second moments (GradStats.sq_mean is None — "
-            "this is a squares=False stale-step carry; estimate on refresh "
-            "steps only)"
-        )
+        raise ValueError("noise_terms needs second moments: GradStats.sq_mean is None")
     from repro.core.layout import is_flat
 
     if is_flat(stats.mean):

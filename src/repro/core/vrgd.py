@@ -28,10 +28,7 @@ kernels/ops.py) — per-leaf mean(r) and trust-ratio reductions run as grid
 phases inside the kernel, so there is no jnp prepass and no per-leaf
 dispatch loop.  An optional ``spmd`` plan (``Backend.shard(mesh, rules)``)
 reroutes those calls through per-shard shard_map pipelines on FSDP-sharded
-buffer rows.  Amortized-GSNR "stale" steps (no Σg² tree) run the same
-element-wise jnp math below directly on the flat buffers: because
-FlatBuffer is a pytree node, ``_vr_adam_dir`` works unchanged, fully
-XLA-fused over a single array.  The jnp path here is the oracle either way.
+buffer rows.  The jnp path here is the oracle.
 """
 from __future__ import annotations
 
@@ -43,7 +40,7 @@ import jax.numpy as jnp
 from repro.backend import Backend, resolve_backend
 from repro.core import baselines as B
 from repro.core.gsnr import GradStats, gsnr_scale
-from repro.core.layout import FlatBuffer, ParamLayout, as_flat, is_flat
+from repro.core.layout import FlatBuffer, ParamLayout, is_flat
 
 PyTree = Any
 _tm = jax.tree_util.tree_map
@@ -122,37 +119,24 @@ def vr_momentum(
 def _vr_adam_dir(grads, state, stats, b1, b2, b3, eps, gamma, gsnr_eps, state_dtype="float32"):
     """Shared VR-Adam machinery (Alg. 3 lines 8-17). Returns (dir, new_state).
 
-    Moments are *stored* in state_dtype (bf16 halves optimizer HBM for the
-    §Perf memory hillclimb) but all math runs in f32.
-
-    AMORTIZED GSNR (beyond-paper, EXPERIMENTS §Perf): when ``stats is None``
-    the GSNR momentum p_t is left untouched and the *stale* p̂ rescales the
-    fresh gradient — sound because the paper itself smooths GSNR with
-    b3=0.9 momentum (a half-life of ~6.6 steps), so a refresh period R << 7
-    changes p̂ negligibly while skipping the Σg² pass entirely on (R-1)/R
-    steps.  ``pt`` counts p updates for its bias correction.
+    Moments are *stored* in state_dtype (bf16 halves optimizer HBM) but all
+    math runs in f32.
     """
     t = state["step"] + 1
     tf = t.astype(jnp.float32)
     f32 = lambda tree: _tm(lambda x: x.astype(jnp.float32), tree)
     sd = jnp.dtype(state_dtype)
     store = lambda tree: _tm(lambda x: x.astype(sd), tree)
-    pt = state.get("pt", state["step"])
-    if stats is not None:
-        r = gsnr_scale(stats, gamma, gsnr_eps)
-        p = _tm(lambda p_, r_: b3 * p_ + (1 - b3) * r_, f32(state["p"]), r)
-        pt = pt + 1
-    else:  # stale GSNR step
-        p = f32(state["p"])
-    ptf = jnp.maximum(pt.astype(jnp.float32), 1.0)
-    phat = _tm(lambda p_: p_ / (1 - b3**ptf), p)
+    r = gsnr_scale(_require(stats), gamma, gsnr_eps)
+    p = _tm(lambda p_, r_: b3 * p_ + (1 - b3) * r_, f32(state["p"]), r)
+    phat = _tm(lambda p_: p_ / (1 - b3**tf), p)
     ghat = _tm(lambda ph, g: ph * g, phat, grads)
     m = _tm(lambda m_, g: b1 * m_ + (1 - b1) * g, f32(state["m"]), ghat)
     v = _tm(lambda v_, g: b2 * v_ + (1 - b2) * jnp.square(g), f32(state["v"]), ghat)
     direction = _tm(
         lambda m_, v_: (m_ / (1 - b1**tf)) / (jnp.sqrt(v_ / (1 - b2**tf)) + eps), m, v
     )
-    return direction, {"step": t, "m": store(m), "v": store(v), "p": store(p), "pt": pt}
+    return direction, {"step": t, "m": store(m), "v": store(v), "p": store(p)}
 
 
 def vr_adam(
@@ -179,28 +163,19 @@ def vr_adam(
             z = _flat_zeros_fn(params, state_dtype)
         else:
             z = lambda: _tm(lambda x: jnp.zeros(x.shape, sd), params)
-        return {"step": jnp.zeros((), jnp.int32), "pt": jnp.zeros((), jnp.int32),
-                "m": z(), "v": z(), "p": z()}
+        return {"step": jnp.zeros((), jnp.int32), "m": z(), "v": z(), "p": z()}
 
     def update(grads, state, params=None, stats=None):
         lr = lr_fn(state["step"])
-        if fused and stats is not None:
+        if fused:
             from repro.kernels import ops as kops
 
             return kops.vr_adam_update(
                 grads, state, _require(stats), lr, b1, b2, b3, eps, wd, gamma, gsnr_eps,
                 params, state_dtype, backend=bk, spmd=spmd,
             )
-        if fused:
-            # stale-GSNR step on flat state: the element-wise math below runs
-            # directly on the flat buffers (one fused XLA sweep, no launches)
-            layout = state["m"].layout
-            grads = as_flat(grads, layout)
-            params = as_flat(params, layout) if params is not None else None
-        else:
-            grads = _unpacked(grads)
         d, new_state = _vr_adam_dir(
-            grads, state, stats, b1, b2, b3, eps, gamma, gsnr_eps, state_dtype
+            _unpacked(grads), state, stats, b1, b2, b3, eps, gamma, gsnr_eps, state_dtype
         )
         if wd and params is not None:
             d = _tm(lambda d_, p_: d_ + wd * p_, d, params)
@@ -269,30 +244,17 @@ def vr_lamb(
             z = _flat_zeros_fn(params, state_dtype)
         else:
             z = lambda: _tm(lambda x: jnp.zeros(x.shape, sd), params)
-        return {"step": jnp.zeros((), jnp.int32), "pt": jnp.zeros((), jnp.int32),
-                "m": z(), "v": z(), "p": z()}
+        return {"step": jnp.zeros((), jnp.int32), "m": z(), "v": z(), "p": z()}
 
     def update(grads, state, params, stats=None):
         lr = lr_fn(state["step"])
-        if fused and stats is not None:
+        if fused:
             from repro.kernels import ops as kops
 
             return kops.vr_lamb_update(
                 grads, state, _require(stats), lr, b1, b2, b3, eps, wd, gamma,
                 gsnr_eps, params, state_dtype, backend=bk, spmd=spmd,
             )
-        if fused:
-            # stale-GSNR step on flat state: element-wise chain via the shared
-            # jnp math, then the per-leaf trust ratio as a segment reduction
-            # over the flat rows (kernels/ops.py) — no per-leaf dispatch.
-            from repro.kernels import ops as kops
-
-            layout = state["m"].layout
-            d, new_state = _vr_adam_dir(
-                as_flat(grads, layout), state, None, b1, b2, b3, eps, gamma,
-                gsnr_eps, state_dtype,
-            )
-            return kops.lamb_trust_flat(d, params, lr, wd), new_state
         d, new_state = _vr_adam_dir(
             _unpacked(grads), state, stats, b1, b2, b3, eps, gamma, gsnr_eps, state_dtype
         )

@@ -8,14 +8,8 @@
 #   flat_update.flat_vr_lamb    — 2-phase VR-LAMB + in-grid trust-ratio norms
 #   flat_update.flat_vr_lars    — 2-phase VR-LARS + in-grid trust-ratio norms
 #   flat_stats.flat_moments_*   — flat k-group moment accumulation/finalize
-#   flat_stats.flat_vmap_moments— batched (k, param) stack -> moments
-#
-# Per-leaf kernels (PR 1; retained as differential oracle references):
-#   vr_update.vr_scale          — fused GSNR pipeline, one tensor
-#   vr_adam.vr_adam_inner       — fused VR-Adam inner step, one tensor
-#   vr_lamb.vr_lamb_inner       — fused VR-LAMB + norm partials, one tensor
-#   vr_lamb.vr_lars_inner       — fused VR-LARS + norm partials, one tensor
-#   grad_stats.moments_*        — per-leaf moment accumulation (scan body)
+#   flat_stats.flat_pack_square — the data-parallel [g; g²] payload
+#   flat_spmd.*                 — the per-shard pipelines (backend.FlatSpmd)
 #
 #   flash_attention             — causal/sliding-window online-softmax attention
 #                                 (position/segment-aware: packed + offset

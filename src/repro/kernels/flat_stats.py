@@ -1,37 +1,21 @@
 """Pallas TPU kernels: flat-buffer GradStats accumulation — ONE pallas_call
 per scan step / finalize over the whole parameter set.
 
-PR 1's fused accumulation (kernels/grad_stats.py) removed the double HBM
-sweep of the scan body but still launched one kernel per pytree leaf with a
-pad/unpad round-trip each.  Here the carry (g_sum, g2_sum) lives in the
-ParamLayout flat ``(n_rows, LANE)`` buffer for the whole scan, the incoming
-gradient tree is packed once per microbatch (core/layout.py), and each of
+The carry (g_sum, g2_sum) lives in the ParamLayout flat ``(n_rows, LANE)``
+buffer for the whole scan (core/accumulate.py), the incoming gradient tree
+is packed once per microbatch (core/layout.py), and each of
 
   * ``flat_moments_accum``     (scan body:  g_sum += g; g2_sum += g*g)
   * ``flat_moments_finalize``  (terminal /k normalize of both moments)
-  * ``flat_vmap_moments``      (batched (k, n_rows, LANE) stack -> moments)
 
-is a single ``pallas_call`` with a grid over row-blocks.  The kernel bodies
-for accum/finalize are shared with the per-leaf path (grad_stats.py), which
-stays as the differential oracle reference.
+is a single ``pallas_call`` with a grid over row-blocks: HBM sees each
+carry read and written once per microbatch, g read once.
+``flat_pack_square`` emits the data-parallel path's ``[g; g²]`` payload.
 
-``flat_vmap_moments`` covers the vmap stats method (ROADMAP item: it used to
-ignore the fused-stats backend): the (k, param) gradient stack reduces to
-(mean, sq_mean) in one kernel, grid (n_blocks, k) with k minor so the output
-block revisits are consecutive (the standard accumulate-in-VMEM pattern).
-
-``flat_g_accum`` is the g-only variant for the amortized-GSNR "stale" scan
-path (squares=False): no Σg² stream, the mean-gradient carry stays a flat
-buffer for the whole scan instead of a jnp tree.
-
-The scan-path sweeps (``flat_moments_accum`` / ``flat_g_accum`` /
-``flat_moments_finalize``) derive their grids from the LOCAL operand shape
+Every sweep derives its grid from the LOCAL operand shape
 (``gs.shape[0] // block_rows``), not ``layout.n_blocks`` — they are purely
 element-wise, so those very wrappers run per-shard under shard_map
 (backend.FlatSpmd) on FSDP row slices of the buffer, with no other change.
-``flat_vmap_moments`` is the exception: its grid still comes from the full
-``layout`` geometry and it has no per-shard wrapper (the vmap stats path
-keeps the gathered one-launch reduction).
 """
 from __future__ import annotations
 
@@ -43,7 +27,6 @@ from jax.experimental import pallas as pl
 
 from repro.backend import resolve_interpret
 from repro.core.layout import LANE, ParamLayout
-from repro.kernels.grad_stats import _accum_kernel, _finalize_kernel
 
 
 def _blk(layout: ParamLayout):
@@ -60,6 +43,18 @@ def _local_blocks(x, layout: ParamLayout) -> int:
     return rows // layout.block_rows
 
 
+def _accum_kernel(gs_ref, g2s_ref, g_ref, gs_out, g2s_out):
+    g = g_ref[...].astype(jnp.float32)
+    gs_out[...] = gs_ref[...] + g
+    g2s_out[...] = g2s_ref[...] + g * g
+
+
+def _finalize_kernel(gs_ref, g2s_ref, scal_ref, mean_out, sq_out):
+    inv = scal_ref[0, 0]
+    mean_out[...] = gs_ref[...] * inv
+    sq_out[...] = g2s_ref[...] * inv
+
+
 @functools.partial(jax.jit, static_argnames=("layout", "interpret"))
 def flat_moments_accum(gs, g2s, g, layout: ParamLayout, interpret: bool | None = None):
     """One scan-body update of both flat moment carries: a single launch."""
@@ -74,27 +69,6 @@ def flat_moments_accum(gs, g2s, g, layout: ParamLayout, interpret: bool | None =
         input_output_aliases={0: 0, 1: 1},  # the carries update in place
         interpret=resolve_interpret(interpret),
     )(gs, g2s, g)
-
-
-def _g_accum_kernel(gs_ref, g_ref, gs_out):
-    gs_out[...] = gs_ref[...] + g_ref[...].astype(jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("layout", "interpret"))
-def flat_g_accum(gs, g, layout: ParamLayout, interpret: bool | None = None):
-    """One scan-body update of the g-only flat carry (stale-GSNR steps):
-    a single launch, no Σg² stream."""
-    blk = _blk(layout)
-    sds = jax.ShapeDtypeStruct(gs.shape, jnp.float32)
-    return pl.pallas_call(
-        _g_accum_kernel,
-        grid=(_local_blocks(gs, layout),),
-        in_specs=[blk, blk],
-        out_specs=blk,
-        out_shape=sds,
-        input_output_aliases={0: 0},
-        interpret=resolve_interpret(interpret),
-    )(gs, g)
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "interpret"))
@@ -147,51 +121,12 @@ def flat_pack_square(gf, layout: ParamLayout, interpret: bool | None = None):
     )(gf)
 
 
-def _vmap_kernel(g_ref, mean_ref, sq_ref, *, nk: int, inv: float):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        mean_ref[...] = jnp.zeros_like(mean_ref)
-        sq_ref[...] = jnp.zeros_like(sq_ref)
-
-    g = g_ref[0].astype(jnp.float32)
-    mean_ref[...] += g
-    sq_ref[...] += g * g
-
-    @pl.when(j == nk - 1)
-    def _fin():
-        mean_ref[...] *= inv
-        sq_ref[...] *= inv
-
-
-@functools.partial(jax.jit, static_argnames=("layout", "k", "interpret"))
-def flat_vmap_moments(gstack, layout: ParamLayout, k: int, interpret: bool | None = None):
-    """(k, n_rows, LANE) gradient stack -> flat (mean, sq_mean): one launch.
-
-    The k axis is the minor grid dimension, so each output block stays
-    resident in VMEM while its k slices accumulate, then normalizes in place
-    on the last visit.
-    """
-    br = layout.block_rows
-    sds = jax.ShapeDtypeStruct((layout.n_rows, LANE), jnp.float32)
-    out_blk = pl.BlockSpec((br, LANE), lambda b, j: (b, 0))
-    return pl.pallas_call(
-        functools.partial(_vmap_kernel, nk=k, inv=1.0 / k),
-        grid=(layout.n_blocks, k),
-        in_specs=[pl.BlockSpec((1, br, LANE), lambda b, j: (j, b, 0))],
-        out_specs=(out_blk, out_blk),
-        out_shape=(sds, sds),
-        interpret=resolve_interpret(interpret),
-    )(gstack)
-
-
 # ---------------------------------------------------------------------------
 # contract registration (repro.analysis)
 # ---------------------------------------------------------------------------
 
 
-def _analysis_geometry(kname: str, *, layout_kind: str = "hostile", k: int = 4):
+def _analysis_geometry(kname: str, *, layout_kind: str = "hostile"):
     from repro.analysis.registry import Geometry, Operand, demo_layout
 
     layout = demo_layout(layout_kind)
@@ -203,27 +138,15 @@ def _analysis_geometry(kname: str, *, layout_kind: str = "hostile", k: int = 4):
         return Geometry(grid=(layout.n_blocks,),
                         ins={"gs": f32(blk), "g2s": f32(blk), "g": f32(blk)},
                         outs={"gs_out": f32(blk), "g2s_out": f32(blk)})
-    if kname == "flat_g_accum":
-        return Geometry(grid=(layout.n_blocks,),
-                        ins={"gs": f32(blk), "g": f32(blk)},
-                        outs={"gs_out": f32(blk)})
     if kname == "flat_moments_finalize":
         return Geometry(grid=(layout.n_blocks,),
                         ins={"gs": f32(blk), "g2s": f32(blk), "inv": inv},
                         outs={"mean": f32(blk), "sq": f32(blk)})
-    if kname == "flat_pack_square":
-        out = pl.BlockSpec((2, layout.block_rows, LANE), lambda i: (0, i, 0))
-        return Geometry(grid=(layout.n_blocks,),
-                        ins={"gf": f32(blk)},
-                        outs={"payload": Operand(out, (2,) + rows)})
-    # flat_vmap_moments: k-minor grid keeps each output block in one run —
-    # the registry replay PROVES that
-    br = layout.block_rows
-    out_blk = pl.BlockSpec((br, LANE), lambda b, j: (b, 0))
-    stack = pl.BlockSpec((1, br, LANE), lambda b, j: (j, b, 0))
-    return Geometry(grid=(layout.n_blocks, k),
-                    ins={"gstack": Operand(stack, (k,) + rows)},
-                    outs={"mean": f32(out_blk), "sq": f32(out_blk)})
+    # flat_pack_square
+    out = pl.BlockSpec((2, layout.block_rows, LANE), lambda i: (0, i, 0))
+    return Geometry(grid=(layout.n_blocks,),
+                    ins={"gf": f32(blk)},
+                    outs={"payload": Operand(out, (2,) + rows)})
 
 
 def _register():
@@ -231,19 +154,14 @@ def _register():
 
     oracles = {
         "flat_moments_accum": "moments_accum_ref",
-        "flat_g_accum": "g_accum_ref",
         "flat_moments_finalize": "moments_finalize_ref",
         "flat_pack_square": "pack_square_ref",
-        "flat_vmap_moments": "vmap_moments_ref",
     }
     for kname, oracle in oracles.items():
-        configs = {"representative": dict(layout_kind="aligned"),
-                   "hostile_ragged": dict(layout_kind="hostile")}
-        if kname == "flat_vmap_moments":
-            configs["hostile_odd_k"] = dict(layout_kind="hostile", k=7)
         register_kernel(kname, module=__name__, oracle=oracle,
                         build=functools.partial(_analysis_geometry, kname),
-                        configs=configs)
+                        configs={"representative": dict(layout_kind="aligned"),
+                                 "hostile_ragged": dict(layout_kind="hostile")})
 
 
 _register()

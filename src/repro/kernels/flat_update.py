@@ -31,8 +31,8 @@ pipeline elides the copy: phase 0 reads only g and g2.  A parked output
 window is never written while parked, and since it parks on the first block
 of its window, that block's single copy-back comes after the kernel wrote it.
 
-Semantics match the per-leaf oracle kernels (vr_update/vr_adam/vr_lamb.py)
-and the jnp path exactly (tests/test_oracle.py + tests/test_layout.py):
+Semantics match the per-tensor jnp references (kernels/ref.py) and the jnp
+path exactly (tests/test_oracle.py + tests/test_layout.py):
 the GSNR ratio derives from the raw group moments (g, g2) but scales the
 possibly grad-clipped gradient ga; moments are stored in ``state_dtype``
 with all math in f32; zero tail padding (g = ga = w = 0) keeps every

@@ -7,12 +7,10 @@ whose ``interpret_mode()`` overrides the platform probe, plus an optional
 ``spmd=`` plan (backend.FlatSpmd) that reroutes the flat-buffer calls through
 their per-shard shard_map pipelines when the layout actually shards.
 
-Since the flat-state refactor every optimizer entry point here is ONE
-``pallas_call`` over the ParamLayout flat buffer (kernels/flat_update.py,
-kernels/flat_stats.py) — no per-leaf dispatch loop, no per-leaf pad/unpad,
-and no jnp 1/mean(r) prepass (the mean reduction runs as the kernel's first
-grid phase).  The per-leaf kernels (vr_update/vr_adam/vr_lamb/grad_stats)
-remain as oracle references, exercised by tests/oracle.py.
+Every optimizer entry point here is ONE ``pallas_call`` over the
+ParamLayout flat buffer (kernels/flat_update.py, kernels/flat_stats.py) —
+no per-leaf dispatch loop, no per-leaf pad/unpad, and no jnp 1/mean(r)
+prepass (the mean reduction runs as the kernel's first grid phase).
 
 Every wrapper is required to be bit-for-bit interchangeable (up to f32
 rounding and reduction order) with the jnp path in core/vrgd.py /
@@ -22,12 +20,10 @@ conventions keep the paths aligned:
   * the GSNR ratio derives from the raw group moments (stats.mean, sq_mean)
     but multiplies the gradient actually entering the update (the ``grads``
     argument, which global grad-clip may have rescaled);
-  * optimizer moments are stored in ``state_dtype`` (math always f32), and
-    the GSNR-momentum bias correction uses the stats-step counter ``pt``,
-    not the raw step — they differ under amortized (stale) GSNR refresh.
+  * optimizer moments are stored in ``state_dtype`` (math always f32).
 
 Optimizer state arrives as FlatBuffer nodes (core/layout.py); tree-valued
-inputs (tests, the amortized-GSNR stale path) are packed on entry.  A tree
+inputs (tests, the reference-stats plans) are packed on entry.  A tree
 whose structure diverges from the param layout fails loudly in
 ``ParamLayout.check_tree`` instead of deep inside flatten_up_to.
 """
@@ -35,7 +31,6 @@ from __future__ import annotations
 
 from typing import Any, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro import backend as backend_mod
@@ -126,14 +121,11 @@ def vr_scale_tree(stats: GradStats, grads, gamma: float, eps: float,
 
 
 def _bias_corrections(state, b1, b2, b3):
-    """(t, pt, bc1, bc2, bc3) exactly as vrgd._vr_adam_dir computes them on a
-    fresh-stats step: b1/b2 correct by the optimizer step, b3 by the
-    stats-refresh counter pt (they diverge under amortized GSNR)."""
+    """(t, bc1, bc2, bc3) exactly as vrgd._vr_adam_dir computes them: all
+    three moments correct by the optimizer step t."""
     t = state["step"] + 1
     tf = t.astype(jnp.float32)
-    pt = state.get("pt", state["step"]) + 1
-    ptf = jnp.maximum(pt.astype(jnp.float32), 1.0)
-    return t, pt, 1 - b1**tf, 1 - b2**tf, 1 - b3**ptf
+    return t, 1 - b1**tf, 1 - b2**tf, 1 - b3**tf
 
 
 def _state_flats(state, layout, state_dtype, keys=("m", "v", "p")):
@@ -151,7 +143,7 @@ def vr_adam_update(
     params, state_dtype: str = "float32", backend=None, spmd=None,
 ):
     """Full VR-Adam update as one launch; matches vrgd.vr_adam's jnp path."""
-    t, pt, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
+    t, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
     layout = _layout_for(state["m"], params, stats.mean)
     g = _flat(stats.mean, layout)
     ga = _flat(grads, layout)
@@ -171,9 +163,7 @@ def vr_adam_update(
         upd, m2, v2, p2 = fu.flat_vr_adam(
             g, ga, g2, m, v, p, w, scal, layout, interpret=_interp(backend), **kw
         )
-    new_state = {
-        "step": t, "m": _fb(m2, layout), "v": _fb(v2, layout), "p": _fb(p2, layout), "pt": pt,
-    }
+    new_state = {"step": t, "m": _fb(m2, layout), "v": _fb(v2, layout), "p": _fb(p2, layout)}
     return layout.unpack(upd), new_state
 
 
@@ -182,7 +172,7 @@ def vr_lamb_update(
     params, state_dtype: str = "float32", backend=None, spmd=None,
 ):
     """Full VR-LAMB update as one launch; matches vrgd.vr_lamb's jnp path."""
-    t, pt, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
+    t, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
     layout = _layout_for(state["m"], params, stats.mean)
     g = _flat(stats.mean, layout)
     ga = _flat(grads, layout)
@@ -201,9 +191,7 @@ def vr_lamb_update(
         upd, m2, v2, p2 = fu.flat_vr_lamb(
             g, ga, g2, m, v, p, w, scal, layout, interpret=_interp(backend), **kw
         )
-    new_state = {
-        "step": t, "m": _fb(m2, layout), "v": _fb(v2, layout), "p": _fb(p2, layout), "pt": pt,
-    }
+    new_state = {"step": t, "m": _fb(m2, layout), "v": _fb(v2, layout), "p": _fb(p2, layout)}
     return layout.unpack(upd), new_state
 
 
@@ -231,26 +219,6 @@ def vr_lars_update(grads, state, stats: GradStats, lr, mu, wd, trust, gamma, eps
     return layout.unpack(upd), new_state
 
 
-def lamb_trust_flat(d: FlatBuffer, params, lr, wd):
-    """Stale-GSNR LAMB epilogue on the flat buffer (no kernel launch): the
-    per-leaf trust ratio via a row-wise segment reduction, fully XLA-fused.
-
-    Fresh steps take the 2-phase kernel; stale steps have no Σg² pass to
-    fold in, so plain jnp over ONE flat array is already a single sweep.
-    """
-    from repro.core.baselines import _lamb_phi
-
-    layout = d.layout
-    w = _flat(params, layout) if params is not None else jnp.zeros_like(d.data)
-    u = d.data + wd * w
-    seg_rows = jnp.asarray(layout.row_leaf_ids())
-    u2 = jax.ops.segment_sum(jnp.sum(u * u, axis=1), seg_rows, num_segments=layout.n_leaves)
-    w2 = jax.ops.segment_sum(jnp.sum(w * w, axis=1), seg_rows, num_segments=layout.n_leaves)
-    pn, un = jnp.sqrt(w2), jnp.sqrt(u2)
-    ratio = jnp.where((pn > 0) & (un > 0), _lamb_phi(pn) / (un + 1e-12), 1.0)
-    return layout.unpack(-lr * ratio[seg_rows][:, None] * u)
-
-
 # ---------------------------------------------------------------------------
 # k-group moment accumulation (core/accumulate.py scan body)
 # ---------------------------------------------------------------------------
@@ -276,18 +244,6 @@ def moments_accum_flat(g_sum, g2_sum, grads, layout: ParamLayout,
         return fs.flat_moments_accum(g_sum, g2_sum, g, layout, interpret=_interp(backend))
 
 
-def g_accum_flat(g_sum, grads, layout: ParamLayout, backend=None, spmd=None):
-    """One fused microbatch update of the g-only flat carry (stale-GSNR
-    steps, squares=False): a single launch, no Σg² stream."""
-    with obs.scope(obs.STATS_PACK):
-        g = _flat(grads, layout)
-    plan = _spmd_for(spmd, layout)
-    with obs.scope(obs.STATS_ACCUM):
-        if plan is not None:
-            return plan.g_accum(g_sum, g, layout)
-        return fs.flat_g_accum(g_sum, g, layout, interpret=_interp(backend))
-
-
 def moments_finalize_flat(g_sum, g2_sum, k, layout: ParamLayout,
                           backend=None, spmd=None) -> GradStats:
     """Fused /k normalize (one launch) -> GradStats carrying FlatBuffers."""
@@ -299,14 +255,6 @@ def moments_finalize_flat(g_sum, g2_sum, k, layout: ParamLayout,
             mean, sq = fs.flat_moments_finalize(
                 g_sum, g2_sum, k, layout, interpret=_interp(backend)
             )
-    return GradStats(mean=_fb(mean, layout), sq_mean=_fb(sq, layout), k=k)
-
-
-def vmap_moments_flat(gs_tree, layout: ParamLayout, k: int, backend=None) -> GradStats:
-    """Batched (k, param) gradient stack -> GradStats in one launch (the
-    vmap stats method; see accumulate.grad_stats)."""
-    gstack = jax.vmap(lambda t: layout.pack(t, jnp.float32))(gs_tree)
-    mean, sq = fs.flat_vmap_moments(gstack, layout, k, interpret=_interp(backend))
     return GradStats(mean=_fb(mean, layout), sq_mean=_fb(sq, layout), k=k)
 
 

@@ -76,22 +76,11 @@ def moments_finalize_ref(g_sum, g2_sum, k):
     return g_sum * inv, g2_sum * inv
 
 
-def g_accum_ref(g_sum, g):
-    """Scan-body g-only carry update (amortized-GSNR stale path) in f32."""
-    return g_sum + g.astype(jnp.float32)
-
-
 def pack_square_ref(gf):
     """(rows, LANE) flat gradient -> (2, rows, LANE) stacked [g; g²] f32
     payload — the collective-shaped carry of the data-parallel stats pmean."""
     g = gf.astype(jnp.float32)
     return jnp.stack([g, jnp.square(g)])
-
-
-def vmap_moments_ref(gstack):
-    """(k, rows, LANE) gradient stack -> (mean, sq_mean) over the k axis."""
-    g = gstack.astype(jnp.float32)
-    return jnp.mean(g, axis=0), jnp.mean(jnp.square(g), axis=0)
 
 
 def gsnr_r_raw_ref(g, g2, eps):

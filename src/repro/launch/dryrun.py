@@ -85,23 +85,11 @@ def build_lowered(cfg, shape, mesh, rules):
         bshard = batch_shardings(batch_sds, rules, shape.global_batch)
         k = cfg.optimizer.k
 
-        method = cfg.optimizer.stats_method
-        stale = cfg.optimizer.gsnr_refresh > 1  # lower the amortized "stale" step
-
         def step(state, batch):
-            if stale:
-                loss, aux, stats_ = grad_stats(
-                    loss_fn, state.params, batch, k, has_aux=True, method=method,
-                    squares=False, backend=bk, spmd=spmd,
-                )
-                grads, stats = stats_.mean, None
-            else:
-                loss, aux, stats = grad_stats(
-                    loss_fn, state.params, batch, k, has_aux=True, method=method,
-                    backend=bk, spmd=spmd,
-                )
-                grads = stats.mean
-            upd, opt_state = opt.update(grads, state.opt_state, state.params, stats=stats)
+            loss, aux, stats = grad_stats(
+                loss_fn, state.params, batch, k, has_aux=True, backend=bk, spmd=spmd,
+            )
+            upd, opt_state = opt.update(stats.mean, state.opt_state, state.params, stats=stats)
             params = jax.tree_util.tree_map(lambda p, u: (p + u).astype(p.dtype), state.params, upd)
             return TrainState(params, opt_state, opt_state["step"]), loss
 

@@ -82,9 +82,13 @@ def make_train_step(
 
         lr_dbg = make_schedule(opt_cfg, effective_batch=cfg.global_batch)
 
-    def train_step(state: TrainState, batch, with_stats: bool = True) -> Tuple[TrainState, Dict]:
+    def train_step(state: TrainState, batch, fresh_stats: bool = True) -> Tuple[TrainState, Dict]:
+        # every step takes fresh statistics; benchmarks/chip/program.py still
+        # passes True as a third argument, the only value accepted
+        if not fresh_stats:
+            raise ValueError("train_step computes fresh GSNR statistics on every step")
         noise_est = None
-        if is_vr and with_stats:
+        if is_vr:
             if use_device_stats and noise_scale:
                 loss, aux, stats, nterms = stats_fn(state.params, batch)
                 noise_est = ns.estimate_from_terms(
@@ -96,23 +100,13 @@ def make_train_step(
             else:
                 loss, aux, stats = grad_stats(
                     loss_fn, state.params, batch, opt_cfg.k, has_aux=True,
-                    method=opt_cfg.stats_method, backend=bk, spmd=spmd,
+                    backend=bk, spmd=spmd,
                 )
             if noise_scale and noise_est is None:
                 noise_est = ns.estimate(
                     stats, b_small=cfg.global_batch / stats.k, b_big=cfg.global_batch
                 )
             grads = stats.mean
-        elif is_vr:
-            # amortized-GSNR "stale" step: microbatched mean gradient only —
-            # the Σg² stream (one param-sized f32 buffer) is skipped (§Perf);
-            # with fused stats the mean-gradient carry stays a flat buffer
-            # (g-only accumulation kernel) instead of a jnp tree
-            loss, aux, stats_ = grad_stats(
-                loss_fn, state.params, batch, opt_cfg.k, has_aux=True,
-                method=opt_cfg.stats_method, squares=False, backend=bk, spmd=spmd,
-            )
-            grads, stats = stats_.mean, None
         else:
             loss, aux, grads = grad_only(loss_fn, state.params, batch, has_aux=True)
             stats = None
@@ -220,25 +214,16 @@ def train_loop(
     log_every: int = 0,
     log_gsnr: bool = False,
 ):
-    """Simple driver used by examples/benchmarks. Returns (state, history).
-
-    With cfg.optimizer.gsnr_refresh = R > 1, only every R-th step pays the
-    k-group Σg² pass; the others run a plain backward with the stale,
-    b3-smoothed GSNR momentum (beyond-paper amortization, §Perf)."""
+    """Simple driver used by examples/benchmarks. Returns (state, history)."""
     loss_fn = loss_fn or make_loss_fn(cfg)
     step_fn, _ = make_train_step(cfg, loss_fn, log_gsnr=log_gsnr)
-    supports_stale = cfg.optimizer.name in ("vr_adam", "vr_lamb")
-    refresh = max(1, cfg.optimizer.gsnr_refresh) if supports_stale else 1
-    full_step = jax.jit(lambda s, b: step_fn(s, b, True), donate_argnums=0)
-    stale_step = jax.jit(lambda s, b: step_fn(s, b, False), donate_argnums=0)
+    step = jax.jit(step_fn, donate_argnums=0)
     state = state or init_state(cfg)
     history = []
     it = iter(batches)
     t0 = time.time()
     for i in range(steps):
-        batch = next(it)
-        fn = full_step if (refresh == 1 or i % refresh == 0) else stale_step
-        state, metrics = fn(state, batch)
+        state, metrics = step(state, next(it))
         if log_every and (i % log_every == 0 or i == steps - 1):
             m = {k_: float(v) for k_, v in metrics.items()}
             m["step"], m["wall"] = i, time.time() - t0

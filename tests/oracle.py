@@ -1,20 +1,18 @@
 """Differential oracle harness for the Pallas kernel family.
 
 Every Pallas kernel in src/repro/kernels/ has a pure-jnp reference
-(kernels/ref.py for the per-tensor kernels, core/vrgd.py + core/accumulate.py
-for the full transforms).  This module is the shared machinery that sweeps
+(kernels/ref.py per tensor, core/vrgd.py + core/accumulate.py for the full
+transforms).  This module is the shared machinery that sweeps
 kernel vs. reference over the hostile input grid the kernels must survive:
 
   * shapes: scalar-ish, non-tile-aligned trailing dims, multi-block leaves,
-    and partial edge blocks (rows % BLOCK_ROWS != 0 — the case that poisons
+    and partial edge blocks (rows % FLAT_BLOCK_ROWS != 0 — the case that poisons
     in-kernel reductions if padding is mishandled);
   * dtypes: f32 and bf16 gradients / optimizer state;
   * gamma edge cases: gamma=1.0 must collapse every VR optimizer to its base
     optimizer (clip floor == ceiling), gamma→0 leaves the ratio free;
   * grad-clip divergence: the GSNR ratio derives from raw moments but scales
-    the clipped gradient (g_apply != g);
-  * stale-GSNR steps: amortized refresh where the Pallas path must agree
-    with the jnp path about the pt bias-correction counter.
+    the clipped gradient (g_apply != g).
 
 It is dependency-free on purpose: ``property_cases`` is a seeded loop, not a
 hypothesis strategy, so the suite collects and runs on a bare interpreter
@@ -29,14 +27,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Shapes chosen against the (BLOCK_ROWS=256, LANE=128) tiling:
+# Shapes chosen against the flat layout's (64-row block, LANE=128) tiling:
 #   7        sub-lane sliver (single partial row)
 #   (33, 5)  2-D leaf, non-tile-aligned trailing dim
 #   1000     several rows, ragged tail
-#   4096     exactly 32 aligned rows, single block
+#   4096     exactly 32 full rows, half of one block
 #   (3,5,7)  3-D leaf, everything ragged
-#   40000    313 rows -> partial edge block at BLOCK_ROWS=256
-#   70000    547 rows -> 3 grid steps, partial edge block
+#   40000    313 rows -> 5 blocks, the last partial
+#   70000    547 rows -> 9 blocks, the last partial
 SHAPES: Tuple[Tuple[int, ...], ...] = (
     (7,), (33, 5), (1000,), (4096,), (3, 5, 7), (40000,), (70000,)
 )
@@ -246,8 +244,48 @@ def run_packed_attention_grads(case: Sequence, seed: int = 0, dtype=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
-# Per-leaf reference dispatch (PR 1's kernels/ops.py loops, kept here as the
-# oracle the single-launch flat path is differentially certified against)
+# One-leaf flat runs of the single-launch kernels (the per-tensor sweeps run
+# the kernels the chip runs, each on a ParamLayout of one leaf)
+# ---------------------------------------------------------------------------
+
+
+def one_leaf(x, dtype=jnp.float32):
+    """(layout, packed buffer) for the one-leaf tree ``x``, in ``dtype``."""
+    from repro.core.layout import ParamLayout
+
+    layout = ParamLayout.for_tree(x)
+    return layout, layout.pack(x, dtype)
+
+
+def flat_vr_scale(g, g2, gamma, eps, g_apply=None):
+    """(scaled_grad, r) for one tensor through flat_update.flat_vr_scale."""
+    from repro.kernels import flat_update as fu
+
+    ga = g if g_apply is None else g_apply
+    layout, gf = one_leaf(g)
+    sg, r = fu.flat_vr_scale(gf, one_leaf(ga)[1], one_leaf(g2)[1], layout,
+                             gamma=gamma, eps=eps)
+    return layout.unpack(sg), layout.unpack(r)
+
+
+def lamb_trust(u2, w2):
+    """LAMB's phi-trust ratio from the exact norm sums (baselines.lamb)."""
+    from repro.core.baselines import _lamb_phi
+
+    pn, un = jnp.sqrt(w2), jnp.sqrt(u2)
+    return jnp.where((pn > 0) & (un > 0), _lamb_phi(pn) / (un + 1e-12), 1.0)
+
+
+def lars_trust(u2, w2, trust):
+    """LARS's trust ratio from the exact norm sums (baselines.lars)."""
+    pn, gn = jnp.sqrt(w2), jnp.sqrt(u2)
+    return jnp.where((pn > 0) & (gn > 0), trust * pn / (gn + 1e-12), 1.0)
+
+
+# ---------------------------------------------------------------------------
+# Per-leaf reference dispatch: the jnp per-tensor references of
+# kernels/ref.py looped over the leaves, the oracle the single-launch flat
+# path is differentially certified against over multi-leaf trees
 # ---------------------------------------------------------------------------
 
 
@@ -262,11 +300,11 @@ def _map_unzip(fn, ref_tree, *rest_trees):
 
 
 def per_leaf_vr_scale(stats, grads, gamma, eps):
-    """Kernel-per-leaf (scaled_grads, r): PR 1's ops.vr_scale_tree."""
-    from repro.kernels import vr_update as vu
+    """Per-leaf (scaled_grads, r) from ref.vr_scale_ref."""
+    from repro.kernels import ref
 
     return _map_unzip(
-        lambda g, g2, ga: vu.vr_scale(g, g2, gamma, eps, g_apply=ga),
+        lambda g, g2, ga: ref.vr_scale_ref(g, g2, gamma, eps, g_apply=ga),
         stats.mean, stats.sq_mean, grads,
     )
 
@@ -275,21 +313,21 @@ def per_leaf_vr_adam_update(
     grads, state, stats, lr, b1, b2, b3, eps, wd, gamma, gsnr_eps, params,
     state_dtype="float32",
 ):
-    """Kernel-per-leaf VR-Adam step: PR 1's ops.vr_adam_update."""
-    from repro.kernels import vr_adam as va
+    """Per-leaf VR-Adam step from ref.vr_adam_inner_ref."""
+    from repro.kernels import ref
     from repro.kernels.ops import _bias_corrections
 
     _tm = jax.tree_util.tree_map
-    t, pt, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
+    t, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
     sd = jnp.dtype(state_dtype)
     leaves_g, treedef = jax.tree_util.tree_flatten(stats.mean)
     rest = [treedef.flatten_up_to(t_) for t_ in
             (grads, stats.sq_mean, state["m"], state["v"], state["p"])]
     dirs, ms, vs, ps = [], [], [], []
     for g, ga, g2, m, v, p in zip(leaves_g, *rest):
-        d_, m_, v_, p_ = va.vr_adam_inner(
-            g, g2, m, v, p, bc1, bc2, bc3,
-            b1=b1, b2=b2, b3=b3, eps=eps, gamma=gamma, gsnr_eps=gsnr_eps, g_apply=ga,
+        d_, m_, v_, p_ = ref.vr_adam_inner_ref(
+            g, g2, m, v, p, b1=b1, b2=b2, b3=b3, eps=eps, gamma=gamma,
+            gsnr_eps=gsnr_eps, bc1=bc1, bc2=bc2, bc3=bc3, g_apply=ga,
         )
         dirs.append(d_); ms.append(m_.astype(sd)); vs.append(v_.astype(sd)); ps.append(p_.astype(sd))
     unf = treedef.unflatten
@@ -297,49 +335,44 @@ def per_leaf_vr_adam_update(
     if wd and params is not None:
         d = _tm(lambda d_, p_: d_ + wd * p_, d, params)
     upd = _tm(lambda d_: -lr * d_, d)
-    return upd, {"step": t, "m": unf(ms), "v": unf(vs), "p": unf(ps), "pt": pt}
+    return upd, {"step": t, "m": unf(ms), "v": unf(vs), "p": unf(ps)}
 
 
 def per_leaf_vr_lamb_update(
     grads, state, stats, lr, b1, b2, b3, eps, wd, gamma, gsnr_eps, params,
     state_dtype="float32",
 ):
-    """Kernel-per-leaf VR-LAMB step: PR 1's ops.vr_lamb_update."""
-    from repro.core.baselines import _lamb_phi
-    from repro.kernels import vr_lamb as vl
+    """Per-leaf VR-LAMB step from ref.vr_lamb_inner_ref."""
+    from repro.kernels import ref
     from repro.kernels.ops import _bias_corrections
 
-    t, pt, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
+    t, bc1, bc2, bc3 = _bias_corrections(state, b1, b2, b3)
     sd = jnp.dtype(state_dtype)
     leaves_g, treedef = jax.tree_util.tree_flatten(stats.mean)
     rest = [treedef.flatten_up_to(t_) for t_ in
             (grads, stats.sq_mean, state["m"], state["v"], state["p"], params)]
     upds, ms, vs, ps = [], [], [], []
     for g, ga, g2, m, v, p, w in zip(leaves_g, *rest):
-        u, m_, v_, p_, u2, w2 = vl.vr_lamb_inner(
-            g, ga, g2, m, v, p, w, bc1, bc2, bc3,
-            b1=b1, b2=b2, b3=b3, eps=eps, wd=wd, gamma=gamma, gsnr_eps=gsnr_eps,
+        u, m_, v_, p_, u2, w2 = ref.vr_lamb_inner_ref(
+            g, ga, g2, m, v, p, w, b1=b1, b2=b2, b3=b3, eps=eps, wd=wd,
+            gamma=gamma, gsnr_eps=gsnr_eps, bc1=bc1, bc2=bc2, bc3=bc3,
         )
-        pn, un = jnp.sqrt(w2), jnp.sqrt(u2)
-        ratio = jnp.where((pn > 0) & (un > 0), _lamb_phi(pn) / (un + 1e-12), 1.0)
-        upds.append(-lr * ratio * u)
+        upds.append(-lr * lamb_trust(u2, w2) * u)
         ms.append(m_.astype(sd)); vs.append(v_.astype(sd)); ps.append(p_.astype(sd))
     unf = treedef.unflatten
-    return unf(upds), {"step": t, "m": unf(ms), "v": unf(vs), "p": unf(ps), "pt": pt}
+    return unf(upds), {"step": t, "m": unf(ms), "v": unf(vs), "p": unf(ps)}
 
 
 def per_leaf_vr_lars_update(grads, state, stats, lr, mu, wd, trust, gamma, eps, params):
-    """Kernel-per-leaf VR-LARS step: PR 1's ops.vr_lars_update."""
-    from repro.kernels import vr_lamb as vl
+    """Per-leaf VR-LARS step from ref.vr_lars_inner_ref."""
+    from repro.kernels import ref
 
     leaves_g, treedef = jax.tree_util.tree_flatten(stats.mean)
     rest = [treedef.flatten_up_to(t_) for t_ in (grads, stats.sq_mean, state["m"], params)]
     ms = []
     for g, ga, g2, m, w in zip(leaves_g, *rest):
-        u, u2, w2 = vl.vr_lars_inner(g, ga, g2, w, wd=wd, gamma=gamma, eps=eps)
-        pn, gn = jnp.sqrt(w2), jnp.sqrt(u2)
-        ratio = jnp.where((pn > 0) & (gn > 0), trust * pn / (gn + 1e-12), 1.0)
-        ms.append(mu * m + ratio * u)
+        u, u2, w2 = ref.vr_lars_inner_ref(g, ga, g2, w, wd=wd, gamma=gamma, eps=eps)
+        ms.append(mu * m + lars_trust(u2, w2, trust) * u)
     m_new = treedef.unflatten(ms)
     upd = jax.tree_util.tree_map(lambda m_: -lr * m_, m_new)
     return upd, {"step": state["step"] + 1, "m": m_new}
@@ -373,8 +406,8 @@ def run_flat_vs_per_leaf(
     wd: float = 0.01,
     seed: int = 0,
 ):
-    """Step the flat single-launch transform against the PR 1 per-leaf kernel
-    dispatch in lockstep over the hostile-shape param tree.
+    """Step the flat single-launch transform against the per-leaf jnp
+    references in lockstep over the hostile-shape param tree.
 
     Returns (upd_per_leaf, upd_flat, state_per_leaf, state_flat_unpacked).
     """
@@ -400,11 +433,11 @@ def run_flat_vs_per_leaf(
         ref_update = lambda s: per_leaf_vr_lars_update(
             grads, s, stats, lr, 0.9, wd, 0.001, gamma, 1e-12, params)
     elif name == "vr_adam":
-        s_r = {"step": zero, "pt": zero, "m": z(), "v": z(), "p": z()}
+        s_r = {"step": zero, "m": z(), "v": z(), "p": z()}
         ref_update = lambda s: per_leaf_vr_adam_update(
             grads, s, stats, lr, 0.9, 0.999, 0.9, 1e-6, wd, gamma, 1e-12, params, state_dtype)
     else:  # vr_lamb
-        s_r = {"step": zero, "pt": zero, "m": z(), "v": z(), "p": z()}
+        s_r = {"step": zero, "m": z(), "v": z(), "p": z()}
         ref_update = lambda s: per_leaf_vr_lamb_update(
             grads, s, stats, lr, 0.9, 0.999, 0.9, 1e-6, wd, gamma, 1e-12, params, state_dtype)
     u_r = u_f = None
@@ -425,7 +458,6 @@ def run_transform_pair(
     state_dtype: str = "float32",
     gamma: float = 0.1,
     clip_scale=None,
-    stale_every: int = 0,
     lr: float = 0.01,
     wd: float = 0.01,
     seed: int = 0,
@@ -433,8 +465,7 @@ def run_transform_pair(
     """Step the jnp and Pallas variants of one optimizer in lockstep.
 
     Returns (updates_jnp, updates_pallas, state_jnp, state_pallas) from the
-    final step.  stale_every=R feeds stats only every R-th step (amortized
-    GSNR); clip_scale scales the applied gradient away from stats.mean.
+    final step.  clip_scale scales the applied gradient away from stats.mean.
     """
     from repro.configs.base import OptimizerConfig
     from repro.core import GradStats, make_optimizer
@@ -460,10 +491,9 @@ def run_transform_pair(
     o_k = make_optimizer(cfg, use_pallas=True)
     s_j, s_k = o_j.init(params), o_k.init(params)
     u_j = u_k = None
-    for t in range(steps):
-        st = stats if (not stale_every or t % stale_every == 0) else None
-        u_j, s_j = o_j.update(grads, s_j, params, stats=st)
-        u_k, s_k = o_k.update(grads, s_k, params, stats=st)
+    for _ in range(steps):
+        u_j, s_j = o_j.update(grads, s_j, params, stats=stats)
+        u_k, s_k = o_k.update(grads, s_k, params, stats=stats)
     return u_j, u_k, s_j, s_k
 
 

@@ -10,9 +10,8 @@ Two halves:
     when fed a geometry violating exactly that invariant (a checker whose
     rules never fire is indistinguishable from one that checks nothing).
 
-Plus differential tests pinning the oracles this PR added to
-kernels/ref.py (ORACLE-REF closed the "every fused kernel has a jnp
-oracle" gap for flat_pack_square / flat_g_accum / flat_vmap_moments).
+Plus differential tests pinning the kernels/ref.py oracles that only the
+ORACLE-REF rule ("every fused kernel has a jnp oracle") reads.
 """
 import dataclasses
 
@@ -50,11 +49,10 @@ def test_committed_kernels_pass_the_full_contract_check():
 
 def test_registry_covers_every_kernel_module():
     kernels = all_kernels()
-    assert len(kernels) >= 23
+    assert len(kernels) >= 16
     modules = {k.module for k in kernels.values()}
     for mod in ("flash_attention", "flash_attention_bwd", "flash_decode",
-                "flat_update", "flat_stats", "flat_spmd", "grad_stats",
-                "vr_update", "vr_adam", "vr_lamb"):
+                "flat_update", "flat_stats", "flat_spmd"):
         assert any(m.endswith(mod) for m in modules), f"no kernels from {mod}"
 
 
@@ -333,35 +331,6 @@ def test_flat_pack_square_matches_ref():
     want = pack_square_ref(gf)
     assert got.shape == (2, layout.n_rows, 128)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_flat_g_accum_matches_ref():
-    from repro.kernels.flat_stats import flat_g_accum
-    from repro.kernels.ref import g_accum_ref
-
-    layout = demo_layout("hostile")
-    key = jax.random.PRNGKey(1)
-    gs = jax.random.normal(key, (layout.n_rows, 128))
-    g = jax.random.normal(jax.random.fold_in(key, 1), (layout.n_rows, 128))
-    got = jax.jit(lambda a, b: flat_g_accum(a, b, layout))(gs, g)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(g_accum_ref(gs, g)))
-
-
-def test_flat_vmap_moments_matches_ref():
-    from repro.kernels.flat_stats import flat_vmap_moments
-    from repro.kernels.ref import vmap_moments_ref
-
-    layout = demo_layout("hostile")
-    k = 4
-    gstack = jax.random.normal(jax.random.PRNGKey(2), (k, layout.n_rows, 128))
-    mean, sq = jax.jit(lambda x: flat_vmap_moments(x, layout, k))(gstack)
-    rmean, rsq = vmap_moments_ref(gstack)
-    # the kernel folds the k axis sequentially; jnp.mean reduces in a tree —
-    # same math, one reassociation per slice
-    np.testing.assert_allclose(np.asarray(mean), np.asarray(rmean),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(sq), np.asarray(rsq),
-                               rtol=1e-5, atol=1e-6)
 
 
 def test_gsnr_r_raw_ref_is_the_scale_numerator():

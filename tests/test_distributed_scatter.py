@@ -155,7 +155,7 @@ for name, rules_kw, kw in (("step", {}, {}),
         placed = jax.device_put(state, param_shardings(state, rules))
         step_fn, _ = make_train_step(cfg, make_loss_fn(cfg), mesh=mesh, **kw)
     outs = (jax.tree_util.tree_map(lambda x: x.sharding, placed), NamedSharding(mesh, P()))
-    f = jax.jit(lambda s, b: step_fn(s, b, True), out_shardings=outs)
+    f = jax.jit(step_fn, out_shardings=outs)
     out[name] = f.lower(placed, batch).compile().as_text()
 print(json.dumps(out))
 """

@@ -1,17 +1,18 @@
 """Pallas kernel sweeps: shapes x dtypes against the pure-jnp oracles.
 
 Property sweeps are dependency-free seeded loops — hypothesis is NOT
-required.  The exhaustive differential grid lives in tests/test_oracle.py.
+required.  The exhaustive differential grid lives in tests/test_oracle.py;
+the optimizer sweeps here run the flat kernels on one-leaf layouts too.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import oracle
+from repro.kernels import flat_update as fu
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.vr_adam import vr_adam_inner
-from repro.kernels.vr_update import vr_scale
 
 SIZES = [7, 128, 1000, 4096, 12345]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -23,7 +24,7 @@ def test_vr_scale_sweep(n, dtype):
     key = jax.random.PRNGKey(n)
     g = (jax.random.normal(key, (n,)) * 0.2).astype(dtype)
     g2 = (jnp.square(g.astype(jnp.float32)) + jax.random.uniform(jax.random.fold_in(key, 1), (n,)) * 0.05).astype(dtype)
-    sg, r = vr_scale(g, g2, 0.1, 1e-12)
+    sg, r = oracle.flat_vr_scale(g, g2, 0.1, 1e-12)
     sg_r, r_r = ref.vr_scale_ref(g, g2, 0.1, 1e-12)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(sg, np.float32), np.asarray(sg_r, np.float32), atol=tol, rtol=tol)
@@ -38,7 +39,7 @@ def test_vr_scale_property():
         gamma = float(rng.uniform(0.01, 0.99))
         g = jnp.asarray(rng.uniform(-2, 2, n).astype(np.float32))
         g2 = jnp.square(g) + 0.01
-        sg, r = vr_scale(g, g2, gamma, 1e-12)
+        sg, r = oracle.flat_vr_scale(g, g2, gamma, 1e-12)
         assert np.all(np.asarray(r) >= gamma - 1e-5)
         assert np.all(np.asarray(r) <= 1 + 1e-5)
         np.testing.assert_allclose(np.asarray(sg), np.asarray(r * g), atol=1e-5)
@@ -54,7 +55,13 @@ def test_vr_adam_sweep(n):
     v = jax.random.uniform(ks[3], (n,)) * 0.01
     p = jax.random.uniform(ks[4], (n,))
     kw = dict(b1=0.9, b2=0.999, b3=0.9, eps=1e-8, gamma=0.1, gsnr_eps=1e-12)
-    outs = vr_adam_inner(g, g2, m, v, p, jnp.float32(0.19), jnp.float32(0.002), jnp.float32(0.19), **kw)
+    layout, gf = oracle.one_leaf(g)
+    # lr = -1 and no weight decay: the kernel's update is the Adam direction
+    outs = fu.flat_vr_adam(
+        gf, gf, *(oracle.one_leaf(x)[1] for x in (g2, m, v, p)), jnp.zeros_like(gf),
+        fu._scal8(-1.0, 0.19, 0.002, 0.19), layout, wd=0.0, **kw,
+    )
+    outs = [layout.unpack(x) for x in outs]
     refs = ref.vr_adam_inner_ref(g, g2, m, v, p, bc1=0.19, bc2=0.002, bc3=0.19, **kw)
     for name, a, b in zip("direction/m/v/p".split("/"), outs, refs):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4, err_msg=name)

@@ -129,17 +129,6 @@ def test_update_is_one_pallas_call(name):
     assert count_pallas_calls(jaxpr) == LAUNCHES["flat_update"], jaxpr
 
 
-@pytest.mark.parametrize("name", ("vr_adam", "vr_lamb"))
-def test_stale_update_launches_nothing(name):
-    """Amortized-GSNR steps are pure element-wise flat math: zero launches
-    (XLA fuses the single-array sweep; nothing to gain from a kernel)."""
-    opt, params, g, stats = _opt_and_inputs(name)
-    state = opt.init(params)
-    _, state = opt.update(g, state, params, stats=stats)
-    jaxpr = jax.make_jaxpr(lambda s: opt.update(g, s, params, stats=None))(state)
-    assert count_pallas_calls(jaxpr) == LAUNCHES["flat_update_stale"], jaxpr
-
-
 def test_grad_stats_scan_is_two_pallas_calls():
     """One accumulation call in the scan body + one finalize call."""
     from repro.core import grad_stats
@@ -156,80 +145,6 @@ def test_grad_stats_scan_is_two_pallas_calls():
         lambda p, b: grad_stats(loss_fn, p, b, 4, use_pallas=True)[2]
     )(params, (X, Y))
     assert count_pallas_calls(jaxpr) == LAUNCHES["grad_stats_scan"], jaxpr
-
-
-def test_stale_grad_stats_is_one_pallas_call_and_stays_flat():
-    """The squares=False (amortized-GSNR stale) scan path under a fused-stats
-    plan runs the g-only flat accumulation kernel: ONE pallas_call (the scan
-    body accum; the /k is a fused jnp sweep) and the mean gradient comes
-    back as a FlatBuffer — no jnp tree carry anywhere in the stale step."""
-    from repro.backend import Backend
-    from repro.core import grad_stats
-
-    params = {"w": jnp.ones(300), "b": jnp.zeros(())}
-
-    def loss_fn(p, b):
-        x, y = b
-        return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
-
-    X = jnp.ones((16, 300))
-    Y = jnp.ones((16,))
-    fn = lambda p, b: grad_stats(
-        loss_fn, p, b, 4, squares=False, backend=Backend.all_fused()
-    )[2]
-    jaxpr = jax.make_jaxpr(fn)(params, (X, Y))
-    assert count_pallas_calls(jaxpr) == LAUNCHES["grad_stats_scan_stale"], jaxpr
-    stats = jax.jit(fn)(params, (X, Y))
-    assert is_flat(stats.mean) and stats.sq_mean is None
-    # statistics identical to the tree-carry stale path
-    stats_ref = jax.jit(
-        lambda p, b: grad_stats(loss_fn, p, b, 4, squares=False)[2]
-    )(params, (X, Y))
-    for a, b in zip(
-        jax.tree_util.tree_leaves(stats.mean.unpack()),
-        jax.tree_util.tree_leaves(stats_ref.mean),
-    ):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7)
-
-
-def test_stale_full_train_step_stays_flat():
-    """End to end stale step (gsnr_refresh amortization) under a fused plan:
-    1 stats launch + 0 update launches on the optimizer side — the mean
-    gradient never unpacks into a tree until the update leaves the
-    transform.  With fused attention the full stale step is 4 launches
-    (1 attn fwd + 1 remat recompute + 1 fused attn bwd + 1 g-accum)."""
-    from repro.backend import Backend
-    from repro.configs import get_smoke
-    from repro.data import lm_batches
-    from repro.train import init_state, make_loss_fn, make_train_step
-
-    cfg = get_smoke("granite-3-2b").replace(global_batch=8, seq_len=16)
-    cfg = cfg.replace(
-        optimizer=dataclasses.replace(cfg.optimizer, name="vr_lamb", k=4, gsnr_refresh=4),
-        parallel=dataclasses.replace(cfg.parallel, backend=Backend.all_fused()),
-    )
-    batch = next(iter(lm_batches(cfg.model.vocab_size, 8, 16, seed=0)))
-    state = init_state(cfg)
-    step_fn, _ = make_train_step(cfg, make_loss_fn(cfg))
-    jaxpr = jax.make_jaxpr(lambda s, b: step_fn(s, b, False))(state, batch)
-    assert count_pallas_calls(jaxpr) == LAUNCHES["train_step_stale"], count_pallas_calls(jaxpr)
-
-
-def test_vmap_grad_stats_is_one_pallas_call():
-    from repro.core import grad_stats
-
-    params = {"w": jnp.ones(300)}
-
-    def loss_fn(p, b):
-        x, y = b
-        return jnp.mean((x @ p["w"] - y) ** 2)
-
-    X = jnp.ones((16, 300))
-    Y = jnp.ones((16,))
-    jaxpr = jax.make_jaxpr(
-        lambda p, b: grad_stats(loss_fn, p, b, 4, method="vmap", use_pallas=True)[2]
-    )(params, (X, Y))
-    assert count_pallas_calls(jaxpr) == LAUNCHES["grad_stats_vmap"], jaxpr
 
 
 def test_flash_attention_train_vjp_launch_counts():

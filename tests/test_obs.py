@@ -30,7 +30,7 @@ def _compiled_step_text():
     step_fn, _ = make_train_step(cfg)
     state = init_state(cfg)
     batch = next(lm_batches(cfg.model.vocab_size, cfg.global_batch, cfg.seq_len, seed=0))
-    step = jax.jit(lambda s, b: step_fn(s, b, True))
+    step = jax.jit(step_fn)
     return step.lower(state, batch).compile().as_text()
 
 

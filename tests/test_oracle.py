@@ -2,8 +2,9 @@
 
 Uses the tests/oracle.py harness (dependency-free property loops, interpret
 mode on CPU).  Covers the acceptance grid: non-tile-aligned shapes, partial
-edge blocks, f32/bf16 state, gamma=1.0 base-optimizer collapse, grad-clip
-divergence (g_apply != g), and stale-GSNR (amortized refresh) steps.
+edge blocks, f32/bf16 state, gamma=1.0 base-optimizer collapse and grad-clip
+divergence (g_apply != g).  The per-tensor sweeps run the single-launch flat
+kernels the chip runs, each on a one-leaf ParamLayout.
 """
 import jax
 import jax.numpy as jnp
@@ -11,20 +12,23 @@ import numpy as np
 import pytest
 
 import oracle
+from repro.kernels import flat_spmd as fsp
+from repro.kernels import flat_stats as fs
+from repro.kernels import flat_update as fu
 from repro.kernels import ref
-from repro.kernels.grad_stats import moments_accum, moments_finalize, moments_init
-from repro.kernels.vr_adam import vr_adam_inner
-from repro.kernels.vr_lamb import vr_lamb_inner, vr_lars_inner
-from repro.kernels.vr_update import vr_scale
 
 ADAM_KW = dict(b1=0.9, b2=0.999, b3=0.9, eps=1e-8, gamma=0.1, gsnr_eps=1e-12)
 LAMB_KW = dict(b1=0.9, b2=0.999, b3=0.9, eps=1e-6, wd=0.01, gamma=0.1, gsnr_eps=1e-12)
+LARS_KW = dict(wd=1e-4, gamma=0.1, eps=1e-12)
 BC = dict(bc1=0.19, bc2=0.002, bc3=0.19)
-_f = jnp.float32
+
+
+def _packed(*xs, dtype=jnp.float32):
+    return [oracle.one_leaf(x, dtype)[1] for x in xs]
 
 
 # ---------------------------------------------------------------------------
-# per-tensor kernels
+# per-tensor sweeps: the flat kernels on one leaf
 # ---------------------------------------------------------------------------
 
 
@@ -35,7 +39,7 @@ def test_vr_scale_oracle(shape, dtype):
         for clip in (None, 0.37):
             g, ga, g2 = oracle.gsnr_inputs(shape, seed=sum(shape), dtype=dtype,
                                            clip_scale=clip)
-            got = vr_scale(g, g2, gamma, 1e-12, g_apply=ga)
+            got = oracle.flat_vr_scale(g, g2, gamma, 1e-12, g_apply=ga)
             want = ref.vr_scale_ref(g, g2, gamma, 1e-12, g_apply=ga)
             oracle.assert_trees_close(
                 got, want, msg=f"vr_scale {shape} {dtype} gamma={gamma} clip={clip}",
@@ -50,8 +54,15 @@ def test_vr_scale_oracle(shape, dtype):
 def test_vr_adam_inner_oracle(shape, state_dtype):
     g, ga, g2 = oracle.gsnr_inputs(shape, seed=1, clip_scale=0.9)
     m, v, p, _ = oracle.opt_state_inputs(shape, seed=2, state_dtype=state_dtype)
-    got = vr_adam_inner(g, g2, m, v, p, _f(0.19), _f(0.002), _f(0.19),
-                        g_apply=ga, **ADAM_KW)
+    layout, gf = oracle.one_leaf(g)
+    sd = jnp.dtype(state_dtype)
+    # lr = -1 and no weight decay: the kernel's update is the Adam direction
+    outs = fu.flat_vr_adam(
+        gf, *_packed(ga, g2), *_packed(m, v, p, dtype=sd), jnp.zeros_like(gf),
+        fu._scal8(-1.0, BC["bc1"], BC["bc2"], BC["bc3"]), layout,
+        wd=0.0, state_dtype=sd.name, **ADAM_KW,
+    )
+    got = tuple(layout.unpack(x) for x in outs)
     want = ref.vr_adam_inner_ref(g, g2, m, v, p, g_apply=ga, **ADAM_KW, **BC)
     oracle.assert_trees_close(
         got, want, msg=f"vr_adam {shape} {state_dtype}", **oracle.tol_for(state_dtype)
@@ -64,18 +75,33 @@ def test_vr_lamb_inner_oracle(shape):
     norm reduction must see exact zeros in the padded tail, not garbage."""
     g, ga, g2 = oracle.gsnr_inputs(shape, seed=3, clip_scale=0.8)
     m, v, p, w = oracle.opt_state_inputs(shape, seed=4)
-    got = vr_lamb_inner(g, ga, g2, m, v, p, w, _f(0.19), _f(0.002), _f(0.19), **LAMB_KW)
-    want = ref.vr_lamb_inner_ref(g, ga, g2, m, v, p, w, **LAMB_KW, **BC)
-    oracle.assert_trees_close(got, want, msg=f"vr_lamb {shape}", atol=1e-3, rtol=1e-3)
+    u, m_, v_, p_, u2, w2 = ref.vr_lamb_inner_ref(g, ga, g2, m, v, p, w, **LAMB_KW, **BC)
+    layout, gf = oracle.one_leaf(g)
+    # lr = -1 / (the reference's trust ratio): a kernel whose norms agree
+    # returns u itself as the update
+    lr = -1.0 / oracle.lamb_trust(u2, w2)
+    outs = fu.flat_vr_lamb(gf, *_packed(ga, g2, m, v, p, w),
+                           fu._scal8(lr, BC["bc1"], BC["bc2"], BC["bc3"]), layout,
+                           **LAMB_KW)
+    got = tuple(layout.unpack(x) for x in outs)
+    oracle.assert_trees_close(got, (u, m_, v_, p_), msg=f"vr_lamb {shape}",
+                              atol=1e-3, rtol=1e-3)
 
 
 @pytest.mark.parametrize("shape", oracle.SHAPES, ids=str)
 def test_vr_lars_inner_oracle(shape):
     g, ga, g2 = oracle.gsnr_inputs(shape, seed=5, clip_scale=0.6)
     _, _, _, w = oracle.opt_state_inputs(shape, seed=6)
-    got = vr_lars_inner(g, ga, g2, w, wd=1e-4, gamma=0.1, eps=1e-12)
-    want = ref.vr_lars_inner_ref(g, ga, g2, w, wd=1e-4, gamma=0.1, eps=1e-12)
-    oracle.assert_trees_close(got, want, msg=f"vr_lars {shape}", atol=1e-3, rtol=1e-3)
+    u, u2, w2 = ref.vr_lars_inner_ref(g, ga, g2, w, **LARS_KW)
+    layout, gf = oracle.one_leaf(g)
+    # zero momentum and lr = -1 / (the reference's trust ratio): the update
+    # is u itself where the kernel's norms agree
+    lr = -1.0 / oracle.lars_trust(u2, w2, 0.001)
+    upd, _ = fu.flat_vr_lars(gf, *_packed(ga, g2), jnp.zeros_like(gf), *_packed(w),
+                             fu._scal8(lr, LARS_KW["gamma"]), layout, mu=0.9,
+                             wd=LARS_KW["wd"], trust=0.001, eps=LARS_KW["eps"])
+    oracle.assert_trees_close(layout.unpack(upd), u, msg=f"vr_lars {shape}",
+                              atol=1e-3, rtol=1e-3)
 
 
 @pytest.mark.parametrize("shape", oracle.SHAPES, ids=str)
@@ -83,15 +109,15 @@ def test_vr_lars_inner_oracle(shape):
 def test_grad_stats_moments_oracle(shape, dtype):
     """k fused accumulation steps + fused /k finalize == the jnp scan body."""
     k = 4
-    gs2d = moments_init(jnp.zeros(shape))
-    g2s2d = jnp.zeros_like(gs2d)
+    layout, gs = oracle.one_leaf(jnp.zeros(shape))
+    g2s = jnp.zeros_like(gs)
     gs_ref = jnp.zeros(shape, jnp.float32)
     g2s_ref = jnp.zeros_like(gs_ref)
     for i in range(k):
         g, _, _ = oracle.gsnr_inputs(shape, seed=100 + i, dtype=dtype)
-        gs2d, g2s2d = moments_accum(gs2d, g2s2d, g)
+        gs, g2s = fs.flat_moments_accum(gs, g2s, oracle.one_leaf(g, dtype)[1], layout)
         gs_ref, g2s_ref = ref.moments_accum_ref(gs_ref, g2s_ref, g)
-    got = moments_finalize(gs2d, g2s2d, k, tuple(shape))
+    got = tuple(layout.unpack(x) for x in fs.flat_moments_finalize(gs, g2s, k, layout))
     want = ref.moments_finalize_ref(gs_ref, g2s_ref, k)
     oracle.assert_trees_close(
         got, want, msg=f"moments {shape} {dtype}", **oracle.tol_for(dtype)
@@ -105,7 +131,7 @@ def test_vr_scale_property_loop():
         g, ga, g2 = oracle.gsnr_inputs(
             case["shape"], case["seed"], case["dtype"], case["clip_scale"]
         )
-        sg, r = vr_scale(g, g2, case["gamma"], 1e-12, g_apply=ga)
+        sg, r = oracle.flat_vr_scale(g, g2, case["gamma"], 1e-12, g_apply=ga)
         r_np = np.asarray(r)
         assert np.all(r_np >= case["gamma"] - 1e-5), case
         assert np.all(r_np <= 1 + 1e-5), case
@@ -114,6 +140,52 @@ def test_vr_scale_property_loop():
             np.asarray(r * ga.astype(jnp.float32), np.float32),
             atol=3e-2 if case["dtype"] == jnp.bfloat16 else 1e-5,
         )
+
+
+@pytest.mark.parametrize("shape", oracle.SHAPES, ids=str)
+@pytest.mark.parametrize("name", ("vr_lamb", "vr_lars"))
+def test_shard_kernels_match_the_per_tensor_refs(name, shape):
+    """backend.FlatSpmd's per-shard pipeline — the kernels bert-large.p1-dp4
+    runs — on one shard of a one-leaf layout, where its psums are the
+    identity: the r partials, then the LAMB / LARS compute and the shared
+    epilogue.  The compute's u, state and norm partials match the per-tensor
+    reference; the update and state match the gathered single launch."""
+    g, ga, g2 = oracle.gsnr_inputs(shape, seed=7, clip_scale=0.8)
+    m, v, p, w = oracle.opt_state_inputs(shape, seed=8)
+    layout, gf = oracle.one_leaf(g)
+    ga_f, g2_f, m_f, v_f, p_f, w_f = _packed(ga, g2, m, v, p, w)
+    lids = jnp.asarray(layout.block_leaf_ids())
+    invsz = jnp.asarray(layout.leaf_inv_sizes())
+    if name == "vr_lamb":
+        scal = fu._scal8(0.01, BC["bc1"], BC["bc2"], BC["bc3"])
+        racc = fsp.leaf_r_partials(gf, g2_f, lids, layout, gsnr_eps=LAMB_KW["gsnr_eps"])
+        computed = fsp.vr_lamb_compute(gf, ga_f, g2_f, m_f, v_f, p_f, w_f, scal, racc,
+                                       lids, invsz, layout, **LAMB_KW)
+        u, m2, v2, p2, uacc, wacc = computed
+        stepped = (fu.lamb_epilogue(u, uacc, wacc, scal[0, 0], lids), m2, v2, p2)
+        gathered = fu.flat_vr_lamb(gf, ga_f, g2_f, m_f, v_f, p_f, w_f, scal, layout,
+                                   **LAMB_KW)
+        want = ref.vr_lamb_inner_ref(g, ga, g2, m, v, p, w, **LAMB_KW, **BC)
+    else:
+        scal = fu._scal8(0.01, LARS_KW["gamma"])
+        racc = fsp.leaf_r_partials(gf, g2_f, lids, layout, gsnr_eps=LARS_KW["eps"])
+        computed = fsp.vr_lars_compute(gf, ga_f, g2_f, w_f, scal, racc, lids, invsz,
+                                       layout, wd=LARS_KW["wd"], eps=LARS_KW["eps"])
+        u, uacc, wacc = computed
+        stepped = fu.lars_epilogue(u, m_f, uacc, wacc, scal[0, 0], lids, mu=0.9,
+                                   trust=0.001)
+        gathered = fu.flat_vr_lars(gf, ga_f, g2_f, m_f, w_f, scal, layout, mu=0.9,
+                                   wd=LARS_KW["wd"], trust=0.001, eps=LARS_KW["eps"])
+        want = ref.vr_lars_inner_ref(g, ga, g2, w, **LARS_KW)
+    # u and the state on the leaf's elements; each norm partial summed
+    got = tuple(layout.unpack(x) for x in computed[:-2]) + tuple(
+        jnp.sum(acc[0]) for acc in computed[-2:])
+    oracle.assert_trees_close(got, want, msg=f"{name} shard vs ref {shape}",
+                              atol=1e-3, rtol=1e-3)
+    # the sharded-vs-gathered tolerance of tests/test_spmd_flat.py: the
+    # epilogue runs outside the gathered launch's jit here, ~1 ulp apart
+    oracle.assert_trees_close(stepped, gathered, msg=f"{name} shard vs gathered {shape}",
+                              rtol=3e-6, atol=1e-8)
 
 
 # ---------------------------------------------------------------------------
@@ -492,28 +564,17 @@ def test_gamma_one_collapses_to_base(name):
     oracle.assert_trees_close(u_v, u_b, msg=f"{name} gamma=1", atol=1e-5, rtol=1e-4)
 
 
-@pytest.mark.parametrize("name", ("vr_adam", "vr_lamb"))
-def test_stale_gsnr_steps_agree(name):
-    """Amortized GSNR: stats arrive every 2nd step.  The Pallas fresh-step
-    path must bias-correct p̂ by the stats counter pt (not the raw step) to
-    stay in lockstep with the jnp path."""
-    u_j, u_k, s_j, s_k = oracle.run_transform_pair(name, steps=4, stale_every=2)
-    oracle.assert_trees_close(u_k, u_j, msg=f"{name} stale", atol=1e-5, rtol=1e-4)
-    assert int(s_k["pt"]) == 2 and int(s_k["step"]) == 4
-    assert int(s_j["pt"]) == 2
-
-
 # ---------------------------------------------------------------------------
-# flat single-launch path vs the PR 1 per-leaf kernel dispatch (the per-leaf
-# loops live on in tests/oracle.py as the reference implementation)
+# flat single-launch path vs per-leaf loops over the jnp per-tensor
+# references (tests/oracle.py)
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("name", ("vr_adam", "vr_lamb", "vr_lars"))
 @pytest.mark.parametrize("clip", (None, 0.37), ids=("noclip", "clip"))
 def test_flat_matches_per_leaf_kernels(name, clip):
-    """The one-pallas_call flat update must agree with the kernel-per-leaf
-    dispatch leaf for leaf over the hostile shape grid (non-tile-aligned
+    """The one-pallas_call flat update must agree with the per-leaf jnp
+    references leaf for leaf over the hostile shape grid (non-tile-aligned
     leaves, partial edge blocks, tuple-valued pytree nodes)."""
     u_r, u_f, s_r, s_f = oracle.run_flat_vs_per_leaf(name, steps=2, clip_scale=clip)
     oracle.assert_trees_close(u_f, u_r, msg=f"{name} upd", atol=1e-5, rtol=1e-3)
@@ -532,7 +593,7 @@ def test_flat_matches_per_leaf_bf16_state(name):
 
 
 def test_flat_scale_matches_per_leaf_kernels():
-    """flat_vr_scale vs kernel-per-leaf vr_scale on the hostile param tree."""
+    """flat_vr_scale vs per-leaf ref.vr_scale_ref on the hostile param tree."""
     from repro.core import GradStats
     from repro.kernels import ops as kops
 
