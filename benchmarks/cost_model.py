@@ -13,7 +13,7 @@ single-source-of-truth builders:
   * kernels/flash_attention.fwd_geometry   (+ kv_fetch_blocks fetch maps)
   * kernels/flash_attention_bwd.bwd_geometry
   * kernels/flat_update.PHASE_WINDOWS / _phased_specs / _specs
-  * kernels/flat_stats._blk
+  * kernels/flat_stats._sweep / _payload_spec
 
 so a kernel-side grid or index-map change shows up here without touching the
 model.  MXU FLOPs are matmul counts per LIVE tile pair (dead packed tiles
@@ -235,27 +235,26 @@ def flat_update_cost(cfg: dict = FLAT_CONFIG) -> dict:
 
 def flat_stats_cost(cfg: dict = FLAT_CONFIG) -> dict:
     """The grad-stats launches of the fused step: the scan-body accumulate
-    and the /k finalize (one-block-one-visit streams), plus the device-wise
+    and the /k finalize (one-tile-one-visit streams), plus the device-wise
     pack+square payload builder (distributed path)."""
     from jax.experimental import pallas as pl
 
-    from repro.core.layout import LANE
     from repro.kernels import flat_stats as fs
 
-    layout = _flat_layout()
+    rows = _flat_layout().n_rows
     eb = cfg["elem_bytes"]
-    blk = fs._blk(layout)
-    grid = (layout.n_blocks,)
-    accum = replay_dma(grid, [("gs", blk, eb, False), ("g2s", blk, eb, False),
-                              ("g", blk, eb, False), ("gs_out", blk, eb, True),
-                              ("g2s_out", blk, eb, True)])
+    grid, tile = fs._sweep("flat_moments_accum", rows)
+    accum = replay_dma(grid, [("gs", tile, eb, False), ("g2s", tile, eb, False),
+                              ("g", tile, eb, False), ("gs_out", tile, eb, True),
+                              ("g2s_out", tile, eb, True)])
+    grid, tile = fs._sweep("flat_moments_finalize", rows)
     inv_sp = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    fin = replay_dma(grid, [("gs", blk, eb, False), ("g2s", blk, eb, False),
-                            ("inv", inv_sp, 4, False), ("mean", blk, eb, True),
-                            ("sq", blk, eb, True)])
-    pack_out = pl.BlockSpec((2, layout.block_rows, LANE), lambda i: (0, i, 0))
-    pack = replay_dma(grid, [("gf", blk, eb, False),
-                             ("payload", pack_out, eb, True)])
+    fin = replay_dma(grid, [("gs", tile, eb, False), ("g2s", tile, eb, False),
+                            ("inv", inv_sp, 4, False), ("mean", tile, eb, True),
+                            ("sq", tile, eb, True)])
+    grid, tile = fs._sweep("flat_pack_square", rows)
+    pack = replay_dma(grid, [("gf", tile, eb, False),
+                             ("payload", fs._payload_spec(tile), eb, True)])
     return {
         "accum_hbm_bytes": _total_bytes(accum),
         "finalize_hbm_bytes": _total_bytes(fin),

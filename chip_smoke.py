@@ -14,7 +14,8 @@ a TPU must resolve every subsystem to the fused Pallas kernels compiled by
 Mosaic, then — after that state is freed — under
 ``Backend.all_reference()`` on the same batches.  Every value must be
 finite, and the fused and reference results must agree within the printed
-tolerances.
+tolerances.  Before it, the row tile and grid steps each statistics sweep
+takes over the model's flat buffer are printed.
 
 ``--chips 4`` runs only the data-parallel path: the same model on a
 (data=4) mesh with ``gsnr_source="data_axis"`` (k = 4 = the device count)
@@ -238,6 +239,24 @@ def attention(label, b, s, h, kv, d):
     gc.collect()
 
 
+def stats_tiles(cfg):
+    """Print the row tile and grid steps each statistics sweep takes over
+    the model's flat buffer (``flat_stats.sweep_rows``)."""
+    import jax
+
+    from repro.core.layout import ParamLayout
+    from repro.kernels import flat_stats as fs
+    from repro.models import init_params
+
+    shapes = jax.eval_shape(lambda: init_params(cfg.model, jax.random.PRNGKey(0),
+                                                scan_layers=cfg.parallel.scan_layers))
+    rows = ParamLayout.for_tree(shapes).n_rows
+    for kname, streams in fs.STREAMS.items():
+        tile = fs.sweep_rows(rows, streams)
+        print(f"[stats] {kname}: {rows} rows in {-(-rows // tile)} grid steps of "
+              f"{tile} rows", flush=True)
+
+
 def one_chip(device):
     from repro.backend import Backend
 
@@ -248,6 +267,7 @@ def one_chip(device):
     for case in ATTENTION:
         attention(*case)
     fused_cfg = make_cfg(Backend(), k=8)
+    stats_tiles(fused_cfg)
     batches = host_batches(fused_cfg)
     fused = run("fused", fused_cfg, batches)
     print(f"[fused] peak_bytes_in_use {peak_gib(device):.2f} GiB", flush=True)

@@ -317,7 +317,7 @@ class FlatSpmd:
         interp = self._interp()
         pad = self._pad_rows(layout)
         row = self._row_spec(layout)
-        body = lambda a, b, c: fs.flat_moments_accum(a, b, c, layout, interpret=interp)
+        body = lambda a, b, c: fs.flat_moments_accum(a, b, c, interpret=interp)
         out = self._smap(body, (row, row, row), (row, row))(
             self._padded(gs, pad), self._padded(g2s, pad), self._padded(g, pad)
         )
@@ -329,7 +329,7 @@ class FlatSpmd:
         interp = self._interp()
         pad = self._pad_rows(layout)
         row = self._row_spec(layout)
-        body = lambda a, b, kk: fs.flat_moments_finalize(a, b, kk, layout, interpret=interp)
+        body = lambda a, b, kk: fs.flat_moments_finalize(a, b, kk, interpret=interp)
         k = jnp.asarray(k, jnp.float32)
         out = self._smap(body, (row, row, P()), (row, row))(
             self._padded(gs, pad), self._padded(g2s, pad), k

@@ -113,7 +113,7 @@ def device_grad_stats_fn(
             elif fused:
                 # one kernel builds the [g; g²] payload in a single read of
                 # gf; mean/sq are views of the reduced payload, not copies
-                payload = flat_pack_square(gf, layout, interpret=_interp(resolved))
+                payload = flat_pack_square(gf, interpret=_interp(resolved))
                 payload = jax.lax.pmean(payload, data_axis)  # one collective
                 mean, sq = payload[0], payload[1]
             else:  # paper-faithful two-collective schedule, flat carries

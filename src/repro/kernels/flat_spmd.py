@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.layout import LANE, ParamLayout
-from repro.kernels.flat_stats import _local_blocks
 from repro.kernels.flat_update import (
     _accumulate_r,
     _adam_apply,
@@ -60,6 +59,16 @@ from repro.kernels.flat_update import (
 )
 
 _f32 = jnp.float32
+
+
+def _local_blocks(x, layout: ParamLayout) -> int:
+    rows = x.shape[0]
+    if rows % layout.block_rows:
+        raise ValueError(
+            f"flat shard has {rows} rows, not a multiple of block_rows="
+            f"{layout.block_rows} — shard count must divide n_blocks"
+        )
+    return rows // layout.block_rows
 
 
 def _specs(layout: ParamLayout):

@@ -241,7 +241,7 @@ def moments_accum_flat(g_sum, g2_sum, grads, layout: ParamLayout,
     with obs.scope(obs.STATS_ACCUM):
         if plan is not None:
             return plan.moments_accum(g_sum, g2_sum, g, layout)
-        return fs.flat_moments_accum(g_sum, g2_sum, g, layout, interpret=_interp(backend))
+        return fs.flat_moments_accum(g_sum, g2_sum, g, interpret=_interp(backend))
 
 
 def moments_finalize_flat(g_sum, g2_sum, k, layout: ParamLayout,
@@ -252,9 +252,7 @@ def moments_finalize_flat(g_sum, g2_sum, k, layout: ParamLayout,
         if plan is not None:
             mean, sq = plan.moments_finalize(g_sum, g2_sum, k, layout)
         else:
-            mean, sq = fs.flat_moments_finalize(
-                g_sum, g2_sum, k, layout, interpret=_interp(backend)
-            )
+            mean, sq = fs.flat_moments_finalize(g_sum, g2_sum, k, interpret=_interp(backend))
     return GradStats(mean=_fb(mean, layout), sq_mean=_fb(sq, layout), k=k)
 
 

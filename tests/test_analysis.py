@@ -327,9 +327,25 @@ def test_flat_pack_square_matches_ref():
 
     layout = demo_layout("hostile")
     gf = jax.random.normal(jax.random.PRNGKey(0), (layout.n_rows, 128))
-    got = jax.jit(lambda x: flat_pack_square(x, layout))(gf)
+    got = jax.jit(flat_pack_square)(gf)
     want = pack_square_ref(gf)
     assert got.shape == (2, layout.n_rows, 128)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=("f32", "bf16"))
+def test_flat_pack_square_matches_ref_over_ragged_tiles(dtype):
+    # the hostile layout's rows plus two whole sweep tiles: a grid of three
+    # row tiles, the last ragged, each payload half written in every one
+    from repro.kernels.flat_stats import SWEEP_ROWS, flat_pack_square, sweep_rows
+    from repro.kernels.ref import pack_square_ref
+
+    rows = demo_layout("hostile").n_rows + 2 * SWEEP_ROWS
+    assert sweep_rows(rows, 3) == SWEEP_ROWS and rows % SWEEP_ROWS
+    gf = jax.random.normal(jax.random.PRNGKey(1), (rows, 128)).astype(dtype)
+    got = jax.jit(flat_pack_square)(gf)
+    want = jax.jit(pack_square_ref)(gf)
+    assert got.shape == (2, rows, 128)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -226,6 +226,48 @@ def test_shape_chosen_geometry_at_the_cells_shapes():
     assert not _chosen(GRANITE_20B)[1][2]
 
 
+def _cell_layout(config: str):
+    """The flat ParamLayout of a chip benchmark configuration, from shapes
+    alone: its family's ``ModelConfig`` through the program's
+    ``init_params`` under ``jax.eval_shape``."""
+    import json
+    import pathlib
+    import sys
+
+    from repro.configs import ParallelismConfig
+    from repro.core.layout import ParamLayout
+    from repro.models import init_params
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmarks.chip.spec import family
+
+    conf = json.loads((root / "benchmarks" / "chip" / "configs" / f"{config}.json").read_text())
+    model = family(conf).model_config(conf)
+    scan = ParallelismConfig().scan_layers
+    return ParamLayout.for_tree(jax.eval_shape(
+        lambda: init_params(model, jax.random.PRNGKey(0), scan_layers=scan)))
+
+
+@pytest.mark.parametrize("config", ["bert-large", "granite-3-2b", "mellum2-12b-a2.5b"])
+def test_statistics_sweeps_take_few_grid_steps_at_the_cells_shapes(config):
+    """At the cells' flat layouts (~2.6-2.7 M rows, ~41,000 64-row layout
+    blocks) every statistics sweep takes at most 2,700 grid steps, whose
+    double-buffered windows fit the VMEM budget with room for the body."""
+    from repro.analysis.layout_contracts import DOUBLE_BUFFER, VMEM_BUDGET_BYTES
+    from repro.kernels import flat_stats as fs
+
+    layout = _cell_layout(config)
+    assert layout.n_blocks > 40_000
+    for kname, streams in fs.STREAMS.items():
+        (steps,), tile = fs._sweep(kname, layout.n_rows)
+        rows = tile.block_shape[0]
+        assert steps <= 2_700 and steps * rows >= layout.n_rows > (steps - 1) * rows, kname
+        assert DOUBLE_BUFFER * streams * rows * 128 * 4 <= fs.SWEEP_VMEM_BYTES, kname
+        assert fs.SWEEP_VMEM_BYTES < VMEM_BUDGET_BYTES["tpu"]
+
+
 @pytest.mark.parametrize("block_h", [3, 8])
 def test_a_head_block_must_fit_the_heads(block_h):
     # 4 query heads over 2 kv heads: 3 splits a group, 8 is more than H

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 import oracle
+from repro.core.layout import ParamLayout
 from repro.kernels import flat_spmd as fsp
 from repro.kernels import flat_stats as fs
 from repro.kernels import flat_update as fu
@@ -104,21 +105,41 @@ def test_vr_lars_inner_oracle(shape):
                               atol=1e-3, rtol=1e-3)
 
 
-@pytest.mark.parametrize("shape", oracle.SHAPES, ids=str)
+# beyond one sweep tile (flat_stats.SWEEP_ROWS = 2048 rows): one leaf of
+# 2,368 rows (37 blocks; tiles of 2048 and a ragged 320), and the hostile
+# shapes as leaves of one tree beside a leaf of 3,200 rows (3,712 in all;
+# the last tile ragged at 1,664)
+MOMENT_CASES = oracle.SHAPES + (
+    pytest.param((300000,), id="tiles-ragged"),
+    pytest.param(oracle.SHAPES + ((3200, 128),), id="tiles-hostile-tree"),
+)
+
+
+@pytest.mark.parametrize("shape", MOMENT_CASES, ids=str)
 @pytest.mark.parametrize("dtype", oracle.DTYPES, ids=("f32", "bf16"))
 def test_grad_stats_moments_oracle(shape, dtype):
-    """k fused accumulation steps + fused /k finalize == the jnp scan body."""
+    """k fused accumulation steps + fused /k finalize == the jnp scan body,
+    per leaf; bitwise for f32 gradients (the same f32 operations per
+    element, whatever the grid step)."""
     k = 4
-    layout, gs = oracle.one_leaf(jnp.zeros(shape))
-    g2s = jnp.zeros_like(gs)
-    gs_ref = jnp.zeros(shape, jnp.float32)
-    g2s_ref = jnp.zeros_like(gs_ref)
+    # jitted like the kernel, so both sides compile g2 + g*g the same way
+    accum_ref, finalize_ref = jax.jit(ref.moments_accum_ref), jax.jit(ref.moments_finalize_ref)
+    shapes = list(shape) if isinstance(shape[0], tuple) else [shape]
+    layout = ParamLayout.for_tree([jnp.zeros(s) for s in shapes])
+    gs, g2s = layout.zeros(), layout.zeros()
+    gs_ref = [jnp.zeros(s, jnp.float32) for s in shapes]
+    g2s_ref = [jnp.zeros(s, jnp.float32) for s in shapes]
     for i in range(k):
-        g, _, _ = oracle.gsnr_inputs(shape, seed=100 + i, dtype=dtype)
-        gs, g2s = fs.flat_moments_accum(gs, g2s, oracle.one_leaf(g, dtype)[1], layout)
-        gs_ref, g2s_ref = ref.moments_accum_ref(gs_ref, g2s_ref, g)
-    got = tuple(layout.unpack(x) for x in fs.flat_moments_finalize(gs, g2s, k, layout))
-    want = ref.moments_finalize_ref(gs_ref, g2s_ref, k)
+        g = [oracle.gsnr_inputs(s, seed=100 + i + 1000 * j, dtype=dtype)[0]
+             for j, s in enumerate(shapes)]
+        gs, g2s = fs.flat_moments_accum(gs, g2s, layout.pack(g, dtype))
+        for j, gj in enumerate(g):
+            gs_ref[j], g2s_ref[j] = accum_ref(gs_ref[j], g2s_ref[j], gj)
+    got = tuple(layout.unpack(x) for x in fs.flat_moments_finalize(gs, g2s, k))
+    want = tuple(zip(*(finalize_ref(a, b, k) for a, b in zip(gs_ref, g2s_ref))))
+    if dtype == jnp.float32:
+        for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     oracle.assert_trees_close(
         got, want, msg=f"moments {shape} {dtype}", **oracle.tol_for(dtype)
     )

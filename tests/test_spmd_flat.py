@@ -164,6 +164,44 @@ print("OK")
 """
 
 
+SWEEP_SCRIPT = r"""
+import jax, jax.numpy as jnp, numpy as np
+from repro.backend import Backend
+from repro.core.layout import ParamLayout
+from repro.kernels import flat_stats as fs
+from repro.kernels import ops as kops
+from repro.kernels import ref
+from repro.launch.mesh import make_mesh
+from repro.sharding.rules import Rules
+
+mesh = make_mesh((4,), ("data",))
+plan = Backend.all_fused().shard(mesh, Rules(mesh=mesh))
+# (blocks, shard rows): 320 rows a shard, fewer than a tile and not a
+# multiple of it; 2,368 a shard, a whole tile and a ragged 320; 150 blocks
+# pad to 152 (2,432 a shard: a tile and a ragged 384)
+for blocks, shard_rows in ((20, 320), (148, 2368), (150, 2432)):
+    tree = {"w": jax.random.normal(jax.random.PRNGKey(blocks), (blocks * 64, 128))}
+    layout = ParamLayout.for_tree(tree)
+    assert (layout.n_rows + plan._pad_rows(layout)) // 4 == shard_rows
+    assert fs.sweep_rows(shard_rows, fs.STREAMS["flat_moments_accum"]) == min(shard_rows, 2048)
+    gs = jax.random.normal(jax.random.PRNGKey(1), (layout.n_rows, 128))
+    g2s = jnp.square(gs) * 0.5
+    grads = jax.tree_util.tree_map(lambda x: x * 0.01, tree)
+    a_s = jax.jit(lambda a, b, c: kops.moments_accum_flat(a, b, c, layout, spmd=plan))(gs, g2s, grads)
+    a_g = jax.jit(lambda a, b, c: kops.moments_accum_flat(a, b, c, layout))(gs, g2s, grads)
+    want = jax.jit(ref.moments_accum_ref)(gs, g2s, layout.pack(grads))
+    for x, y, z in zip(a_s, a_g, want):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(z))
+    f_s = jax.jit(lambda a, b: kops.moments_finalize_flat(a, b, 4, layout, spmd=plan))(*a_s)
+    f_g = jax.jit(lambda a, b: kops.moments_finalize_flat(a, b, 4, layout))(*a_g)
+    np.testing.assert_array_equal(np.asarray(f_s.mean.data), np.asarray(f_g.mean.data))
+    np.testing.assert_array_equal(np.asarray(f_s.sq_mean.data), np.asarray(f_g.sq_mean.data))
+    print("sweep", blocks, shard_rows, "bitwise ok")
+print("OK")
+"""
+
+
 TRAINER_SCRIPT = r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
@@ -216,6 +254,14 @@ def test_spmd_flat_ops_match_gathered_oracle_subprocess():
     allclose on straddling ones (including non-divisible block counts via
     the internal remainder padding), launch counts pinned."""
     _run(OPS_SCRIPT)
+
+
+def test_spmd_stats_sweeps_tile_each_shard_subprocess():
+    """The statistics sweeps per shard of a 4-device mesh tile each shard's
+    own rows: a shard smaller than one tile, one a tile and a ragged rest,
+    and one padded to a whole number of layout blocks; accumulate and
+    finalize are bitwise equal to the gathered launches and the reference."""
+    _run(SWEEP_SCRIPT)
 
 
 @pytest.mark.slow

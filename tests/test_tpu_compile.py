@@ -173,9 +173,8 @@ def test_flat_update_compiles(one_chip, bert_layout, name):
 def test_flat_stats_compiles(one_chip, bert_layout, name):
     from repro.kernels import flat_stats as fs
 
-    layout = bert_layout
-    rows = ((layout.n_rows, LANE), jnp.float32)
-    fn = functools.partial(getattr(fs, name), layout=layout, interpret=False)
+    rows = ((bert_layout.n_rows, LANE), jnp.float32)
+    fn = functools.partial(getattr(fs, name), interpret=False)
     if name == "flat_moments_accum":
         _compile(one_chip, fn, rows, rows, rows)
     elif name == "flat_moments_finalize":
